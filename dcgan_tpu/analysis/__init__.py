@@ -8,8 +8,7 @@ tripwire:
 AST tier (ISSUE 8 — no imports of the code under analysis, milliseconds):
 
     DCG001  collectives only on the dispatch thread   analysis/threads.py
-    DCG002  no donating non-XLA-owned buffers         analysis/donation.py
-    DCG003  shard_map only via utils/backend shim     analysis/hygiene.py
+    DCG003  shard_map only via utils/backend          analysis/hygiene.py
     DCG004  event keys declared + gated (parity)      analysis/parity.py
     DCG005  no wall-clock/host-RNG in traced bodies   analysis/hygiene.py
     DCG006  retry-wrapped IO in services/checkpoint   analysis/hygiene.py

@@ -341,12 +341,10 @@ def run_checks(sources: Sequence[SourceFile], config: Optional[Config] = None,
     `suppressed_out` to receive the findings a suppression absorbed —
     the stale-suppression audit (DCG014) needs them to tell a working
     suppression from a dead one."""
-    from dcgan_tpu.analysis import donation, hygiene, parity, protocol, \
-        threads
+    from dcgan_tpu.analysis import hygiene, parity, protocol, threads
 
     registry = {
         "DCG001": threads.check_collectives_off_dispatch,
-        "DCG002": donation.check_donation_hazard,
         "DCG003": hygiene.check_raw_shard_map,
         "DCG004": parity.check_key_inventory,
         "DCG005": hygiene.check_traced_body_hygiene,
@@ -388,8 +386,8 @@ def run_checks(sources: Sequence[SourceFile], config: Optional[Config] = None,
     return findings
 
 
-AST_CHECK_IDS = ("DCG001", "DCG002", "DCG003", "DCG004", "DCG005",
-                 "DCG006", "DCG013")
+AST_CHECK_IDS = ("DCG001", "DCG003", "DCG004", "DCG005", "DCG006",
+                 "DCG013")
 
 STALE_SUPPRESSION_CHECK = "DCG014"
 STALE_BASELINE_CHECK = "DCG015"

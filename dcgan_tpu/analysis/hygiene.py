@@ -2,13 +2,11 @@
 
 - **DCG003** — raw shard_map references (the `shard_map` attribute on
   `jax` or `jax.experimental`, or imports of the experimental module)
-  outside `utils/backend.py`. This container's jax 0.4.37 only ships the
-  experimental form (with `check_rep`); the modern form takes
-  `check_vma`. Every call site must route through the
-  `utils/backend.shard_map` compat shim or the explicit-collective layer
-  breaks at first use on one side of the API graduation. Docstrings are
-  checked too (for the literal modern-API claim) — a doc that names the
-  wrong API is how the next call site gets written against it.
+  outside `utils/backend.py`. Every call site routes through
+  `utils/backend.shard_map`, so the replication check is switched in one
+  place under one keyword (`check`). Docstrings are checked too (for the
+  literal raw-API name) — a doc that names the raw API is how the next
+  call site gets written against it.
 
 - **DCG005** — traced-body hygiene: wall-clock (`time.time`,
   `datetime.now`, ...) and host RNG (`random.*`, `np.random.*`) calls
@@ -83,11 +81,10 @@ def check_raw_shard_map(sources: Sequence[SourceFile],
                         if node.body else "<module>",
                         key="docstring:jax.shard_map",
                         message=(
-                            "docstring claims `jax.shard_map` — this "
-                            "container only has jax.experimental."
-                            "shard_map behind the utils/backend.shard_map "
-                            "shim; name the shim so the next call site "
-                            "is written against the API that exists")))
+                            "docstring names raw `jax.shard_map` — name "
+                            "utils/backend.shard_map, the one call site, "
+                            "so the next caller is written against "
+                            "it")))
     return findings
 
 
@@ -97,9 +94,8 @@ def _sm_finding(sf: SourceFile, node: ast.AST, chain: Optional[str]
         check="DCG003", path=sf.path, line=node.lineno,
         symbol=sf.enclosing_symbol(node), key=chain or "shard_map",
         message=(f"raw {chain!r} reference outside utils/backend.py — "
-                 "route through utils/backend.shard_map (the check_vma/"
-                 "check_rep API-graduation shim); a raw reference breaks "
-                 "on one side of the graduation"))
+                 "route through utils/backend.shard_map, the one call "
+                 "site that switches the replication check"))
 
 
 # -- DCG005 ------------------------------------------------------------------

@@ -424,10 +424,13 @@ def _make_loader(cfg: DataConfig, paths: Sequence[str], seed: int):
                   label_feature=cfg.label_feature,
                   max_corrupt_records=cfg.max_corrupt_records)
     if cfg.use_native:
+        from dcgan_tpu.data.native import NativeLoader, NativeLoaderError
         try:
-            from dcgan_tpu.data.native import NativeLoader
             return NativeLoader(paths, **kwargs)
-        except Exception as e:
+        except NativeLoaderError as e:
+            # no toolchain (the build failed): a development machine carries
+            # on in Python. A path that is measured must not — chip_smoke.py
+            # turns this warning into an error.
             import warnings
             warnings.warn(f"native loader unavailable ({e}); "
                           "using pure-Python loader")
@@ -615,17 +618,18 @@ def make_dataset(cfg: DataConfig, sharding=None,
     check_manifest(cfg.data_dir, cfg)
     if cfg.record_dtype == "float64" and any(
             d.platform not in ("cpu",) for d in jax.devices()):
-        # The parity wire format is input-bound at accelerator rates by this
-        # repo's own measurements (BASELINE.md: ~14-18k img/s one-core
-        # float64 decode ceiling vs ~21.5k img/s chip consumption). Warn,
-        # don't fail: short runs and parity experiments are legitimate.
+        # The parity wire format moves and decodes 8 bytes for every value
+        # that carries 8 bits — the format most likely to leave an
+        # accelerator waiting for input (rates not measured on the current
+        # machine). Warn, don't fail: short runs and parity experiments
+        # are legitimate.
         import warnings
 
         warnings.warn(
-            "float64 TFRecords feeding an accelerator: the float64 decode "
-            "ceiling (~14-18k img/s/core) is below the chip's measured "
-            "consumption rate — re-prepare with --record_dtype uint8 "
-            "(the default) unless byte-exact reference parity is the goal",
+            "float64 TFRecords feeding an accelerator: 8 bytes decoded per "
+            "8-bit value makes this the format most likely to starve the "
+            "chip — re-prepare with --record_dtype uint8 (the default) "
+            "unless byte-exact reference parity is the goal",
             RuntimeWarning, stacklevel=2)
     paths = shard_for_process(list_shards(cfg.data_dir),
                               jax.process_index(), jax.process_count())

@@ -9,11 +9,10 @@ missing producer, implemented as the reference *intended*: center-crop to
 `crop_size`, resize to `image_size`, serialize in the exact schema the input
 pipeline (and its C++ loader) consumes.
 
-Default wire format is uint8, not the reference's float64: this repo's own
-measurements (BASELINE.md) put the one-core float64 decode ceiling at
-~14-18k img/s against a ~21.5k img/s chip consumption rate — the parity
-format is input-bound at chip rates by construction (8 bytes/pixel for
-values that carry 8 bits). `--record_dtype float64` keeps the strict-parity
+Default wire format is uint8, not the reference's float64: the parity
+format spends 8 bytes/pixel on values that carry 8 bits, on disk and in the
+decode loop (loader and chip rates are not measured on the current
+machine). `--record_dtype float64` keeps the strict-parity
 byte format available, and the pipeline warns when it meets a chip-rate
 consumer (data/pipeline.py).
 
@@ -264,10 +263,8 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--num_shards", type=int, default=8)
     p.add_argument("--record_dtype", default=None,
                    choices=["float64", "float32", "uint8"],
-                   help="on-disk pixel dtype; default uint8 (8x smaller, "
-                        "and the only wire format whose one-core decode "
-                        "ceiling clears the chip's measured consumption "
-                        "rate — BASELINE.md); pass float64 for strict "
+                   help="on-disk pixel dtype; default uint8 (8x smaller "
+                        "and cheaper to decode); pass float64 for strict "
                         "parity with the reference (image_input.py:48)")
     p.add_argument("--labeled", action="store_true",
                    help="class subdirectories -> int64 label feature")

@@ -29,9 +29,7 @@ in 30s" (or "your capacity is back") mid-run:
   moves the LIVE state between meshes through the elastic host path
   (`jax.device_get` -> `reshard.put_host_tree` onto the target surface's
   sharded templates), which re-scatters ZeRO-2/3 resident shards and
-  replicated leaves alike, then (persistent compile cache active) rebases
-  the tree onto XLA-owned buffers so donation into deserialized
-  executables stays safe (DESIGN §6d).
+  replicated leaves alike.
 
 The trainer (train/trainer.py) sequences the two around the PR 14
 phase-boundary machinery: lag-by-one metric flush -> services drain ->
@@ -340,15 +338,6 @@ class LiveTopologyRuntime:
         # the target-sharded template: eval_shape only — nothing allocates
         template = warmup.state_example(pt_t)
         moved = put_host_tree(jax.device_get(state), template)
-        from dcgan_tpu.utils.checkpoint import persistent_cache_active
-
-        if persistent_cache_active():
-            # host-staged leaves must not be donated into deserialized
-            # executables (DESIGN §6d) — one identity pass (the target
-            # topology's primed state_copy signature) rebases the tree
-            from dcgan_tpu.train.rollback import device_copy
-
-            moved = device_copy(moved)
         self.index = target
         self.switches += 1
         self.last_switch_ms = (time.perf_counter() - t0) * 1e3
@@ -459,8 +448,8 @@ class LiveTopologyRuntime:
             if cfg_i.activation_summary_steps:
                 pt_i.summarize(st, imgs, key, *lbls)
             # identity-copy signatures the run dispatches later on this
-            # topology: the switch's donation rebase (full state) and the
-            # histogram snapshot (params subtree)
+            # topology: the post-switch rollback snapshot (full state) and
+            # the histogram snapshot (params subtree)
             st = device_copy(st)
             device_copy(st["params"])
             jax.block_until_ready(jax.tree_util.tree_leaves(m))

@@ -23,8 +23,7 @@ Two paths, chosen by what changed (DESIGN.md §6h's decision tree):
 
 Both paths return trees with exactly the target state's shardings, so
 everything downstream of restore (warmup plan lowering, rollback
-snapshots, the donation-safety rebase) sees the same tree it would after
-a same-topology restore.
+snapshots) sees the same tree it would after a same-topology restore.
 """
 
 from __future__ import annotations

@@ -1,5 +1,5 @@
 """The eval job: stream real-data and generator features once, score FID
-(BASELINE.md north star: FID-50k parity) and optionally KID from the same
+(BASELINE.json north star: FID-50k parity) and optionally KID from the same
 pass.
 
 Layout mirrors the training driver: the sampler is the mesh-sharded

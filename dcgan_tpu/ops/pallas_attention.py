@@ -50,10 +50,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 # Tile sizes. The per-tile softmax state update is loop-carried, so tile
 # COUNT — not matmul rate — dominates at the head dims this model uses;
-# large k-tiles amortize that serialization. (256, 1024) is the chip-swept
-# optimum (v5e, S=16384 fwd+bwd: 11.95 ms vs 28.9 at the naive MXU-edge
-# 128/128 and 21.6 dense; at S=40960 flash 35.96 ms vs dense 80.66 — the
-# sweep grid and every measured cell are in DESIGN.md §8). Overridable via
+# large k-tiles amortize that serialization. (256, 1024) won a tile sweep on
+# the previous machine against the naive MXU-edge 128/128 (timings not
+# measured on the current one; DESIGN.md §8). Overridable via
 # the DCGAN_FLASH_TQ / DCGAN_FLASH_TK env vars — read at TRACE time, and
 # the resolved tiles are baked into the jit-compiled program (they are not
 # part of the jit cache key), so set them before the first call for a given

@@ -59,15 +59,35 @@ Pytree = dict
 _CONV_DIMS = ("NHWC", "HWIO", "NHWC")
 
 
+#: widest contraction dim taken as ONE block when no lane-aligned block
+#: divides it (K = 1600 at the first celeba64/dcgan128 D stage: 0.8 MiB of
+#: bf16 patch tile); anything wider is zero-padded to the lane tiling instead
+_WHOLE_K_MAX = 2048
+
+
+def _k_padded(n: int) -> int:
+    """The contraction dim the kernels are handed for a true K of n: n
+    itself when `_k_tile` can block it, else n rounded up to the 128 lanes
+    (zero patch columns against zero weight rows add nothing to the GEMM,
+    hence nothing to the moments)."""
+    if n % 128 == 0 or n <= _WHOLE_K_MAX:
+        return n
+    return -(-n // 128) * 128
+
+
 def _k_tile(n: int) -> int:
-    """Largest contraction-block <= 512 dividing n. The contraction dim is
-    Cin*kh*kw (e.g. 1600..12800 at the 128/256px stages) — streaming it in
-    blocks keeps the weight tile (tk x Cout) VMEM-resident instead of the
-    whole [K, Cout] matrix (13 MiB f32 at the deepest 256px stage)."""
-    tile = min(n, 512)
-    while n % tile:
-        tile -= 1
-    return tile
+    """Contraction block for K = Cin*kh*kw (1600..25600 at the 64-256px
+    stages): the largest multiple of 128 up to 512 that divides n, else the
+    whole n. The TPU lowering takes a block's last dim only as a multiple
+    of the 128 lanes or as the whole array dim, so "largest divisor <= 512"
+    (400 at K = 1600, 3200 and 6400) is refused by the chip's compiler.
+    Streaming K in blocks keeps the weight tile (tk x Cout) VMEM-resident
+    instead of the whole [K, Cout] matrix (13 MiB f32 at the deepest 256px
+    stage); callers bound the whole-n case with `_k_padded`."""
+    for tile in (512, 384, 256, 128):
+        if n % tile == 0:
+            return tile
+    return n
 
 
 def w_to_gemm(w: jax.Array) -> jax.Array:
@@ -365,6 +385,10 @@ def fused_conv_bn_act(conv_params: Pytree, bn_params: Pytree,
     x = x.astype(cdt)
     w2d = w_to_gemm(w.astype(cdt))
     p2d, (n, ho, wo) = conv_patches(x, kernel, stride, transpose)
+    k_pad = _k_padded(p2d.shape[1]) - p2d.shape[1]
+    if k_pad:
+        p2d = jnp.pad(p2d, ((0, 0), (0, k_pad)))
+        w2d = jnp.pad(w2d, ((0, k_pad), (0, 0)))
     if quant == "fp8":
         p2d, w2d = fake_quant_fp8(p2d), fake_quant_fp8(w2d)
     c = w2d.shape[1]
@@ -473,7 +497,7 @@ def kernel_cost(m: int, k: int, c: int, *, train: bool,
         parts["scale_act"] = 3 * m * c
         hbm = (m * k * isz + k * c * isz + 3 * c * 4    # + scale, shift
                + m * c * isz)
-    tm, tk = _row_tile(m), _k_tile(k)
+    tm, tk = _row_tile(m), _k_tile(_k_padded(k))
     vmem = (tm * tk + tk * c) * isz + tm * c * 4 + 2 * c * 4
     return {"flops": sum(parts.values()), "flops_parts": parts,
             "bytes": hbm, "peak_temp_mib": round(vmem / 2**20, 3)}

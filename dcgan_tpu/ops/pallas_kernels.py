@@ -18,11 +18,11 @@ once per op.
 
 Both degrade to `interpret=True` off-TPU, so the same code path is exercised
 by the CPU test mesh. Models opt in via ModelConfig.use_pallas; the jnp path
-remains the default for a measured reason: on this workload XLA's own
-elementwise fusion already saturates HBM — DCGAN-64 batch-64 on a v5e chip
-measures ~19.8k img/s unfused vs ~16.3k fused (readback-synced, bench.py),
-so the kernels are a capability (and the pattern for ops XLA can't fuse),
-not a default. GSPMD cannot repartition an opaque kernel call, so on
+remains the default for a structural reason: XLA already fuses the BN
+epilogue into its neighbours, so a hand-written kernel has no HBM traffic
+left to remove (it lost to XLA on the previous machine; not measured on the
+current one — DESIGN.md §8b), so the kernels are a capability (and the
+pattern for ops XLA can't fuse), not a default. GSPMD cannot repartition an opaque kernel call, so on
 multi-device meshes the kernels run per data-shard inside a shard_map — the
 gspmd backend nests one around each fused BN call
 (ops/norm.py::_pallas_shard_moments, VERDICT r1 #5), and the shard_map

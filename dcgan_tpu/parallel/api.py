@@ -31,10 +31,9 @@ Pytree = Any
 #: BOTH backends, by construction. The semantic analyzer (DCG007) holds
 #: this in both directions against the compiled executables: every donated
 #: input of these programs must be realized as an `input_output_aliases`
-#: pair (donated-but-unaliased is a silent copy, and under the
-#: deserialized-executable guards of DESIGN §6d a latent heap hazard), and
-#: no program OUTSIDE this set may donate (an undeclared donor bypasses
-#: the trainer's donation-safety discipline). Adding a donating program
+#: pair (donated-but-unaliased is a silent copy), and no program OUTSIDE
+#: this set may donate (an undeclared donor invalidates buffers a caller
+#: still holds). Adding a donating program
 #: means adding it here and regenerating analysis/programs.lock.jsonl.
 DONATED_PROGRAMS = ("train_step", "multi_step", "d_update", "g_update")
 

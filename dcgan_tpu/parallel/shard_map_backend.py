@@ -1,9 +1,8 @@
 """Explicit-collective training backend: shard_map + psum/pmean by hand.
 
 The default backend (parallel/api.py) states shardings and lets GSPMD insert
-the collectives. This one is the other idiom: shard_map — via the
-`utils/backend.shard_map` shim over `jax.experimental.shard_map`, the only
-form this container's jax 0.4.37 ships (DCG003) — gives each device its
+the collectives. This one is the other idiom: shard_map — via
+`utils/backend.shard_map`, the one call site (DCG003) — gives each device its
 per-shard program and the cross-replica communication is written
 out explicitly — `lax.pmean` over the "data" axis for gradients, losses, and
 BatchNorm moments (train/steps.py and ops/norm.py take `axis_name` for exactly
@@ -185,10 +184,6 @@ def make_shard_map_train(cfg: TrainConfig,
     vma = not cfg.model.use_pallas and zero < 2
 
     def smap(f, in_specs, out_specs):
-        # utils/backend.shard_map: the check_vma/check_rep API-graduation
-        # compat shim every shard_map site shares — without it this whole
-        # backend (and its slow-marked, hence tier-1-invisible, test
-        # suite) failed at first use on this container's jax 0.4.37
         from dcgan_tpu.utils.backend import shard_map
 
         return shard_map(f, mesh=mesh, in_specs=in_specs,

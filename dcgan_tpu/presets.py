@@ -106,11 +106,10 @@ def sagan64(**overrides) -> TrainConfig:
     """
     cfg = _build(ModelConfig(output_size=64, attn_res=32,
                              spectral_norm="gd",
-                             # measured-best execution split (r5 chip probe:
-                             # 10.75 vs 15.70 ms/step, +46% throughput):
-                             # attention on the flash kernels, BN on XLA —
-                             # fused-BN Pallas loses ~20% at these shapes
-                             # (DESIGN.md §8b) while flash wins at S=1024.
+                             # the execution split DESIGN.md §8b argues
+                             # for (restructure, don't re-fuse): attention
+                             # on the flash kernels, BN on XLA. Its timing
+                             # is not measured on the current machine.
                              # Composes with every mesh: per-shard nested
                              # shard_map on DP gspmd (attn_apply's
                              # pallas_mesh route), ring x flash under
@@ -131,9 +130,8 @@ def sagan128(**overrides) -> TrainConfig:
     Same recipe as sagan64 otherwise (hinge, SN both nets, TTUR, EMA)."""
     cfg = _build(ModelConfig(output_size=128, attn_res=64,
                              spectral_norm="gd",
-                             # same measured-best split as sagan64: flash
-                             # attention + XLA BN (S=4096 is deeper into
-                             # flash's winning regime, DESIGN.md §8)
+                             # same split as sagan64: flash attention +
+                             # XLA BN (DESIGN.md §8)
                              use_pallas=True, bn_pallas=False),
                  MeshConfig(),
                  batch_size=64, loss="hinge", beta1=0.0,
@@ -145,18 +143,17 @@ def sagan128(**overrides) -> TrainConfig:
 def sagan256_lc(**overrides) -> TrainConfig:
     """The long-context configuration: 256x256 DCGAN stacks with attention
     over the 128x128 feature map — a 16 384-token sequence — on the flash
-    kernels (use_pallas). This is the config the chip measurements pin as
-    flash-ONLY at the reference's batch 64: XLA's dense lowering needs a
-    64 GiB f32[64, 16384, 16384] score buffer and cannot allocate, while
-    the flash path trains at 51.3 img/s (BASELINE.md dcgan256-attn128-*
-    rows; DESIGN.md §8/8b). SAGAN recipe (hinge, SN on D, TTUR, EMA); SN
+    kernels (use_pallas). This config is flash-ONLY at the reference's
+    batch 64: XLA's dense lowering needs a 64 GiB f32[64, 16384, 16384]
+    score buffer and cannot allocate (DESIGN.md §8/8b; its rate on the
+    flash path is not measured on the current machine). SAGAN recipe (hinge, SN on D, TTUR, EMA); SN
     is D-only here — G's 2048-channel early stages make G-side power
     iteration the dominant non-attention cost at this depth."""
     cfg = _build(ModelConfig(output_size=256, attn_res=128,
                              spectral_norm="d", use_pallas=True,
                              # r5: BN back on XLA — use_pallas exists here
                              # for the flash ATTENTION path; the fused-BN
-                             # half measurably loses (DESIGN.md §8b)
+                             # half re-fuses what XLA fuses (DESIGN.md §8b)
                              bn_pallas=False),
                  MeshConfig(),
                  # shard_map backend: use_pallas + attn_res composes with

@@ -30,9 +30,7 @@ State carry rules (DESIGN.md §6j):
   shardings are equivalent (the common case — one mesh, one rule table,
   same path+shape => same spec, so ZeRO-2/3 resident shards carry
   without movement); a spec change reshards through the elastic host
-  path (`elastic/reshard.put_host_tree` per leaf), and any host-staged
-  leaf forces a donation-safety rebase of the merged tree when the
-  persistent compile cache is active (DESIGN §6d).
+  path (`elastic/reshard.put_host_tree` per leaf).
 """
 
 from __future__ import annotations
@@ -85,14 +83,10 @@ def carry_path(path: str, *, arch: str, shift: int) -> Optional[str]:
 
 
 def carry_state(old_state: Pytree, new_state: Pytree, *, arch: str,
-                shift: int) -> Tuple[Pytree, int, bool]:
+                shift: int) -> Tuple[Pytree, int]:
     """Merge an old phase's live state into a fresh new-phase init.
 
-    Returns (merged tree, carried-leaf count, host_staged) — host_staged
-    is True when any carried leaf crossed shardings through the elastic
-    host path (the caller rebases the merged tree onto XLA buffers when
-    the persistent cache is active, DESIGN §6d).
-    """
+    Returns (merged tree, carried-leaf count)."""
     import jax
 
     from dcgan_tpu.elastic.rules import path_str
@@ -103,11 +97,10 @@ def carry_state(old_state: Pytree, new_state: Pytree, *, arch: str,
         if new_home is not None:
             old_by_path[new_home] = leaf
 
-    staged = False
     carried = 0
 
     def merge(path, fresh):
-        nonlocal staged, carried
+        nonlocal carried
         old = old_by_path.get(path_str(path))
         if old is None:
             return fresh
@@ -126,11 +119,10 @@ def carry_state(old_state: Pytree, new_state: Pytree, *, arch: str,
         # reshard through the elastic host path, per-shard upload
         from dcgan_tpu.elastic.reshard import put_host_tree
 
-        staged = True
         return put_host_tree(jax.device_get(old), fresh)
 
     merged = jax.tree_util.tree_map_with_path(merge, new_state)
-    return merged, carried, staged
+    return merged, carried
 
 
 class PhaseRuntime:
@@ -246,18 +238,8 @@ class PhaseRuntime:
         shift = cfg_i.model.num_up_layers - old_cfg.model.num_up_layers
         fresh = pt_i.init(jax.random.key(
             self.base_cfg.seed + 1000 + self.index))
-        merged, carried, staged = carry_state(
+        merged, carried = carry_state(
             state, fresh, arch=cfg_i.model.arch, shift=shift)
-        if staged:
-            from dcgan_tpu.utils.checkpoint import persistent_cache_active
-
-            if persistent_cache_active():
-                # host-staged leaves must not be donated into deserialized
-                # executables (DESIGN §6d) — one identity pass rebases the
-                # whole merged tree onto XLA-owned buffers
-                from dcgan_tpu.train.rollback import device_copy
-
-                merged = device_copy(merged)
         self.last_carried = carried
         self.last_switch_ms = (time.perf_counter() - t0) * 1e3
         return merged
@@ -396,7 +378,7 @@ class PhaseRuntime:
             if cfg_i.activation_summary_steps:
                 pt_i.summarize(st, imgs, key, *lbls)
             # the identity-copy signatures the run dispatches later: the
-            # switch's donation rebase (full state) and the single-process
+            # rollback snapshot (full state) and the single-process
             # histogram snapshot (params subtree)
             st = device_copy(st)
             device_copy(st["params"])

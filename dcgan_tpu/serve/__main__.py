@@ -73,8 +73,10 @@ def build_parser() -> argparse.ArgumentParser:
                    help="deadline flush: max time the oldest request "
                         "waits for batchmates")
     p.add_argument("--compile_cache_dir", default="",
-                   help="persistent compile cache (warm restarts "
-                        "deserialize the bucket programs)")
+                   help="persistent compile cache: warm restarts "
+                        "deserialize the bucket programs (default: "
+                        "JAX_COMPILATION_CACHE_DIR when set, else "
+                        ".jax_cache/ in the checkout)")
     p.add_argument("--fleet", type=int, default=0,
                    help="run N health-checked replicas behind the "
                         "failover router (0 = single bare server)")
@@ -131,20 +133,19 @@ def _load_arrivals(args) -> List[dict]:
     return out
 
 
-def main(argv: Optional[List[str]] = None) -> int:
-    args = build_parser().parse_args(argv)
-    if args.platform:
-        import jax
-
-        jax.config.update("jax_platforms", args.platform)
-    from dcgan_tpu.analysis import tripwire
-
-    tripwire.maybe_install()  # DCGAN_THREAD_CHECKS=1 honors the drill env
+def build_server(args: argparse.Namespace):
+    """(server, fleet-or-None) from parsed flags — the service as `main`
+    starts it. Separate from `main` so a caller that wants the responses
+    themselves and not a load replay (chip_smoke.py) still constructs
+    exactly what `python -m dcgan_tpu.serve` constructs."""
     from dcgan_tpu.config import MODEL_OVERRIDE_FLAGS
     from dcgan_tpu.serve.buckets import parse_buckets
     from dcgan_tpu.serve.fleet import ServeFleet
     from dcgan_tpu.serve.server import SamplerServer
     from dcgan_tpu.serve.sources import ArtifactSource, CheckpointSource
+    from dcgan_tpu.train.warmup import resolve_cache_dir
+
+    cache_dir = resolve_cache_dir(args.compile_cache_dir, entry_point=True)
 
     def _make_source():
         if args.artifact:
@@ -156,26 +157,36 @@ def main(argv: Optional[List[str]] = None) -> int:
 
     ladder = parse_buckets(args.buckets) if args.buckets else None
     fleet_n = max(0, args.fleet)
-    fleet = None
     if fleet_n:
         fleet = ServeFleet(
             [_make_source() for _ in range(fleet_n)],
             buckets=(ladder.buckets if ladder is not None else None),
             max_batch=args.max_batch, max_queue=args.max_queue,
             max_wait_ms=args.max_wait_ms,
-            cache_dir=args.compile_cache_dir, seed=args.seed,
+            cache_dir=cache_dir, seed=args.seed,
             heartbeat_secs=args.heartbeat_secs,
             miss_beats=args.miss_beats,
             watch_promotions=args.watch_promotions,
             watch_interval_secs=args.watch_interval_secs)
-        server = fleet.servers[0]   # banner/cold-start reporting
-    else:
-        server = SamplerServer(_make_source(), ladder=ladder,
-                               max_batch=args.max_batch,
-                               max_queue=args.max_queue,
-                               max_wait_ms=args.max_wait_ms,
-                               cache_dir=args.compile_cache_dir,
-                               seed=args.seed)
+        return fleet.servers[0], fleet   # servers[0]: banner/cold-start
+    return SamplerServer(_make_source(), ladder=ladder,
+                         max_batch=args.max_batch,
+                         max_queue=args.max_queue,
+                         max_wait_ms=args.max_wait_ms,
+                         cache_dir=cache_dir, seed=args.seed), None
+
+
+def main(argv: Optional[List[str]] = None) -> int:
+    args = build_parser().parse_args(argv)
+    if args.platform:
+        import jax
+
+        jax.config.update("jax_platforms", args.platform)
+    from dcgan_tpu.analysis import tripwire
+
+    tripwire.maybe_install()  # DCGAN_THREAD_CHECKS=1 honors the drill env
+    server, fleet = build_server(args)
+    fleet_n = len(fleet.servers) if fleet is not None else 0
 
     # graceful drain on SIGTERM/SIGINT: the handler only flips a flag —
     # the main thread breaks out of the load loop and runs the drain

@@ -127,10 +127,10 @@ class ServeWorker:
     def _cold_start(self) -> None:
         s = self._server
         t0 = time.perf_counter()
-        if s.cache_dir:
-            from dcgan_tpu.train import warmup
+        from dcgan_tpu.train import warmup
 
-            warmup.configure_compile_cache(s.cache_dir)
+        if warmup.configure_compile_cache(
+                warmup.resolve_cache_dir(s.cache_dir)) is not None:
             s._monitor = warmup.CompileCacheMonitor()
         s.meta = s.source.prepare()
         t_restore = time.perf_counter()

@@ -229,11 +229,12 @@ def build_parser() -> argparse.ArgumentParser:
                    help="per-layer activation histogram cadence (0 = off)")
     # warm start (DESIGN.md §6d)
     p.add_argument("--compile_cache_dir", default="",
-                   help="non-empty wires JAX's persistent compilation "
-                        "cache here (DCGAN_COMPILE_CACHE_DIR env honored "
-                        "when unset): restarts deserialize already-seen "
-                        "programs instead of recompiling; adoption is "
-                        "surfaced as perf/compile_cache_* counters")
+                   help="keep JAX's persistent compilation cache here "
+                        "(default: JAX_COMPILATION_CACHE_DIR when set, "
+                        "else .jax_cache/ in the checkout): restarts "
+                        "deserialize already-seen programs instead of "
+                        "recompiling; adoption is surfaced as "
+                        "perf/compile_cache_* counters")
     p.add_argument("--compile_cache_per_process", type=_parse_bool,
                    default=False, metavar="{true,false}",
                    help="multi-host without a shared filesystem: each "
@@ -335,7 +336,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "*.consumed and the switch record lands in *.ack")
     p.add_argument("--steps_per_call", type=int, default=1,
                    help=">1 dispatches K steps as one compiled scan program "
-                        "(sheds per-dispatch RPC overhead; observability "
+                        "(sheds per-dispatch host overhead; observability "
                         "cadences must be multiples of K)")
     p.add_argument("--backend", choices=["gspmd", "shard_map"],
                    default="gspmd",
@@ -373,8 +374,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "analogue for image models)")
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--platform", default=None,
-                   help="force a JAX platform (e.g. cpu for local debug; "
-                        "overrides plugins that pin jax_platforms at startup)")
+                   help="force a JAX platform (e.g. cpu for local debug)")
     return p
 
 
@@ -536,8 +536,7 @@ def main(argv: Optional[List[str]] = None) -> None:
 
     if cfg.comm_overlap != "off":
         # Arm XLA's async-collective scheduler before jax initializes its
-        # backend (TPU-only inside the helper — unknown XLA_FLAGS entries
-        # are fatal on other backends, so the helper also honors an
+        # backend (TPU-only inside the helper, which also honors an
         # explicit non-TPU --platform/JAX_PLATFORMS request). This is the
         # gspmd half of the backward-overlap story (DESIGN §6n); the
         # shard_map half is the bucketed/staged hook placement itself.
@@ -551,6 +550,12 @@ def main(argv: Optional[List[str]] = None) -> None:
     if args.platform:
         import jax
         jax.config.update("jax_platforms", args.platform)
+
+    # the entry point's cache placement: train() itself leaves a process
+    # without a cache alone, so the in-checkout default is put in force here
+    from dcgan_tpu.train import warmup
+    warmup.configure_compile_cache(
+        warmup.resolve_cache_dir(cfg.compile_cache_dir, entry_point=True))
 
     from dcgan_tpu.train.trainer import train
     train(cfg, synthetic_data=args.synthetic)

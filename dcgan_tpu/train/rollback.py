@@ -126,13 +126,7 @@ class RollbackManager:
             self._shardings = jax.tree_util.tree_map(
                 lambda x: x.sharding if hasattr(x, "sharding") else None,
                 state)
-            # owned_host_copy, not bare device_get: under the persistent
-            # compile cache, device_get's zero-copy views get donated over
-            # in place by deserialized executables, and the "snapshot"
-            # would silently track the very divergence it exists to flee
-            from dcgan_tpu.utils.checkpoint import owned_host_copy
-
-            self._snap = owned_host_copy(state)
+            self._snap = jax.device_get(state)
         self._snap_step = int(step)
 
     def restore(self, exc: FloatingPointError) -> tuple:
@@ -166,21 +160,6 @@ class RollbackManager:
                 lambda host, sh: jax.device_put(host, sh)
                 if sh is not None else host,
                 self._snap, self._shardings)
-            from dcgan_tpu.utils.checkpoint import persistent_cache_active
-
-            if persistent_cache_active():
-                # device_put buffers are not XLA-executable outputs, and
-                # DONATING any such buffer into an executable DESERIALIZED
-                # from the persistent compile cache corrupts the heap
-                # (jaxlib 0.4.37 CPU — same class as checkpoint.py's
-                # _rebase_onto_xla_buffers; empirically owned device_put
-                # buffers crash too, not just externally-referenced ones).
-                # One non-donating identity copy rebases the restored
-                # state onto XLA-owned buffers before the trainer's
-                # donated step programs touch it; the AOT warmup plan
-                # pre-compiles this exact variant ("state_copy@restore")
-                # so no compile runs in the guarded restore window.
-                state = device_copy(state)
         return state, self._snap_step
 
     def lr_scale(self) -> float:

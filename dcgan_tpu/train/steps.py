@@ -365,16 +365,9 @@ def make_train_step(cfg: TrainConfig, *, axis_name: Optional[str] = None,
         # Under shard_map (axis_name set) the critic-scan metric carry must
         # be data-axis-VARYING to match the loop body's per-device metric
         # outputs — an unvarying f32 zero fails the scan's carry-type check
-        # at trace time. `lax.pcast` only exists once the VMA type system
-        # graduated (jax >= 0.6); this container's 0.4.37 experimental
-        # shard_map has no replicated->varying cast, and its check_rep
-        # tracker accepts the plain replicated zero as a carry init — so
-        # fall back to it instead of crashing every shard_map stage-program
-        # trace at `lax.pcast` (caught by the semantic analyzer, DCG009).
+        # at trace time.
         z0 = jnp.zeros((), jnp.float32)
-        pcast = getattr(lax, "pcast", None)
-        return pcast(z0, axis_name, to="varying") \
-            if (axis_name and pcast is not None) else z0
+        return lax.pcast(z0, axis_name, to="varying") if axis_name else z0
 
     def _d_metrics(d_loss, d_real, d_fake, gp) -> dict:
         # the discriminator half of the step's metric row — the fused

@@ -703,7 +703,7 @@ def _train_run(cfg: TrainConfig, *, synthetic_data: bool,
     # first live steps.
     # "fleet-warm": every process's live dispatches will HIT the primed
     # cache — true single-process and in the shared-dir multi-host mode,
-    # false for per-process dirs under multi-host (jaxlib writes entries
+    # false for per-process dirs under multi-host (JAX writes entries
     # chief-only, so non-chief proc<i>/ stores never fill and their live
     # dispatches still compile). Everything that assumes warm hits —
     # watchdog warm proof, the compiled_ks seed, the pre-warmed backoff
@@ -717,13 +717,14 @@ def _train_run(cfg: TrainConfig, *, synthetic_data: bool,
                         # switch prints its compile-request delta from here
     if cfg.aot_warmup:
         if chief and cache_dir is None:
-            print("[dcgan_tpu] --aot_warmup without --compile_cache_dir: "
+            print("[dcgan_tpu] --aot_warmup without a compile cache: "
                   "warmed programs are recompiled at first live dispatch "
-                  "(compile timings still recorded); set a cache dir so "
+                  "(compile timings still recorded); set "
+                  "--compile_cache_dir or JAX_COMPILATION_CACHE_DIR so "
                   "dispatches deserialize the warmed entries", flush=True)
         if chief and cache_dir is not None and not cache_fleet_wide:
             print("[dcgan_tpu] --compile_cache_per_process under "
-                  "multi-host: this jaxlib writes cache entries from the "
+                  "multi-host: JAX writes cache entries from the "
                   "chief only, so non-chief proc<i>/ stores stay empty — "
                   "warm restarts still recompile there, and warmup is NOT "
                   "used as watchdog warm proof (use one shared "
@@ -1024,21 +1025,13 @@ def _train_run(cfg: TrainConfig, *, synthetic_data: bool,
             snap = device_copy(params)
             _stage(snap)
             return snap
-        # owned_host_copy, not bare device_get: the histogram must capture
-        # THIS step's params, not whatever the next donated dispatch
-        # leaves in the buffer a cache-deserialized executable overwrote
-        # in place (utils/checkpoint.owned_host_copy owns the workaround)
-        from dcgan_tpu.utils.checkpoint import owned_host_copy
-
-        return owned_host_copy(params)
+        return jax.device_get(params)
 
     def _host_vals(p: dict) -> dict:
         """Materialized {name: float} metric scalars for one step's record,
         cached on the record — ONE transfer shared by every consumer
         (NaN gate, step log, summary writer); per-scalar float() would
-        issue a device round-trip each (~0.65 ms/step measured over a
-        high-latency transport, tools/bench_trainer_loop.py's 3.75 vs
-        3.09 ms/step gap)."""
+        issue a device round-trip each."""
         nonlocal mesh_warm
         if p.get("host") is None:
             p["host"] = {k: float(v) for k, v in

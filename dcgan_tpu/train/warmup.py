@@ -9,8 +9,10 @@ compilation caching as first-class throughput infrastructure and ParaGAN
 (arxiv 2411.03999) frames GAN efficiency as end-to-end goodput; this module
 is that discipline for tpu-dcgan's time-to-first-step:
 
-- `configure_compile_cache` wires JAX's persistent compilation cache behind
-  `--compile_cache_dir` (config + CLI + `DCGAN_COMPILE_CACHE_DIR` env). The
+- `resolve_cache_dir` + `configure_compile_cache` place JAX's persistent
+  compilation cache, for every entry point alike: `--compile_cache_dir`,
+  else `JAX_COMPILATION_CACHE_DIR`, else (entry points only) the fixed
+  `CHECKOUT_CACHE_DIR`. The
   multi-host keying is safe by construction: JAX's cache layer only WRITES
   entries from process 0 (chief-writes) while every process reads, so one
   shared directory never sees write contention; for fleets without a shared
@@ -55,7 +57,16 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 
-CACHE_ENV_VAR = "DCGAN_COMPILE_CACHE_DIR"
+#: jax's own variable: where it is set the cache is kept there, and the
+#: only thing that overrides it is an explicit --compile_cache_dir
+CACHE_ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+
+#: the entry points' default when neither flag nor variable names a
+#: directory. Fixed and inside the checkout (git-ignored): the path is
+#: part of the cache key, so a directory that moves never hits.
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 #: monitoring event name -> counter key (the three adoption counters JAX's
 #: compile path records around the persistent cache)
@@ -67,42 +78,46 @@ _EVENT_COUNTERS = {
 _SAVED_EVENT = "/jax/compilation_cache/compile_time_saved_sec"
 
 
-def resolve_cache_dir(cfg_dir: str, env=None) -> str:
-    """The effective cache dir: the config/CLI value, else the
-    DCGAN_COMPILE_CACHE_DIR environment override, else "" (off)."""
+def resolve_cache_dir(cfg_dir: str = "", *, entry_point: bool = False,
+                      env=None) -> str:
+    """Where the persistent compile cache lives — the one decision the
+    trainer, the server, bench.py, chip_smoke.py and the tools share: the
+    config/CLI value, else JAX_COMPILATION_CACHE_DIR, else
+    CHECKOUT_CACHE_DIR for an entry point (the CLI mains, bench.py,
+    chip_smoke.py) and "" for a library caller — which
+    `configure_compile_cache` reads as "leave the process's setting
+    alone"."""
     env = os.environ if env is None else env
-    return cfg_dir or env.get(CACHE_ENV_VAR, "")
+    return (cfg_dir or env.get(CACHE_ENV_VAR, "")
+            or (CHECKOUT_CACHE_DIR if entry_point else ""))
 
 
 def configure_compile_cache(cache_dir: str, *,
                             per_process: bool = False) -> Optional[str]:
     """Point JAX's persistent compilation cache at `cache_dir`; returns the
     effective directory (per-process subdir under `per_process`) or None
-    when caching stays off. Must run before the first compile — the trainer
-    calls it right after `initialize_multihost()` (the per-process keying
-    needs the real process index), before any program is built.
+    when the process has no cache. An empty `cache_dir` leaves the
+    process's setting alone — whatever JAX_COMPILATION_CACHE_DIR or an
+    entry point put in force stays in force, and is what gets returned.
+    Must run before the first compile — the trainer calls it right after
+    `initialize_multihost()` (the per-process keying needs the real
+    process index), before any program is built.
     """
+    cache_dir = cache_dir or jax.config.jax_compilation_cache_dir
     if not cache_dir:
-        # explicit OFF: a previous train() in this process may have pointed
-        # the GLOBAL jax cache somewhere — leaving it set would keep
-        # deserializing executables in a run whose donation-safety guards
-        # (trainer/rollback/checkpoint, keyed on the cache being active)
-        # believe the cache is off
-        if getattr(jax.config, "jax_compilation_cache_dir", None):
-            jax.config.update("jax_compilation_cache_dir", None)
-            _reset_cache_object()
         return None
     if per_process and jax.process_count() > 1:
-        # no shared filesystem: disjoint per-process stores. Keys are
-        # process-independent, so this trades dedup for zero cross-host
-        # filesystem assumptions. jaxlib <= 0.4.37 only WRITES cache
-        # entries from process 0, so non-chief stores stay empty (reads
-        # are harmless) — the trainer excludes this mode from watchdog
-        # warm proof and warns, rather than arming deadlines over peers
-        # that will in fact recompile.
-        cache_dir = os.path.join(cache_dir, f"proc{jax.process_index()}")
-    changed = getattr(jax.config, "jax_compilation_cache_dir",
-                      None) != cache_dir
+        # no shared filesystem: disjoint per-process stores under whichever
+        # root is in force. Keys are process-independent, so this trades
+        # dedup for zero cross-host filesystem assumptions. JAX only WRITES
+        # cache entries from process 0, so non-chief stores stay empty
+        # (reads are harmless) — the trainer excludes this mode from
+        # watchdog warm proof and warns, rather than arming deadlines over
+        # peers that will in fact recompile.
+        sub = f"proc{jax.process_index()}"
+        if os.path.basename(cache_dir) != sub:  # a repeat train() call
+            cache_dir = os.path.join(cache_dir, sub)
+    changed = jax.config.jax_compilation_cache_dir != cache_dir
     os.makedirs(cache_dir, exist_ok=True)
     jax.config.update("jax_compilation_cache_dir", cache_dir)
     # cache EVERY program: this trainer compiles a handful of long-lived
@@ -121,12 +136,9 @@ def _reset_cache_object() -> None:
     """Drop jax's memoized persistent-cache object so the current
     `jax_compilation_cache_dir` value takes effect (jax initializes the
     object lazily ONCE and never re-reads the config)."""
-    try:
-        from jax._src import compilation_cache
+    from jax.experimental.compilation_cache import compilation_cache
 
-        compilation_cache.reset_cache()
-    except Exception:
-        pass  # future jax: internal module moved; first-use init wins
+    compilation_cache.reset_cache()
 
 
 def cache_serves_all_processes(per_process: bool) -> bool:
@@ -134,10 +146,10 @@ def cache_serves_all_processes(per_process: bool) -> bool:
     the condition watchdog warm proof rides on. True for single-process
     and for the shared-dir multi-host mode (the chief writes during its
     AOT compiles, the warmup barrier orders those writes before any peer's
-    live dispatch reads them). False for per-process dirs under multi-host
-    on jaxlib <= 0.4.37: only process 0's store is ever written, so every
-    other process recompiles at first live dispatch no matter how warm its
-    warmup looked."""
+    live dispatch reads them). False for per-process dirs under multi-host:
+    only process 0's store is ever written, so every other process
+    recompiles at first live dispatch no matter how warm its warmup
+    looked."""
     return jax.process_count() == 1 or not per_process
 
 
@@ -152,9 +164,6 @@ class CompileCacheMonitor:
     """
 
     def __init__(self) -> None:
-        from jax._src import monitoring
-
-        self._monitoring = monitoring
         self._counts: Dict[str, int] = {k: 0 for k in
                                         _EVENT_COUNTERS.values()}
         self._saved_secs = 0.0
@@ -171,8 +180,8 @@ class CompileCacheMonitor:
 
         self._on_event = _on_event
         self._on_duration = _on_duration
-        monitoring.register_event_listener(_on_event)
-        monitoring.register_event_duration_secs_listener(_on_duration)
+        jax.monitoring.register_event_listener(_on_event)
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
 
     def counters(self) -> Dict[str, float]:
         out: Dict[str, float] = dict(self._counts)
@@ -188,16 +197,8 @@ class CompileCacheMonitor:
         if self._closed:
             return
         self._closed = True
-        for unreg, cb in (
-                (self._monitoring._unregister_event_listener_by_callback,
-                 self._on_event),
-                (self._monitoring
-                 ._unregister_event_duration_listener_by_callback,
-                 self._on_duration)):
-            try:
-                unreg(cb)
-            except Exception:
-                pass  # listener registry changed under us — nothing to leak
+        jax.monitoring.unregister_event_listener(self._on_event)
+        jax.monitoring.unregister_event_duration_listener(self._on_duration)
 
 
 def backoff_config(cfg, scale: float):

@@ -127,64 +127,6 @@ def _dir_checksums(step_dir: str) -> Dict[str, Dict[str, int]]:
     return out
 
 
-_IDENTITY_COPY = None
-
-
-def _rebase_onto_xla_buffers(tree: Pytree) -> Pytree:
-    """Rebase a just-restored tree onto fresh XLA-owned buffers via one
-    non-donating jitted identity pass (the rollback.device_copy idiom).
-
-    Workaround for a jaxlib 0.4.37 CPU interaction the warm-start work
-    surfaced: DONATING a tensorstore-backed buffer (what Orbax restore
-    returns) into an executable DESERIALIZED from the persistent
-    compilation cache corrupts the heap (malloc_consolidate/SIGSEGV a few
-    dispatches later). Reading such buffers is fine — only donation is
-    broken — so one identity copy whose outputs are ordinary XLA
-    allocations makes the restored state safe to feed the trainer's
-    donated step programs. Applied only when the persistent cache is
-    configured (the only regime that deserializes executables); costs one
-    device-side copy of the state, value- and sharding-preserving, and is
-    a mesh-consistent per-shard program under multi-host (every process
-    dispatches it at the same point, like the rollback snapshot copy)."""
-    global _IDENTITY_COPY
-    if _IDENTITY_COPY is None:
-        _IDENTITY_COPY = jax.jit(
-            lambda t: jax.tree_util.tree_map(lambda a: a + 0, t))
-    return _IDENTITY_COPY(tree)
-
-
-def persistent_cache_active() -> bool:
-    """Whether JAX's persistent compilation cache is configured — the only
-    regime that runs DESERIALIZED executables, where donated non-XLA-owned
-    buffers are unsafe (see _rebase_onto_xla_buffers; train/rollback.py
-    applies the same rebase to its host-snapshot restore path)."""
-    try:
-        return bool(jax.config.jax_compilation_cache_dir)
-    except AttributeError:  # future jax: config knob renamed/removed
-        return False
-
-
-def owned_host_copy(tree: Pytree) -> Pytree:
-    """`jax.device_get` whose result is safe to hold across donated
-    dispatches when the persistent cache is active.
-
-    On CPU, device_get returns zero-copy numpy VIEWS of the XLA buffers.
-    Executables DESERIALIZED from the persistent compilation cache donate
-    those buffers in place even while a view is alive (jaxlib 0.4.37 —
-    fresh-compiled executables copy instead when external references
-    exist), so a host "snapshot" would silently track the live state. One
-    owned copy per leaf breaks the aliasing; skipped when the cache is off
-    (no deserialized executables, the views behave). The ONE site holding
-    this workaround's knowledge — the rollback snapshot and the trainer's
-    multi-process histogram capture both call it."""
-    host = jax.device_get(tree)
-    if not persistent_cache_active():
-        return host
-    import numpy as np
-
-    return jax.tree_util.tree_map(lambda x: np.array(x, copy=True), host)
-
-
 def has_restorable_checkpoint(directory: str) -> bool:
     """True iff `directory` holds at least one completed Orbax step dir.
 
@@ -691,8 +633,8 @@ class Checkpointer:
         shards). Different process count: the arrays restore host-side
         (numpy, full arrays, no device staging copy) and
         `make_array_from_callback` uploads each device's shard
-        (elastic/reshard.py). Verification, quarantine fallback, and the
-        donation-safety rebase are IDENTICAL on both paths; a missing or
+        (elastic/reshard.py). Verification and quarantine fallback are
+        IDENTICAL on both paths; a missing or
         unreadable sidecar — or a matching topology — takes the exact
         pre-elastic path, so same-topology restores are byte-identical in
         behavior (the parity contract). `last_reshard` records the event.
@@ -747,8 +689,7 @@ class Checkpointer:
                     reshard_info["reshard_ms"] = \
                         (time.perf_counter() - t0) * 1e3
                     self.last_reshard = reshard_info
-                return _rebase_onto_xla_buffers(restored) \
-                    if persistent_cache_active() else restored
+                return restored
             bad = self._stat_precheck(step, files)
             if bad is not None:
                 self._mark_corrupt(step, bad)
@@ -811,8 +752,7 @@ class Checkpointer:
             if reshard_info is not None:
                 reshard_info["reshard_ms"] = restore_ms
                 self.last_reshard = reshard_info
-            return _rebase_onto_xla_buffers(restored) \
-                if persistent_cache_active() else restored
+            return restored
         return None
 
     def wait(self) -> None:

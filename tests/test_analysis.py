@@ -185,62 +185,6 @@ class ServeWorker:
         assert fs == []
 
 
-# -- DCG002: donation hazard -------------------------------------------------
-
-class TestDonationHazard:
-    def test_device_get_into_donating_jit_flagged(self):
-        src = '''
-import jax
-step = jax.jit(lambda s: s, donate_argnums=(0,))
-
-def resume(state):
-    restored = jax.device_get(state)
-    return step(restored)
-'''
-        fs = run({"dcgan_tpu/x.py": src}, checks=["DCG002"])
-        assert [f.key for f in fs] == ["step(restored)"]
-
-    def test_pt_dispatch_with_device_put_value_flagged(self):
-        src = '''
-import jax
-
-def loop(pt, host_state, images, key):
-    state = jax.device_put(host_state)
-    state, metrics = pt.step(state, images, key)
-    return state
-'''
-        fs = run({"dcgan_tpu/x.py": src}, checks=["DCG002"])
-        assert [f.key for f in fs] == ["pt.step(state)"]
-
-    def test_sanitized_twin_clean(self):
-        src = '''
-import jax
-from dcgan_tpu.utils.checkpoint import owned_host_copy
-step = jax.jit(lambda s: s, donate_argnums=(0,))
-
-def resume(state):
-    restored = owned_host_copy(state)
-    return step(restored)
-
-def rebased(mgr, abstract):
-    from dcgan_tpu.utils.checkpoint import _rebase_onto_xla_buffers
-    restored = _rebase_onto_xla_buffers(mgr.restore(abstract))
-    return step(restored)
-'''
-        assert run({"dcgan_tpu/x.py": src}, checks=["DCG002"]) == []
-
-    def test_non_donating_jit_clean(self):
-        src = '''
-import jax
-probe = jax.jit(lambda s: s)
-
-def peek(state):
-    host = jax.device_get(state)
-    return probe(host)
-'''
-        assert run({"dcgan_tpu/x.py": src}, checks=["DCG002"]) == []
-
-
 # -- DCG003: raw shard_map ---------------------------------------------------
 
 class TestRawShardMap:
@@ -946,6 +890,16 @@ class TestProgramManifest:
             "--semantic --stream-table` and paste between the markers")
 
 
+def test_fingerprint_ignores_frozenset_print_order():
+    """A shard_map equation prints `manual_axes=frozenset({...})` in the
+    order the process's string-hash seed gives it; the committed
+    fingerprints must not depend on that."""
+    a = "shard_map[manual_axes=frozenset({'data', 'model'}) x=0x7f00] a"
+    b = "shard_map[manual_axes=frozenset({'model', 'data'}) x=0x5a1c] a"
+    assert semantic._sanitized(a) == semantic._sanitized(b)
+    assert "frozenset({'data', 'model'})" in semantic._sanitized(b)
+
+
 class TestRetraceHazards:
     """DCG009: baked-in consts, weak-typed leaks, warmup coverage."""
 
@@ -1025,9 +979,7 @@ class TestTracedBodySemanticHygiene:
             ["transfer:fx::prog:device_put"]
 
     def test_f64_promotion_flagged(self):
-        from jax.experimental import enable_x64
-
-        with enable_x64():
+        with _jax.enable_x64(True):
             a = _audit(_jax.jit(lambda x: x.astype(_jnp.float64) * 2),
                        (_jnp.ones((2,), _jnp.float32),))
         fs = semantic.check_hygiene([a])

@@ -392,7 +392,7 @@ class TestPipeline:
         assert b2.shape == (16, 8, 8, 3)
 
     def test_float64_on_accelerator_warns(self, tmp_path, monkeypatch):
-        """The parity wire format is input-bound at chip rates (BASELINE.md);
+        """The parity wire format is the one most likely to starve a chip;
         the pipeline must say so when a float64 corpus meets a non-CPU
         consumer (VERDICT r3 #6) — and stay quiet for uint8."""
         import types

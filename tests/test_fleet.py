@@ -436,30 +436,9 @@ def inject_step(donor_dir, serve_dir, step):
     os.rename(tmp, os.path.join(serve_dir, str(step)))
 
 
-@pytest.fixture
-def _pristine_cache_state():
-    """Point the process-global persistent cache at a tmp dir without
-    leaking into later tests (the test_serve discipline)."""
-    import jax
-
-    prev = {
-        "jax_compilation_cache_dir": jax.config.jax_compilation_cache_dir,
-        "jax_persistent_cache_min_compile_time_secs":
-            jax.config.jax_persistent_cache_min_compile_time_secs,
-        "jax_persistent_cache_min_entry_size_bytes":
-            jax.config.jax_persistent_cache_min_entry_size_bytes,
-    }
-    yield
-    for k, v in prev.items():
-        jax.config.update(k, v)
-    from jax._src import compilation_cache
-
-    compilation_cache.reset_cache()
-
-
 class TestPromotionEndToEnd:
     def test_zero_recompile_promotion_serves_new_weights(
-            self, promotable_ckpt, tmp_path, _pristine_cache_state):
+            self, promotable_ckpt, tmp_path):
         """The acceptance pin: a newly finalized step injected mid-serve
         promotes with compile_requests_delta == 0 (measured by the live
         CompileCacheMonitor across the swap + re-prime) and the swapped

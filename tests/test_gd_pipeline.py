@@ -234,10 +234,10 @@ class TestWarmupPlanStages:
 
 
 class TestShardMapStagesTrace:
-    """Regression for the `lax.pcast` latent crash (ISSUE 11 triage):
-    this container's jax 0.4.37 predates the VMA type system, so
-    steps.py::_zero_metric must fall back to the plain replicated zero
-    instead of crashing every shard_map stage-program trace — the tier-1
+    """The shard_map backend's pipelined stage programs must trace: the
+    critic-scan metric carry is cast data-axis-varying
+    (steps.py::_zero_metric, `lax.pcast`) to match the loop body's
+    per-device outputs. Once a latent crash (ISSUE 11 triage) — the tier-1
     suite never lowered these programs on this backend, and the semantic
     analyzer's first enumeration could not even complete."""
 

@@ -55,10 +55,13 @@ def _act_ref(v, act, leak=0.2):
 
 
 class TestKTile:
-    def test_divides_and_bounded(self):
-        for n in [1, 7, 25, 150, 512, 800, 1600, 12800, 999]:
+    def test_divides_and_lane_aligned_or_whole(self):
+        """What the TPU lowering takes as a block's last dim: a multiple of
+        the 128 lanes, or the whole dim (tests/test_tpu_compile.py holds
+        the kernels themselves to the chip's compiler)."""
+        for n in [1, 7, 25, 150, 512, 800, 1600, 3200, 6400, 12800, 999]:
             t = _k_tile(n)
-            assert n % t == 0 and 1 <= t <= 512
+            assert n % t == 0 and (t == n or (t % 128 == 0 and t <= 512))
 
     def test_exact_power_hits_512(self):
         assert _k_tile(4096) == 512
@@ -225,11 +228,18 @@ class TestFusedConvBnAct:
     composition the model loops replace — output AND new-state parity,
     both directions, both train modes."""
 
-    @pytest.mark.parametrize("transpose,act", [(False, "lrelu"),
-                                               (True, "relu")])
-    def test_train_parity(self, transpose, act):
-        x = _rand(0, (2, 8, 8, 6))
-        conv_p, bn_p, bn_s = _stage_params(1, 6, 10, transpose=transpose)
+    @pytest.mark.parametrize("transpose,act,in_ch", [
+        (False, "lrelu", 6), (True, "relu", 6),
+        # K = 96*25 = 2400: wider than one block may be and with no
+        # lane-aligned divisor, so the stage zero-pads K to 2432
+        pytest.param(False, "lrelu", 96, id="padded-K")])
+    def test_train_parity(self, transpose, act, in_ch):
+        from dcgan_tpu.ops import pallas_fused
+
+        assert (pallas_fused._k_padded(in_ch * 25) != in_ch * 25) \
+            == (in_ch == 96)
+        x = _rand(0, (2, 8, 8, in_ch))
+        conv_p, bn_p, bn_s = _stage_params(1, in_ch, 10, transpose=transpose)
         y, ns = fused_conv_bn_act(conv_p, bn_p, bn_s, x,
                                   transpose=transpose, kernel=5,
                                   train=True, act=act)
@@ -274,11 +284,16 @@ class TestFusedConvBnAct:
 
         gf = jax.grad(fused_loss, argnums=(0, 1))(conv_p, bn_p)
         gr = jax.grad(ref_loss, argnums=(0, 1))(conv_p, bn_p)
-        # atol floor 2e-3: BN analytically cancels the conv-bias gradient
-        # (a bias shift moves the batch mean BN subtracts), so that leaf is
-        # pure f32 cancellation noise in BOTH paths; rtol on it is
-        # meaningless while the real-signal leaves (w, gamma, beta) are
-        # O(0.1..1) and still pinned by it
+        # BN analytically cancels the conv-bias gradient (a bias shift
+        # moves the batch mean BN subtracts): that leaf's true value is 0
+        # and what each path returns is its own f32 cancellation noise,
+        # whose size follows the compiler's summation order (~2e-3 in the
+        # unfused path under the installed XLA). Comparing the two noises
+        # with each other pins nothing, so each is held to 0 instead, at
+        # 1e-2 — two orders under the real-signal leaves (w, gamma, beta,
+        # O(0.1..1)), which stay pinned against the reference.
+        for g in (gf, gr):
+            np.testing.assert_allclose(g[0].pop("b"), 0.0, atol=1e-2)
         jax.tree.map(lambda a, e: np.testing.assert_allclose(
             a, e, rtol=2e-3, atol=2e-3), gf, gr)
 

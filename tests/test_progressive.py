@@ -240,9 +240,8 @@ class TestCarry:
                                                          np.float32)}}}}
         fresh = {"params": {"disc": {"head": {"w": np.zeros((8, 1),
                                                             np.float32)}}}}
-        merged, carried, staged = carry_state(old, fresh, arch="dcgan",
-                                              shift=1)
-        assert carried == 0 and not staged
+        merged, carried = carry_state(old, fresh, arch="dcgan", shift=1)
+        assert carried == 0
         assert merged["params"]["disc"]["head"]["w"].shape == (8, 1)
 
 

@@ -380,31 +380,9 @@ def trained_ckpt(tmp_path_factory):
 OVERRIDES = {"output_size": 16, "gf_dim": 8, "df_dim": 8}
 
 
-@pytest.fixture
-def _pristine_cache_state():
-    """Serve tests point the process-global persistent cache at a tmp dir;
-    none of that may leak into later tests (the test_warmup discipline)."""
-    import jax
-
-    prev = {
-        "jax_compilation_cache_dir": jax.config.jax_compilation_cache_dir,
-        "jax_persistent_cache_min_compile_time_secs":
-            jax.config.jax_persistent_cache_min_compile_time_secs,
-        "jax_persistent_cache_min_entry_size_bytes":
-            jax.config.jax_persistent_cache_min_entry_size_bytes,
-    }
-    yield
-    for k, v in prev.items():
-        jax.config.update(k, v)
-    from jax._src import compilation_cache
-
-    compilation_cache.reset_cache()
-
-
 class TestServeEndToEnd:
     def test_zero_recompiles_after_bucket_warmup(self, trained_ckpt,
-                                                 tmp_path,
-                                                 _pristine_cache_state):
+                                                 tmp_path):
         """The acceptance pin: under a live persistent compile cache,
         NO compile request fires after the AOT bucket warmup — every
         served batch (odd sizes included) rides a precompiled bucket

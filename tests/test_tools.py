@@ -606,7 +606,9 @@ class TestZeroRollbackSmoke:
         scenarios = {p["scenario"]: p for p in lines if "scenario" in p}
         assert set(scenarios) == {"zero-rollback"}
         assert scenarios["zero-rollback"]["rollbacks"] >= 1
-        assert scenarios["zero-rollback"]["replay_bit_exact"] is True
+        # to 1e-5, not to the bit: see the scenario's docstring for what
+        # the installed XLA:CPU does to the shard-local update
+        assert scenarios["zero-rollback"]["replay_rtol"] == 1e-5
         # two tiny 2-device trainer subprocesses (~25 s each on a quiet
         # host, compile-dominated); ~4x headroom for CI contention
         assert elapsed < 300, f"zero-rollback smoke took {elapsed:.0f}s"
@@ -923,7 +925,7 @@ class TestToolsRunOnCpu:
         """tools/canonical_50k.py end to end at toy scale: random torch
         tower -> convert_torch_embedder .npz -> step-0 checkpoint ->
         `python -m dcgan_tpu.evals --feature_npz` — the exact pipeline the
-        chip row in BASELINE.md certifies at 50k, pinned here so the tool
+        tool runs at 50k on a chip, pinned here so the tool
         cannot rot (the score is arbitrary; the contract is that the
         canonical path executes and reports the requested sample count)."""
         res = subprocess.run(
@@ -992,7 +994,7 @@ class TestToolsRunOnCpu:
                  if l.startswith("{")]
         comps = {p["component"] for p in lines if "component" in p}
         assert comps == {"train_step", "fwd_losses", "g_forward",
-                         "adam_applies"}
+                         "adam_applies", "resident/train_step"}
         summ = lines[-1]
         assert summ["label"] == "step-profile"
         assert summ["step_ms"] > 0 and summ["fwd_ms"] > 0

@@ -25,24 +25,12 @@ pytestmark = pytest.mark.chaos
 
 
 @pytest.fixture(autouse=True)
-def _pristine_cache_state():
-    """The persistent-cache config and the armed chaos plan are both
-    process-global; neither may leak into later tests."""
-    prev = {
-        "jax_compilation_cache_dir": jax.config.jax_compilation_cache_dir,
-        "jax_persistent_cache_min_compile_time_secs":
-            jax.config.jax_persistent_cache_min_compile_time_secs,
-        "jax_persistent_cache_min_entry_size_bytes":
-            jax.config.jax_persistent_cache_min_entry_size_bytes,
-    }
+def _pristine_chaos_plan():
+    """The armed chaos plan is process-global and must not leak into later
+    tests (the cache placement is restored by tests/conftest.py)."""
     chaos.reset()
     yield
     chaos.reset()
-    for k, v in prev.items():
-        jax.config.update(k, v)
-    from jax._src import compilation_cache
-
-    compilation_cache.reset_cache()
 
 
 def _tiny_cfg(root, **kw):
@@ -78,12 +66,45 @@ def _startup_values(root):
 
 
 class TestCacheConfig:
-    def test_resolve_prefers_flag_then_env(self):
-        assert warmup.resolve_cache_dir("/a/b", {warmup.CACHE_ENV_VAR:
-                                                 "/c"}) == "/a/b"
-        assert warmup.resolve_cache_dir("", {warmup.CACHE_ENV_VAR: "/c"}) \
-            == "/c"
-        assert warmup.resolve_cache_dir("", {}) == ""
+    @pytest.mark.parametrize("flag,env,entry_point,want", [
+        # --compile_cache_dir overrides both the variable and the default
+        ("/a/b", {"JAX_COMPILATION_CACHE_DIR": "/c"}, True, "/a/b"),
+        ("/a/b", {}, False, "/a/b"),
+        # the variable set: that directory, for library and entry point
+        ("", {"JAX_COMPILATION_CACHE_DIR": "/c"}, False, "/c"),
+        ("", {"JAX_COMPILATION_CACHE_DIR": "/c"}, True, "/c"),
+        # unset: the fixed in-checkout path at an entry point, and "leave
+        # the process alone" for a library caller
+        ("", {}, True, warmup.CHECKOUT_CACHE_DIR),
+        ("", {}, False, ""),
+    ])
+    def test_resolve_flag_then_env_then_checkout(self, flag, env,
+                                                 entry_point, want):
+        assert warmup.resolve_cache_dir(
+            flag, entry_point=entry_point, env=env) == want
+
+    def test_checkout_dir_is_fixed_and_git_ignored(self):
+        """The path is part of the cache key: it must not move between
+        runs (no tempfile/pid/time component), and what lands in it must
+        never be committed."""
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert warmup.CHECKOUT_CACHE_DIR == os.path.join(repo, ".jax_cache")
+        with open(os.path.join(repo, ".gitignore")) as f:
+            assert ".jax_cache/" in f.read().split()
+
+    def test_train_leaves_an_outside_placement_alone(self, tmp_path):
+        """JAX_COMPILATION_CACHE_DIR lands in jax's config at import; a
+        train() with no --compile_cache_dir must keep the cache there —
+        entries written there, the setting untouched afterwards."""
+        from dcgan_tpu.train.trainer import train
+
+        outside = str(tmp_path / "outside")
+        jax.config.update("jax_compilation_cache_dir", outside)
+        train(_tiny_cfg(tmp_path), synthetic_data=True, max_steps=1)
+        assert jax.config.jax_compilation_cache_dir == outside
+        assert os.listdir(outside)
+        # and the run knew its cache was on: the adoption counters landed
+        assert _startup_values(tmp_path)["perf/compile_cache_misses"] > 0
 
     def test_configure_points_jax_at_dir(self, tmp_path):
         d = str(tmp_path / "cc")
@@ -94,20 +115,19 @@ class TestCacheConfig:
         # every program in this trainer is worth caching (DESIGN.md §6d)
         assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
 
-    def test_configure_off_resets_a_previously_set_dir(self, tmp_path):
-        """A second train() in the same process with the cache OFF must not
-        keep running deserialized executables from the first run's dir —
-        the donation-safety guards key on the cache being active, so a
-        stale global config would disable them while the hazard persists."""
-        from dcgan_tpu.utils.checkpoint import persistent_cache_active
-
-        warmup.configure_compile_cache(str(tmp_path / "cc"))
-        assert persistent_cache_active()
-        assert warmup.configure_compile_cache("") is None
-        assert not persistent_cache_active()
+    def test_configure_empty_leaves_the_process_setting_alone(self,
+                                                              tmp_path):
+        """An empty dir is "no opinion", not "off": the directory in force
+        stays in force and is what the caller is told, so the trainer's
+        cache-keyed behaviour (monitor, warm proof) sees the cache that
+        is really active."""
+        d = str(tmp_path / "cc")
+        warmup.configure_compile_cache(d)
+        assert warmup.configure_compile_cache("") == d
+        assert jax.config.jax_compilation_cache_dir == d
 
     def test_per_process_dirs_do_not_claim_fleet_warmth(self):
-        """jaxlib <= 0.4.37 writes cache entries from the chief only, so
+        """JAX writes cache entries from the chief only, so
         per-process multi-host stores never fill on non-chief processes —
         warm proof (the watchdog arming shortcut) must not ride on them.
         Single-process is always servable."""
@@ -398,18 +418,46 @@ class TestFusedRestore:
         ck.wait()
         assert ck._verify_step(3) == (True, "verified")
 
-    def test_rebase_when_cache_active_preserves_values(self, tmp_path):
-        """With the persistent cache configured, restored trees are
-        rebased onto XLA-owned buffers (the donation-safety workaround) —
-        values and shardings unchanged."""
+    def test_restored_state_donates_into_a_deserialized_executable(
+            self, tmp_path):
+        """What the donation guards this repo used to carry (a rebase of
+        every restored tree, an owned copy of every host snapshot) stood
+        against under an older jaxlib: an executable read back from the
+        persistent cache donating buffers XLA does not own, or donating
+        over a live host view. Under the installed jaxlib a
+        tensorstore-backed restore and a device_put tree both donate
+        cleanly into such an executable, and a host view taken before the
+        donation keeps its values — so the guards are gone, and this is
+        the test that says when they would be needed again."""
         warmup.configure_compile_cache(str(tmp_path / "cc"))
+
+        def make_step():
+            # a fresh function object per call: the in-memory jit cache
+            # misses, the persistent one (keyed on the program) does not
+            def step(t):
+                return jax.tree_util.tree_map(lambda a: a * 2 + 1, t)
+            return jax.jit(step, donate_argnums=0)
+
+        make_step()(self._state(1.0))  # compiles, writes the entry
+        mon = warmup.CompileCacheMonitor()
+        deserialized = make_step()
+
         ck = self._ckpt(tmp_path)
         ck.save(1, self._state(5.0), force=True)
         ck.wait()
         restored = ck.restore_latest(self._state(0.0))
-        assert int(restored["step"]) == 5
-        np.testing.assert_array_equal(
-            np.asarray(restored["w"]), np.full((64, 64), 5.0, np.float32))
+        put = jax.device_put(jax.device_get(self._state(3.0)))
+        for tree, start in ((restored, 5.0), (put, 3.0)):
+            view = jax.device_get(tree)
+            out, want = tree, start
+            for _ in range(8):
+                out, want = deserialized(out), want * 2 + 1
+            np.testing.assert_array_equal(
+                np.asarray(out["w"]), np.full((64, 64), want, np.float32))
+            np.testing.assert_array_equal(
+                view["w"], np.full((64, 64), start, np.float32))
+        assert mon.counters()["hits"] >= 1  # it WAS read back, not compiled
+        mon.close()
 
 
 class TestStartupProfile:

@@ -47,9 +47,6 @@ def main() -> None:
         jax.config.update("jax_platforms", os.environ["BENCH_PLATFORM"])
 
     from dcgan_tpu.ops.pallas_kernels import channel_moments, fused_bn_act
-    from dcgan_tpu.utils.backend import acquire_devices
-
-    acquire_devices()
 
     def jnp_bn_act(x, gamma, beta):
         c = x.shape[-1]

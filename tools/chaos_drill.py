@@ -484,13 +484,18 @@ def scenario_zero_rollback(root: str) -> dict:
     snapshots and restores the data-SHARDED state (params, EMA, and both
     Adam moments live as rule-engine shards between steps), training
     completes, and the post-rollback losses AND final STATE_SUM replay
-    BIT-EXACT against a --zero_stage 1 control fed the same fault — the
-    state sharding is a layout, not a different trajectory. backend=
-    shard_map: its explicit psum_scatter/all_gather round trip reproduces
-    the stage-1 pmean arithmetic to the last bit on CPU (the gspmd
+    a --zero_stage 1 control fed the same fault to 1e-5 — the state
+    sharding is a layout, not a different trajectory. backend=shard_map:
+    its explicit psum_scatter/all_gather round trip hands Adam the
+    stage-1 pmean's gradients to the last bit on CPU (the gspmd
     partitioner reassociates reductions, so stage parity there is
-    tolerance-level — tests/test_zero.py). Both arms run single-process
-    over 2 virtual devices, the 2-way data axis stage 3 needs."""
+    tolerance-level throughout — tests/test_zero.py); what is no longer
+    bit-exact under the installed XLA:CPU is the update itself, whose
+    elementwise loop rounds a shard's scalar tail differently from its
+    vector body (1 ulp on a few elements of a leaf per step, seen as the
+    last digit of g_loss from step 3 on), hence the tolerance. Both arms
+    run single-process over 2 virtual devices, the 2-way data axis stage
+    3 needs."""
     env = {"XLA_FLAGS": "--xla_force_host_platform_device_count=2",
            "DRILL_THREEFRY_PARTITIONABLE": "1"}
     knobs = dict(backend="shard_map", nan_policy="rollback",
@@ -513,19 +518,23 @@ def scenario_zero_rollback(root: str) -> dict:
         rollbacks = _scalar_values(_events(ck), "anomaly/rollbacks")
         _check(rollbacks and max(rollbacks) >= 1,
                f"{tag}: anomaly/rollbacks missing (got {rollbacks})")
-        return _state_sum(out), _loss_rows(_events(ck)), max(rollbacks)
+        return (_state_sum_value(out), _loss_rows(_events(ck)),
+                max(rollbacks))
+
+    def close(a, b):
+        return abs(a - b) <= 1e-5 * max(abs(b), 1e-12)
 
     sum_z, loss_z, rollbacks = one("zero3", 3)
     sum_c, loss_c, _ = one("zero1", 1)
     for s in sorted(loss_c):
-        _check(loss_z.get(s) == loss_c[s],
+        _check(s in loss_z and all(map(close, loss_z[s], loss_c[s])),
                f"step-{s} losses diverged across zero stages: "
                f"{loss_z.get(s)} != {loss_c[s]}")
-    _check(sum_z == sum_c,
+    _check(close(sum_z, sum_c),
            f"zero_stage=3 rollback state diverged from the stage-1 "
            f"control: {sum_z} != {sum_c}")
     return {"rollbacks": rollbacks, "final_step": 6,
-            "replay_bit_exact": True, "state_sum": sum_z}
+            "replay_rtol": 1e-5, "state_sum": sum_z}
 
 
 def scenario_progressive_switch(root: str) -> dict:

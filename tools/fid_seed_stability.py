@@ -1,6 +1,6 @@
 """Cross-seed surrogate-FID rank-stability experiment (VERDICT r3 #3).
 
-Every surrogate-validity trajectory in BASELINE.md uses the one fixed
+Every surrogate-validity trajectory (tools/fid_trajectory.py) uses the one fixed
 feature seed (42, evals/features.py). The objection that leaves open:
 "your FID is one lucky random projection." This tool kills it with CPU
 minutes: train ONE run, snapshot the state at an increasing step ladder,
@@ -69,7 +69,7 @@ def main(argv=None) -> None:
     dims = [int(d) for d in args.feature_dims.split(",")]
     root = tempfile.mkdtemp(prefix="fid_seed_")
 
-    # the tiny CPU validity config (matches the BASELINE.md trajectories)
+    # the tiny CPU validity config (matches fid_trajectory.py's)
     cfg = TrainConfig(
         model=ModelConfig(arch=args.arch, output_size=16, gf_dim=8,
                           df_dim=8, compute_dtype="float32"),
