@@ -484,6 +484,14 @@ class DevicePrefetcher:
     `__next__`. `close()` is idempotent, safe mid-epoch, unblocks a
     producer stuck on a full queue, and closes `owner` (the underlying
     loader) when given.
+
+    Spans (utils/profiling.py::span, always on). Consumer thread:
+    `feed/wait` around the blocking get of `__next__`, one per delivered
+    batch, its count the queue depth seen on entry (0: the step waits for
+    the loader). Producer thread: `feed/load` around `next(host_iter)`
+    (records read, decoded, assembled), `feed/h2d` around `to_global` (the
+    transfer's start), and `feed/full` around a put that found the queue
+    full (the producer's slack).
     """
 
     _SENTINEL = object()
@@ -500,6 +508,10 @@ class DevicePrefetcher:
         self._queue: "queue.Queue" = queue.Queue(maxsize=depth)
         self._stop = threading.Event()
         self._error: Optional[BaseException] = None
+        # imported here, not at the top: the loaders import without jax
+        from dcgan_tpu.utils.profiling import span
+
+        self._span = span
         self._thread = threading.Thread(
             target=self._produce, name="dcgan-device-feed", daemon=True)
         self._thread.start()
@@ -515,14 +527,27 @@ class DevicePrefetcher:
         return False
 
     def _produce(self) -> None:
+        span, host_iter = self._span, iter(self._host_iter)
         try:
-            for batch in self._host_iter:
+            while True:
+                try:
+                    with span("feed/load"):
+                        batch = next(host_iter)
+                except StopIteration:
+                    return
                 if self._stop.is_set():
                     return
                 if self._num_classes and isinstance(batch, tuple):
                     _check_labels(batch, self._num_classes)
-                arr = to_global(batch, self._sharding, self._label_sharding)
-                if not self._put(arr):
+                with span("feed/h2d"):
+                    arr = to_global(batch, self._sharding,
+                                    self._label_sharding)
+                if self._queue.full():
+                    with span("feed/full"):
+                        ok = self._put(arr)
+                else:
+                    ok = self._put(arr)
+                if not ok:
                     return
         except BaseException as e:  # noqa: BLE001 — re-raised on consumer
             self._error = e
@@ -533,6 +558,12 @@ class DevicePrefetcher:
         return self
 
     def __next__(self):
+        with self._span("feed/wait", count=self._queue.qsize()):
+            return self._get()
+
+    def _get(self):
+        """The blocking get; StopIteration at the end of the feed (which
+        leaves no `feed/wait` record: nothing was delivered)."""
         while True:
             if self._stop.is_set():
                 raise StopIteration

@@ -176,6 +176,7 @@ def generator_init(key, cfg: ModelConfig) -> Tuple[Pytree, Pytree]:
     return params, state
 
 
+@jax.named_scope("gen")
 def generator_apply(params: Pytree, state: Pytree, z: jax.Array, *,
                     cfg: ModelConfig, train: bool,
                     labels: Optional[jax.Array] = None,
@@ -229,16 +230,17 @@ def generator_apply(params: Pytree, state: Pytree, z: jax.Array, *,
         onehot = jax.nn.one_hot(labels, cfg.num_classes, dtype=z.dtype)
         z = jnp.concatenate([z, onehot], axis=-1)
 
-    top_ch = cfg.gf_dim * (2 ** (k - 1))
-    h = linear_apply(layer("proj"), z.astype(cdt), compute_dtype=cdt)
-    h = h.reshape(-1, cfg.base_size, cfg.base_size, top_ch)
-    # BN + relu fused (one pass under use_pallas; XLA-fused otherwise)
-    bn_labels = labels if cfg.conditional_bn else None
-    h, new_state["bn0"] = batch_norm_apply(
-        params["bn0"], state["bn0"], h, train=train,
-        momentum=cfg.bn_momentum, eps=cfg.bn_eps, axis_name=axis_name,
-        act="relu", use_pallas=cfg.bn_use_pallas, labels=bn_labels,
-        pallas_mesh=pallas_mesh)
+    with jax.named_scope("proj"):
+        top_ch = cfg.gf_dim * (2 ** (k - 1))
+        h = linear_apply(layer("proj"), z.astype(cdt), compute_dtype=cdt)
+        h = h.reshape(-1, cfg.base_size, cfg.base_size, top_ch)
+        # BN + relu fused (one pass under use_pallas; XLA-fused otherwise)
+        bn_labels = labels if cfg.conditional_bn else None
+        h, new_state["bn0"] = batch_norm_apply(
+            params["bn0"], state["bn0"], h, train=train,
+            momentum=cfg.bn_momentum, eps=cfg.bn_eps, axis_name=axis_name,
+            act="relu", use_pallas=cfg.bn_use_pallas, labels=bn_labels,
+            pallas_mesh=pallas_mesh)
     if cfg.attn_res == cfg.base_size:
         h = attn_apply(attn_params(), h, compute_dtype=cdt,
                        num_heads=cfg.attn_heads,
@@ -249,30 +251,31 @@ def generator_apply(params: Pytree, state: Pytree, z: jax.Array, *,
         capture["h0"] = h
 
     for i in range(1, k + 1):
-        if cfg.pallas_fused and i < k:
-            # the whole interior stage (deconv + bias + BN + relu) as the
-            # fused Pallas block — one HBM round-trip instead of three
-            from dcgan_tpu.ops.pallas_fused import fused_conv_bn_act
+        with jax.named_scope(f"deconv{i}"):
+            if cfg.pallas_fused and i < k:
+                # the whole interior stage (deconv + bias + BN + relu) as the
+                # fused Pallas block — one HBM round-trip instead of three
+                from dcgan_tpu.ops.pallas_fused import fused_conv_bn_act
 
-            h, new_state[f"bn{i}"] = fused_conv_bn_act(
-                layer(f"deconv{i}"), params[f"bn{i}"], state[f"bn{i}"], h,
-                transpose=True, kernel=cfg.kernel_size, stride=2,
-                train=train, momentum=cfg.bn_momentum, eps=cfg.bn_eps,
-                act="relu", axis_name=axis_name, pallas_mesh=pallas_mesh,
-                compute_dtype=cdt,
-                quant=_stage_quant(cfg, cfg.base_size * (2 ** i)))
-        else:
-            h = deconv2d_apply(
-                layer(f"deconv{i}"), h, compute_dtype=cdt,
-                quant="" if i == k
-                else _stage_quant(cfg, cfg.base_size * (2 ** i)))
-            if i < k:
-                h, new_state[f"bn{i}"] = batch_norm_apply(
-                    params[f"bn{i}"], state[f"bn{i}"], h, train=train,
-                    momentum=cfg.bn_momentum, eps=cfg.bn_eps,
-                    axis_name=axis_name, act="relu",
-                    use_pallas=cfg.bn_use_pallas,
-                    labels=bn_labels, pallas_mesh=pallas_mesh)
+                h, new_state[f"bn{i}"] = fused_conv_bn_act(
+                    layer(f"deconv{i}"), params[f"bn{i}"], state[f"bn{i}"], h,
+                    transpose=True, kernel=cfg.kernel_size, stride=2,
+                    train=train, momentum=cfg.bn_momentum, eps=cfg.bn_eps,
+                    act="relu", axis_name=axis_name, pallas_mesh=pallas_mesh,
+                    compute_dtype=cdt,
+                    quant=_stage_quant(cfg, cfg.base_size * (2 ** i)))
+            else:
+                h = deconv2d_apply(
+                    layer(f"deconv{i}"), h, compute_dtype=cdt,
+                    quant="" if i == k
+                    else _stage_quant(cfg, cfg.base_size * (2 ** i)))
+                if i < k:
+                    h, new_state[f"bn{i}"] = batch_norm_apply(
+                        params[f"bn{i}"], state[f"bn{i}"], h, train=train,
+                        momentum=cfg.bn_momentum, eps=cfg.bn_eps,
+                        axis_name=axis_name, act="relu",
+                        use_pallas=cfg.bn_use_pallas,
+                        labels=bn_labels, pallas_mesh=pallas_mesh)
         if i < k:
             if cfg.attn_res == cfg.base_size * (2 ** i):
                 h = attn_apply(attn_params(), h, compute_dtype=cdt,
@@ -346,6 +349,7 @@ def discriminator_init(key, cfg: ModelConfig) -> Tuple[Pytree, Pytree]:
     return params, state
 
 
+@jax.named_scope("disc")
 def discriminator_apply(params: Pytree, state: Pytree, image: jax.Array, *,
                         cfg: ModelConfig, train: bool,
                         labels: Optional[jax.Array] = None,
@@ -389,30 +393,31 @@ def discriminator_apply(params: Pytree, state: Pytree, image: jax.Array, *,
         h = jnp.concatenate([h, maps], axis=-1)
 
     for i in range(k):
-        if cfg.pallas_fused and i > 0:
-            # fused conv + bias + BN + lrelu block (stage 0 keeps the
-            # reference's no-BN shape and stays on the unfused path)
-            from dcgan_tpu.ops.pallas_fused import fused_conv_bn_act
+        with jax.named_scope(f"conv{i}"):
+            if cfg.pallas_fused and i > 0:
+                # fused conv + bias + BN + lrelu block (stage 0 keeps the
+                # reference's no-BN shape and stays on the unfused path)
+                from dcgan_tpu.ops.pallas_fused import fused_conv_bn_act
 
-            h, new_state[f"bn{i}"] = fused_conv_bn_act(
-                layer(f"conv{i}"), params[f"bn{i}"], state[f"bn{i}"], h,
-                transpose=False, kernel=cfg.kernel_size, stride=2,
-                train=train, momentum=cfg.bn_momentum, eps=cfg.bn_eps,
-                act="lrelu", leak=cfg.leak, axis_name=axis_name,
-                pallas_mesh=pallas_mesh, compute_dtype=cdt,
-                quant=_stage_quant(cfg, cfg.output_size >> i))
-        elif i > 0:
-            h = conv2d_apply(layer(f"conv{i}"), h, compute_dtype=cdt,
-                             quant=_stage_quant(cfg, cfg.output_size >> i))
-            # BN + lrelu fused (stage 0 keeps the reference's no-BN shape)
-            h, new_state[f"bn{i}"] = batch_norm_apply(
-                params[f"bn{i}"], state[f"bn{i}"], h, train=train,
-                momentum=cfg.bn_momentum, eps=cfg.bn_eps,
-                axis_name=axis_name, act="lrelu", leak=cfg.leak,
-                use_pallas=cfg.bn_use_pallas, pallas_mesh=pallas_mesh)
-        else:
-            h = conv2d_apply(layer(f"conv{i}"), h, compute_dtype=cdt)
-            h = lrelu(h, cfg.leak)
+                h, new_state[f"bn{i}"] = fused_conv_bn_act(
+                    layer(f"conv{i}"), params[f"bn{i}"], state[f"bn{i}"], h,
+                    transpose=False, kernel=cfg.kernel_size, stride=2,
+                    train=train, momentum=cfg.bn_momentum, eps=cfg.bn_eps,
+                    act="lrelu", leak=cfg.leak, axis_name=axis_name,
+                    pallas_mesh=pallas_mesh, compute_dtype=cdt,
+                    quant=_stage_quant(cfg, cfg.output_size >> i))
+            elif i > 0:
+                h = conv2d_apply(layer(f"conv{i}"), h, compute_dtype=cdt,
+                                 quant=_stage_quant(cfg, cfg.output_size >> i))
+                # BN + lrelu fused (stage 0 keeps the reference's no-BN shape)
+                h, new_state[f"bn{i}"] = batch_norm_apply(
+                    params[f"bn{i}"], state[f"bn{i}"], h, train=train,
+                    momentum=cfg.bn_momentum, eps=cfg.bn_eps,
+                    axis_name=axis_name, act="lrelu", leak=cfg.leak,
+                    use_pallas=cfg.bn_use_pallas, pallas_mesh=pallas_mesh)
+            else:
+                h = conv2d_apply(layer(f"conv{i}"), h, compute_dtype=cdt)
+                h = lrelu(h, cfg.leak)
         if cfg.attn_res and cfg.attn_res == cfg.output_size >> (i + 1):
             h = attn_apply(attn_params(), h, compute_dtype=cdt,
                            num_heads=cfg.attn_heads,
@@ -422,8 +427,9 @@ def discriminator_apply(params: Pytree, state: Pytree, image: jax.Array, *,
         if capture is not None:
             capture[f"h{i}"] = h
 
-    h = h.reshape(h.shape[0], -1)
-    logit = linear_apply(layer("head"), h, compute_dtype=cdt)
+    with jax.named_scope("head"):
+        h = h.reshape(h.shape[0], -1)
+        logit = linear_apply(layer("head"), h, compute_dtype=cdt)
     logit = logit.astype(jnp.float32)
     if capture is not None:
         capture["logit"] = logit

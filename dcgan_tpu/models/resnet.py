@@ -168,8 +168,9 @@ def generator_apply(params: Pytree, state: Pytree, z: jax.Array, *,
         z = jnp.concatenate([z, onehot], axis=-1)
     bn_labels = labels if cfg.conditional_bn else None
 
-    h = linear_apply(layer("proj"), z.astype(cdt), compute_dtype=cdt)
-    h = h.reshape(-1, cfg.base_size, cfg.base_size, chans[0])
+    with jax.named_scope("proj"):
+        h = linear_apply(layer("proj"), z.astype(cdt), compute_dtype=cdt)
+        h = h.reshape(-1, cfg.base_size, cfg.base_size, chans[0])
     if cfg.attn_res == cfg.base_size:
         h = _attn(cfg, params, state, new_state, h, cdt, attn_mesh, sn,
                   train, pallas_mesh=pallas_mesh)
@@ -177,26 +178,28 @@ def generator_apply(params: Pytree, state: Pytree, z: jax.Array, *,
         capture["h0"] = h
 
     for i in range(1, k + 1):
-        r = bn(f"b{i}_bn1", h, "relu")
-        r = _upsample(r)
-        r = conv2d_apply(layer(f"b{i}_conv1"), r, stride=1,
-                         compute_dtype=cdt)
-        r = bn(f"b{i}_bn2", r, "relu")
-        r = conv2d_apply(layer(f"b{i}_conv2"), r, stride=1,
-                         compute_dtype=cdt)
-        s = _upsample(h)
-        if f"b{i}_skip" in params:
-            s = conv2d_apply(layer(f"b{i}_skip"), s, stride=1,
+        with jax.named_scope(f"b{i}"):
+            r = bn(f"b{i}_bn1", h, "relu")
+            r = _upsample(r)
+            r = conv2d_apply(layer(f"b{i}_conv1"), r, stride=1,
                              compute_dtype=cdt)
-        h = r + s
+            r = bn(f"b{i}_bn2", r, "relu")
+            r = conv2d_apply(layer(f"b{i}_conv2"), r, stride=1,
+                             compute_dtype=cdt)
+            s = _upsample(h)
+            if f"b{i}_skip" in params:
+                s = conv2d_apply(layer(f"b{i}_skip"), s, stride=1,
+                                 compute_dtype=cdt)
+            h = r + s
         if cfg.attn_res == cfg.base_size * (2 ** i) and i < k:
             h = _attn(cfg, params, state, new_state, h, cdt, attn_mesh, sn,
                       train, pallas_mesh=pallas_mesh)
         if capture is not None:
             capture[f"h{i}"] = h
 
-    h = bn("bn_out", h, "relu")
-    h = conv2d_apply(layer("out_conv"), h, stride=1, compute_dtype=cdt)
+    with jax.named_scope("out_conv"):
+        h = bn("bn_out", h, "relu")
+        h = conv2d_apply(layer("out_conv"), h, stride=1, compute_dtype=cdt)
     out = jnp.tanh(h.astype(jnp.float32))
     if capture is not None:
         capture[f"h{k + 1}"] = out
@@ -286,29 +289,31 @@ def discriminator_apply(params: Pytree, state: Pytree, image: jax.Array, *,
         h = jnp.concatenate([h, maps], axis=-1)
 
     for i in range(k):
-        # block 0 is the "optimized" form (no pre-activation on raw pixels);
-        # later blocks pre-activate (relu first)
-        r = h if i == 0 else jax.nn.relu(h)
-        r = conv2d_apply(layer(f"b{i}_conv1"), r, stride=1,
-                         compute_dtype=cdt)
-        r = jax.nn.relu(r)
-        r = conv2d_apply(layer(f"b{i}_conv2"), r, stride=1,
-                         compute_dtype=cdt)
-        r = _avgpool(r)
-        s = _avgpool(h)
-        if f"b{i}_skip" in params:
-            s = conv2d_apply(layer(f"b{i}_skip"), s, stride=1,
+        with jax.named_scope(f"b{i}"):
+            # block 0 is the "optimized" form (no pre-activation on raw
+            # pixels); later blocks pre-activate (relu first)
+            r = h if i == 0 else jax.nn.relu(h)
+            r = conv2d_apply(layer(f"b{i}_conv1"), r, stride=1,
                              compute_dtype=cdt)
-        h = r + s
+            r = jax.nn.relu(r)
+            r = conv2d_apply(layer(f"b{i}_conv2"), r, stride=1,
+                             compute_dtype=cdt)
+            r = _avgpool(r)
+            s = _avgpool(h)
+            if f"b{i}_skip" in params:
+                s = conv2d_apply(layer(f"b{i}_skip"), s, stride=1,
+                                 compute_dtype=cdt)
+            h = r + s
         if cfg.attn_res and cfg.attn_res == cfg.output_size >> (i + 1):
             h = _attn(cfg, params, state, new_state, h, cdt, attn_mesh, sn,
                       train, pallas_mesh=pallas_mesh)
         if capture is not None:
             capture[f"h{i}"] = h
 
-    h = jax.nn.relu(h)
-    h = h.sum(axis=(1, 2))                       # global sum pool
-    logit = linear_apply(layer("head"), h, compute_dtype=cdt)
+    with jax.named_scope("head"):
+        h = jax.nn.relu(h)
+        h = h.sum(axis=(1, 2))                       # global sum pool
+        logit = linear_apply(layer("head"), h, compute_dtype=cdt)
     logit = logit.astype(jnp.float32)
     if capture is not None:
         capture["logit"] = logit

@@ -149,13 +149,14 @@ def generator_apply(params: Pytree, state: Pytree, z: jax.Array, *,
 
     # pixel-normalize z (the paper's mapping-input normalization), then the
     # 2-layer lrelu mapping network -> w
-    zn = z.astype(cdt)
-    zn = zn * lax.rsqrt(jnp.mean(zn.astype(jnp.float32) ** 2, axis=-1,
-                                 keepdims=True).astype(cdt) + 1e-8)
-    w_lat = lrelu(linear_apply(params["map0"], zn, compute_dtype=cdt),
-                  cfg.leak)
-    w_lat = lrelu(linear_apply(params["map1"], w_lat, compute_dtype=cdt),
-                  cfg.leak)
+    with jax.named_scope("map"):
+        zn = z.astype(cdt)
+        zn = zn * lax.rsqrt(jnp.mean(zn.astype(jnp.float32) ** 2, axis=-1,
+                                     keepdims=True).astype(cdt) + 1e-8)
+        w_lat = lrelu(linear_apply(params["map0"], zn, compute_dtype=cdt),
+                      cfg.leak)
+        w_lat = lrelu(linear_apply(params["map1"], w_lat, compute_dtype=cdt),
+                      cfg.leak)
     if capture is not None:
         capture["w"] = w_lat
 
@@ -163,14 +164,17 @@ def generator_apply(params: Pytree, state: Pytree, z: jax.Array, *,
                          (z.shape[0],) + params["const"].shape)
     rgb = None
     for i in range(1, k + 1):
-        h = _upsample(h)
-        h = lrelu(_mod_conv(params[f"b{i}_conv1"], params[f"b{i}_style1"],
-                            h, w_lat, demod=True, cdt=cdt), cfg.leak)
-        h = lrelu(_mod_conv(params[f"b{i}_conv2"], params[f"b{i}_style2"],
-                            h, w_lat, demod=True, cdt=cdt), cfg.leak)
-        y = _mod_conv(params[f"b{i}_trgb"], params[f"b{i}_rgb_style"],
-                      h, w_lat, demod=False, cdt=cdt)
-        rgb = y if rgb is None else _upsample(rgb) + y
+        with jax.named_scope(f"b{i}"):
+            h = _upsample(h)
+            h = lrelu(_mod_conv(params[f"b{i}_conv1"],
+                                params[f"b{i}_style1"],
+                                h, w_lat, demod=True, cdt=cdt), cfg.leak)
+            h = lrelu(_mod_conv(params[f"b{i}_conv2"],
+                                params[f"b{i}_style2"],
+                                h, w_lat, demod=True, cdt=cdt), cfg.leak)
+            y = _mod_conv(params[f"b{i}_trgb"], params[f"b{i}_rgb_style"],
+                          h, w_lat, demod=False, cdt=cdt)
+            rgb = y if rgb is None else _upsample(rgb) + y
         if capture is not None:
             capture[f"h{i}"] = h
     out = jnp.tanh(rgb.astype(jnp.float32))

@@ -218,6 +218,7 @@ def _project(params: Pytree, x: jax.Array, cdt) -> Tuple[jax.Array, ...]:
     return q, k, v
 
 
+@jax.named_scope("attn")
 def attn_apply(params: Pytree, x: jax.Array, *, compute_dtype=None,
                num_heads: int = 1, seq_mesh=None, seq_axis: str = "model",
                batch_axis: str = "data", seq_strategy: str = "ring",

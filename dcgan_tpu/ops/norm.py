@@ -121,6 +121,7 @@ def _pallas_shard_epilogue(x, scale, bias, mean, var, *, eps, act, leak,
                      check=False)(x, scale, bias, mean, var)
 
 
+@jax.named_scope("bn")
 def batch_norm_apply(params: Pytree, state: Pytree, x: jax.Array, *,
                      train: bool, momentum: float = 0.9, eps: float = 1e-5,
                      axis_name: Optional[str] = None, act: str = "none",

@@ -164,6 +164,7 @@ def _fwd_impl(q, k, v, scale):
     tq, tk = _blocks(S)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, tk=tk),
+        name="flash_fwd",
         grid=(B, S // tq),
         in_specs=[pl.BlockSpec((1, tq, dk), lambda b, i: (b, i, 0)),
                   pl.BlockSpec((1, S, dk), lambda b, i: (b, 0, 0)),
@@ -291,6 +292,7 @@ def _bwd_core(scale, q, k, v, do, lse, delta, lse_r, delta_r,
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, tk=tk),
+        name="flash_dq",
         grid=(B, S // tq),
         in_specs=[pl.BlockSpec((1, tq, dk), lambda b, i: (b, i, 0)),
                   pl.BlockSpec((1, S, dk), lambda b, i: (b, 0, 0)),
@@ -306,6 +308,7 @@ def _bwd_core(scale, q, k, v, do, lse, delta, lse_r, delta_r,
 
     dk_arr, dv_arr = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, tq=tq),
+        name="flash_dkv",
         grid=(B, S // tk),
         in_specs=[pl.BlockSpec((1, S, dk), lambda b, j: (b, 0, 0)),
                   pl.BlockSpec((1, tk, dk), lambda b, j: (b, j, 0)),

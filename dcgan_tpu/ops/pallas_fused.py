@@ -164,6 +164,7 @@ def _gbm_impl(p2d, w2d, b, out_dtype):
     y, sums, sumsqs = pl.pallas_call(
         functools.partial(_gemm_bias_moments_kernel, k_blocks=k // tk,
                           out_dtype=jnp.dtype(out_dtype)),
+        name="fused_conv_stats",
         grid=(m // tm, k // tk),
         in_specs=[pl.BlockSpec((tm, tk), lambda i, j: (i, j)),
                   pl.BlockSpec((tk, c), lambda i, j: (j, 0)),
@@ -249,6 +250,7 @@ def _gbsa_impl(p2d, w2d, b, scale, shift, act, leak, out_dtype):
     y, _ = pl.pallas_call(
         functools.partial(_gemm_bias_scale_act_kernel, k_blocks=k // tk,
                           act=act, leak=leak),
+        name="fused_conv_apply",
         grid=(m // tm, k // tk),
         in_specs=[pl.BlockSpec((tm, tk), lambda i, j: (i, j)),
                   pl.BlockSpec((tk, c), lambda i, j: (j, 0)),

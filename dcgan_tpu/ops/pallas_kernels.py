@@ -79,6 +79,7 @@ def _moments_fwd_impl(x2d: jax.Array) -> Tuple[jax.Array, jax.Array]:
     acc_spec = pl.BlockSpec((1, c), lambda i: (0, 0))
     sums, sumsqs = pl.pallas_call(
         _moments_kernel,
+        name="bn_moments",
         grid=(n // tile,),
         in_specs=[pl.BlockSpec((tile, c), lambda i: (i, 0))],
         out_specs=(acc_spec, acc_spec),
@@ -150,6 +151,7 @@ def _ssa_impl(x2d, scale, shift, act, leak):
     vec_spec = pl.BlockSpec((1, c), lambda i: (0, 0))
     return pl.pallas_call(
         functools.partial(_ssa_fwd_kernel, act=act, leak=leak),
+        name="bn_apply",
         grid=(n // tile,),
         in_specs=[pl.BlockSpec((tile, c), lambda i: (i, 0)),
                   vec_spec, vec_spec],
@@ -179,6 +181,7 @@ def _ssa_vjp_bwd(act, leak, res, g):
     vec_spec = pl.BlockSpec((1, c), lambda i: (0, 0))
     dx, dscale, dshift = pl.pallas_call(
         functools.partial(_ssa_bwd_kernel, act=act, leak=leak),
+        name="bn_bwd",
         grid=(n // tile,),
         in_specs=[pl.BlockSpec((tile, c), lambda i: (i, 0)),
                   vec_spec, vec_spec,

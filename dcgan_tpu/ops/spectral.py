@@ -35,6 +35,7 @@ def spectral_u_init(key, out_dim: int, *, dtype=jnp.float32) -> jax.Array:
                 1e-12).astype(dtype)
 
 
+@jax.named_scope("sn")
 def spectral_normalize(w: jax.Array, u: jax.Array, *, train: bool,
                        n_iter: int = 1, eps: float = 1e-12
                        ) -> Tuple[jax.Array, jax.Array]:

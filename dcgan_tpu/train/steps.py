@@ -492,11 +492,39 @@ def make_train_step(cfg: TrainConfig, *, axis_name: Optional[str] = None,
             return g_loss, (g_bn, fake)
         return g_loss, (g_bn,)
 
+    # Scopes a reader of a profile thinks in (ISSUE 24): every program
+    # below runs its phases under `d_step` / `g_step`, inside each `loss`
+    # (forward and, through the transpose's name stack, backward) and
+    # `adam`, then `ema`; the nets add `gen` / `disc`, their stages and
+    # `attn` / `bn` / `sn`. Metadata only: no operation changes.
+    def _loss_grad(fn):
+        vg = jax.value_and_grad(fn, has_aux=True)
+
+        def call(*args, **kwargs):
+            with jax.named_scope("loss"):
+                return vg(*args, **kwargs)
+        return call
+
+    d_grad = _loss_grad(d_loss_fn)
+    d_on_fake_grad = _loss_grad(_d_loss_on_fake)
+    g_grad = _loss_grad(g_loss_fn)
+
+    def _adam(opt, grads, opt_state, params: Pytree, net: str):
+        """One optimizer update from already reduced / averaged gradients:
+        (new params, new optimizer state)."""
+        with jax.named_scope("adam"):
+            updates, opt_state = opt.update(grads, opt_state,
+                                            _opt_arg(params))
+            return (optax.apply_updates(params,
+                                        _gather_updates(updates, net)),
+                    opt_state)
+
     def _ema_update(state: Pytree, new_gen: Pytree) -> Pytree:
         d_ema = cfg.g_ema_decay  # 0 -> ema_gen mirrors the live weights
-        return jax.tree_util.tree_map(
-            lambda e, p: d_ema * e + (1.0 - d_ema) * p,
-            state["ema_gen"], new_gen)
+        with jax.named_scope("ema"):
+            return jax.tree_util.tree_map(
+                lambda e, p: d_ema * e + (1.0 - d_ema) * p,
+                state["ema_gen"], new_gen)
 
     def _accum_train_step(state: Pytree, images: jax.Array, z: jax.Array,
                           gp_key, aug_key, labels) -> Tuple[Pytree, dict]:
@@ -543,45 +571,46 @@ def make_train_step(cfg: TrainConfig, *, axis_name: Optional[str] = None,
             def d_micro(carry, x):
                 g_acc, bn_d = carry
                 bn_in = {"gen": bn["gen"], "disc": bn_d}
-                (d_loss, (d_bn_i, d_real, d_fake, gp)), grads = \
-                    jax.value_and_grad(d_loss_fn, has_aux=True)(
-                        d_full, gen_full, bn_in, x["img"], x["z"],
-                        x["gpk"], x.get("lbl"), state["step"], False,
-                        x.get("augk"))
+                (d_loss, (d_bn_i, d_real, d_fake, gp)), grads = d_grad(
+                    d_full, gen_full, bn_in, x["img"], x["z"],
+                    x["gpk"], x.get("lbl"), state["step"], False,
+                    x.get("augk"))
                 return ((_acc(g_acc, grads), d_bn_i),
                         (d_loss, d_real, d_fake, gp))
 
             (g_acc, bn_d), ms = lax.scan(
                 d_micro, (_zeros_f32(d_full), bn_d_start), xs)
-            updates, d_opt_state = opt_d.update(
-                _avg(g_acc, d_full, "disc"), d_opt_state,
-                _opt_arg(d_params))
-            return (optax.apply_updates(
-                        d_params, _gather_updates(updates, "disc")),
-                    d_opt_state, bn_d, tuple(m.mean() for m in ms))
+            d_params, d_opt_state = _adam(
+                opt_d, _avg(g_acc, d_full, "disc"), d_opt_state, d_params,
+                "disc")
+            return (d_params, d_opt_state, bn_d,
+                    tuple(m.mean() for m in ms))
 
-        if cfg.n_critic == 1:
-            new_disc, d_opt, d_bn, (d_loss, d_real, d_fake, gp) = \
-                d_accum_update(params["disc"], state["opt"]["disc"],
-                               bn["disc"], _micro_xs(z, gp_key, aug_key))
-        else:
-            # the non-accum critic loop's semantics (fresh full z per
-            # iteration against the same real batch), each iteration's
-            # update accumulated over K microbatches
-            def critic_iter(carry, iter_key):
-                d_params_c, d_opt_c, d_bn_c, _ = carry
-                z_i, gpk, aug_k = _critic_streams(iter_key, images.shape[0])
-                out = d_accum_update(d_params_c, d_opt_c, d_bn_c,
-                                     _micro_xs(z_i, gpk, aug_k))
-                return out, None
+        with jax.named_scope("d_step"):
+            if cfg.n_critic == 1:
+                new_disc, d_opt, d_bn, (d_loss, d_real, d_fake, gp) = \
+                    d_accum_update(params["disc"], state["opt"]["disc"],
+                                   bn["disc"],
+                                   _micro_xs(z, gp_key, aug_key))
+            else:
+                # the non-accum critic loop's semantics (fresh full z per
+                # iteration against the same real batch), each iteration's
+                # update accumulated over K microbatches
+                def critic_iter(carry, iter_key):
+                    d_params_c, d_opt_c, d_bn_c, _ = carry
+                    z_i, gpk, aug_k = _critic_streams(iter_key,
+                                                      images.shape[0])
+                    out = d_accum_update(d_params_c, d_opt_c, d_bn_c,
+                                         _micro_xs(z_i, gpk, aug_k))
+                    return out, None
 
-            zero = _zero_metric()
-            (new_disc, d_opt, d_bn,
-             (d_loss, d_real, d_fake, gp)), _ = lax.scan(
-                critic_iter,
-                (params["disc"], state["opt"]["disc"], bn["disc"],
-                 (zero, zero, zero, zero)),
-                jax.random.split(gp_key, cfg.n_critic))
+                zero = _zero_metric()
+                (new_disc, d_opt, d_bn,
+                 (d_loss, d_real, d_fake, gp)), _ = lax.scan(
+                    critic_iter,
+                    (params["disc"], state["opt"]["disc"], bn["disc"],
+                     (zero, zero, zero, zero)),
+                    jax.random.split(gp_key, cfg.n_critic))
 
         if cfg.update_mode == "sequential":
             g_target_disc, disc_bn_for_g = \
@@ -598,19 +627,17 @@ def make_train_step(cfg: TrainConfig, *, axis_name: Optional[str] = None,
         def g_micro(carry, x):
             g_acc, bn_g = carry
             bn_in = {"gen": bn_g, "disc": disc_bn_for_g}
-            (g_loss, (g_bn_i,)), grads = \
-                jax.value_and_grad(g_loss_fn, has_aux=True)(
-                    gen_full, g_target_disc, bn_in, x["z"],
-                    x.get("lbl"), x.get("augk"))
+            (g_loss, (g_bn_i,)), grads = g_grad(
+                gen_full, g_target_disc, bn_in, x["z"],
+                x.get("lbl"), x.get("augk"))
             return (_acc(g_acc, grads), g_bn_i), g_loss
 
-        (g_gacc, g_bn), g_losses = lax.scan(
-            g_micro, (_zeros_f32(gen_full), bn["gen"]), g_xs)
-        g_grads = _avg(g_gacc, gen_full, "gen")
-        g_updates, g_opt = opt_g.update(g_grads, state["opt"]["gen"],
-                                        _opt_arg(params["gen"]))
-        new_gen = optax.apply_updates(params["gen"],
-                                      _gather_updates(g_updates, "gen"))
+        with jax.named_scope("g_step"):
+            (g_gacc, g_bn), g_losses = lax.scan(
+                g_micro, (_zeros_f32(gen_full), bn["gen"]), g_xs)
+            new_gen, g_opt = _adam(opt_g, _avg(g_gacc, gen_full, "gen"),
+                                   state["opt"]["gen"], params["gen"],
+                                   "gen")
 
         new_state = {
             "params": {"gen": new_gen, "disc": new_disc},
@@ -648,51 +675,48 @@ def make_train_step(cfg: TrainConfig, *, axis_name: Optional[str] = None,
         gen_full = _gather_params(params["gen"], "gen")
 
         # --- D step(s) ------------------------------------------------------
-        if cfg.n_critic == 1:
-            (d_loss, (d_bn, d_real, d_fake, gp)), d_grads = jax.value_and_grad(
-                d_loss_fn, has_aux=True)(
+        with jax.named_scope("d_step"):
+            if cfg.n_critic == 1:
+                (d_loss, (d_bn, d_real, d_fake, gp)), d_grads = d_grad(
                     _gather_params(params["disc"], "disc"), gen_full, bn,
                     images, z, gp_key,
                     labels, state["step"], False, aug_key)
-            d_grads = _reduce_grads(d_grads, "disc")
-            d_updates, d_opt = opt_d.update(d_grads, state["opt"]["disc"],
-                                            _opt_arg(params["disc"]))
-            new_disc = optax.apply_updates(params["disc"],
-                                           _gather_updates(d_updates,
-                                                           "disc"))
-        else:
-            # n_critic > 1 (canonical WGAN-GP: 5) — scanned critic updates
-            # inside the same compiled program. Each iteration draws fresh z
-            # (and a fresh interpolation key) against the same real batch;
-            # the loop is lax.scan so XLA compiles the critic body once.
-            def critic_iter(carry, iter_key):
-                d_params_c, d_opt_c, d_bn_c, _ = carry
-                z_i, gpk, aug_k = _critic_streams(iter_key, images.shape[0])
-                bn_in = {"gen": bn["gen"], "disc": d_bn_c}
-                (loss_i, (bn_i, real_i, fake_i, gp_i)), grads = \
-                    jax.value_and_grad(d_loss_fn, has_aux=True)(
+                new_disc, d_opt = _adam(
+                    opt_d, _reduce_grads(d_grads, "disc"),
+                    state["opt"]["disc"], params["disc"], "disc")
+            else:
+                # n_critic > 1 (canonical WGAN-GP: 5) — scanned critic
+                # updates inside the same compiled program. Each iteration
+                # draws fresh z (and a fresh interpolation key) against the
+                # same real batch; the loop is lax.scan so XLA compiles the
+                # critic body once.
+                def critic_iter(carry, iter_key):
+                    d_params_c, d_opt_c, d_bn_c, _ = carry
+                    z_i, gpk, aug_k = _critic_streams(iter_key,
+                                                      images.shape[0])
+                    bn_in = {"gen": bn["gen"], "disc": d_bn_c}
+                    (loss_i, (bn_i, real_i, fake_i, gp_i)), grads = d_grad(
                         _gather_params(d_params_c, "disc"), gen_full,
                         bn_in, images, z_i, gpk,
                         labels, state["step"], False, aug_k)
-                grads = _reduce_grads(grads, "disc")
-                updates, d_opt_c = opt_d.update(grads, d_opt_c,
-                                                _opt_arg(d_params_c))
-                d_params_c = optax.apply_updates(
-                    d_params_c, _gather_updates(updates, "disc"))
-                # last iteration's metrics ride the carry; note they are
-                # evaluated at that iteration's PRE-update params (one Adam
-                # step stale relative to the critic G trains against)
-                return ((d_params_c, d_opt_c, bn_i,
-                         (loss_i, real_i, fake_i, gp_i)), None)
+                    d_params_c, d_opt_c = _adam(
+                        opt_d, _reduce_grads(grads, "disc"), d_opt_c,
+                        d_params_c, "disc")
+                    # last iteration's metrics ride the carry; note they
+                    # are evaluated at that iteration's PRE-update params
+                    # (one Adam step stale relative to the critic G trains
+                    # against)
+                    return ((d_params_c, d_opt_c, bn_i,
+                             (loss_i, real_i, fake_i, gp_i)), None)
 
-            iter_keys = jax.random.split(gp_key, cfg.n_critic)
-            zero = _zero_metric()
-            (new_disc, d_opt, d_bn,
-             (d_loss, d_real, d_fake, gp)), _ = lax.scan(
-                critic_iter,
-                (params["disc"], state["opt"]["disc"], bn["disc"],
-                 (zero, zero, zero, zero)),
-                iter_keys)
+                iter_keys = jax.random.split(gp_key, cfg.n_critic)
+                zero = _zero_metric()
+                (new_disc, d_opt, d_bn,
+                 (d_loss, d_real, d_fake, gp)), _ = lax.scan(
+                    critic_iter,
+                    (params["disc"], state["opt"]["disc"], bn["disc"],
+                     (zero, zero, zero, zero)),
+                    iter_keys)
 
         if cfg.update_mode == "sequential":
             g_target_disc = _gather_params(new_disc, "disc")
@@ -702,14 +726,12 @@ def make_train_step(cfg: TrainConfig, *, axis_name: Optional[str] = None,
             g_bn_in = bn
 
         # --- G step ---------------------------------------------------------
-        (g_loss, (g_bn,)), g_grads = jax.value_and_grad(
-            g_loss_fn, has_aux=True)(
+        with jax.named_scope("g_step"):
+            (g_loss, (g_bn,)), g_grads = g_grad(
                 gen_full, g_target_disc, g_bn_in, z, labels, aug_key)
-        g_grads = _reduce_grads(g_grads, "gen")
-        g_updates, g_opt = opt_g.update(g_grads, state["opt"]["gen"],
-                                        _opt_arg(params["gen"]))
-        new_gen = optax.apply_updates(params["gen"],
-                                      _gather_updates(g_updates, "gen"))
+            new_gen, g_opt = _adam(
+                opt_g, _reduce_grads(g_grads, "gen"), state["opt"]["gen"],
+                params["gen"], "gen")
 
         new_state = {
             "params": {"gen": new_gen, "disc": new_disc},
@@ -775,6 +797,7 @@ def make_train_step(cfg: TrainConfig, *, axis_name: Optional[str] = None,
                            jax.random.fold_in(key, _FILL_TAG),
                            cfg.n_critic)
 
+    @jax.named_scope("d_step")
     def d_update(state: Pytree, images: jax.Array, fakes: jax.Array,
                  key: jax.Array) -> Tuple[Pytree, dict]:
         """The critic update(s) CONSUMING a provided fake stack (slot i
@@ -806,22 +829,20 @@ def make_train_step(cfg: TrainConfig, *, axis_name: Optional[str] = None,
                 def d_micro(c, x):
                     g_acc, bn_d = c
                     bn_in = {"gen": bn["gen"], "disc": bn_d}
-                    (loss, (bn_i, real, fk, gp)), grads = \
-                        jax.value_and_grad(_d_loss_on_fake, has_aux=True)(
-                            d_full, bn_in, x["img"], x["fake"],
-                            x["gpk"], None, state["step"], False,
-                            x.get("augk"))
+                    (loss, (bn_i, real, fk, gp)), grads = d_on_fake_grad(
+                        d_full, bn_in, x["img"], x["fake"],
+                        x["gpk"], None, state["step"], False,
+                        x.get("augk"))
                     return ((_acc(g_acc, grads), bn_i),
                             (loss, real, fk, gp))
 
                 (g_acc, bn_d), ms = lax.scan(
                     d_micro, (_zeros_f32(d_full), d_bn_c), xs_m)
-                updates, d_opt_c = opt_d.update(
-                    _avg(g_acc, d_full, "disc"), d_opt_c,
-                    _opt_arg(d_params_c))
-                return ((optax.apply_updates(
-                             d_params_c, _gather_updates(updates, "disc")),
-                         d_opt_c, bn_d, tuple(m.mean() for m in ms)), None)
+                d_params_c, d_opt_c = _adam(
+                    opt_d, _avg(g_acc, d_full, "disc"), d_opt_c,
+                    d_params_c, "disc")
+                return ((d_params_c, d_opt_c, bn_d,
+                         tuple(m.mean() for m in ms)), None)
         else:
             def critic_iter(carry, xs):
                 d_params_c, d_opt_c, d_bn_c, _ = carry
@@ -829,16 +850,14 @@ def make_train_step(cfg: TrainConfig, *, axis_name: Optional[str] = None,
                 _, gpk, aug_k = _critic_streams(iter_key, stage_batch)
                 bn_in = {"gen": bn["gen"], "disc": d_bn_c}
                 (loss_i, (bn_i, real_i, fake_m, gp_i)), grads = \
-                    jax.value_and_grad(_d_loss_on_fake, has_aux=True)(
+                    d_on_fake_grad(
                         _gather_params(d_params_c, "disc"), bn_in, images,
                         fake_i, gpk, None,
                         state["step"], False, aug_k)
-                grads = _reduce_grads(grads, "disc")
-                updates, d_opt_c = opt_d.update(grads, d_opt_c,
-                                                _opt_arg(d_params_c))
-                return ((optax.apply_updates(
-                             d_params_c, _gather_updates(updates, "disc")),
-                         d_opt_c, bn_i,
+                d_params_c, d_opt_c = _adam(
+                    opt_d, _reduce_grads(grads, "disc"), d_opt_c,
+                    d_params_c, "disc")
+                return ((d_params_c, d_opt_c, bn_i,
                          (loss_i, real_i, fake_m, gp_i)), None)
 
         carry0 = (params["disc"], state["opt"]["disc"], bn["disc"],
@@ -865,6 +884,7 @@ def make_train_step(cfg: TrainConfig, *, axis_name: Optional[str] = None,
         }
         return new_state, _d_metrics(d_loss, d_real, d_fake, gp)
 
+    @jax.named_scope("g_step")
     def g_update(state: Pytree, key: jax.Array
                  ) -> Tuple[Pytree, jax.Array, dict]:
         """The generator update against the CURRENT critic (the trainer
@@ -896,10 +916,9 @@ def make_train_step(cfg: TrainConfig, *, axis_name: Optional[str] = None,
             def g_micro(carry, x):
                 g_acc, bn_g = carry
                 bn_in = {"gen": bn_g, "disc": bn["disc"]}
-                (g_loss_i, (g_bn_i, fake_i)), grads = \
-                    jax.value_and_grad(g_loss_fn, has_aux=True)(
-                        gen_full, disc_full, bn_in, x["z"],
-                        None, x.get("augk"), return_fake=True)
+                (g_loss_i, (g_bn_i, fake_i)), grads = g_grad(
+                    gen_full, disc_full, bn_in, x["z"],
+                    None, x.get("augk"), return_fake=True)
                 return (_acc(g_acc, grads), g_bn_i), (g_loss_i, fake_i)
 
             (g_gacc, g_bn), (g_losses, fakes_m) = lax.scan(
@@ -913,15 +932,12 @@ def make_train_step(cfg: TrainConfig, *, axis_name: Optional[str] = None,
             z = jax.random.uniform(z_key, (stage_batch, mcfg.z_dim),
                                    minval=-1.0, maxval=1.0,
                                    dtype=jnp.float32)
-            (g_loss, (g_bn, fake)), g_grads = jax.value_and_grad(
-                g_loss_fn, has_aux=True)(
-                    gen_full, disc_full, bn, z, None, aug_key,
-                    return_fake=True)
+            (g_loss, (g_bn, fake)), g_grads = g_grad(
+                gen_full, disc_full, bn, z, None, aug_key,
+                return_fake=True)
             g_grads = _reduce_grads(g_grads, "gen")
-        g_updates, g_opt = opt_g.update(g_grads, state["opt"]["gen"],
-                                        _opt_arg(params["gen"]))
-        new_gen = optax.apply_updates(params["gen"],
-                                      _gather_updates(g_updates, "gen"))
+        new_gen, g_opt = _adam(opt_g, g_grads, state["opt"]["gen"],
+                               params["gen"], "gen")
 
         if cfg.n_critic > 1:
             extra = _fake_stack(gen_full, bn["gen"], extra_key,

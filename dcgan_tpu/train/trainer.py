@@ -85,7 +85,12 @@ from dcgan_tpu.utils.metrics import (
     MetricWriter,
     param_histograms,
 )
-from dcgan_tpu.utils.profiling import StartupProfile, StepTimer, TraceCapture
+from dcgan_tpu.utils.profiling import (
+    StartupProfile,
+    StepTimer,
+    TraceCapture,
+    span,
+)
 
 Pytree = Any
 
@@ -1611,19 +1616,10 @@ def _train_run(cfg: TrainConfig, *, synthetic_data: bool,
             labels = None
             if k == 1:
                 key = jax.random.fold_in(base_key, step_num)
-                if conditional:
-                    images, labels = next(data)
-                    if prog is not None:
-                        images = prog.fade_images(images, step_num)
-                    state, metrics = pt.step(state, images, key, labels)
-                elif pipeline is not None:
-                    # pipelined dispatch (ISSUE 7): d_update consumes the
-                    # stack g_update produced during the previous step;
-                    # an unprimed buffer (run start, post-rollback, post-
-                    # drain) dispatches the gen_fakes fill first — the
-                    # watchdog phase armed above names which case a hang
-                    # died in
-                    images = next(data)
+                with span("train/next"):
+                    batch = next(data)
+                images, labels = batch if conditional else (batch, None)
+                with span("train/dispatch"):
                     if prog is not None:
                         # image-space fade-in (ISSUE 15): inside a fade
                         # window the real batch blends toward its
@@ -1631,12 +1627,19 @@ def _train_run(cfg: TrainConfig, *, synthetic_data: bool,
                         # jitted blend (alpha a traced scalar); a no-op
                         # dispatch-free identity at alpha == 1
                         images = prog.fade_images(images, step_num)
-                    state, metrics = pipeline.step(pt, state, images, key)
-                else:
-                    images = next(data)
-                    if prog is not None:
-                        images = prog.fade_images(images, step_num)
-                    state, metrics = pt.step(state, images, key)
+                    if conditional:
+                        state, metrics = pt.step(state, images, key, labels)
+                    elif pipeline is not None:
+                        # pipelined dispatch (ISSUE 7): d_update consumes
+                        # the stack g_update produced during the previous
+                        # step; an unprimed buffer (run start, post-
+                        # rollback, post-drain) dispatches the gen_fakes
+                        # fill first — the watchdog phase armed above names
+                        # which case a hang died in
+                        state, metrics = pipeline.step(pt, state, images,
+                                                       key)
+                    else:
+                        state, metrics = pt.step(state, images, key)
             else:
                 # one vmapped dispatch for all K per-step keys (a python
                 # loop of fold_ins would pay K of the per-dispatch
@@ -1645,18 +1648,19 @@ def _train_run(cfg: TrainConfig, *, synthetic_data: bool,
                 keys = jax.vmap(jax.random.fold_in, (None, 0))(
                     base_key, jax.numpy.arange(step_num, step_num + k))
                 key = keys[-1]  # for the cadence consumers below
-                if conditional:
-                    pairs = [next(data) for _ in range(k)]
-                    imgs_k = jax.numpy.stack([p[0] for p in pairs])
-                    lbls_k = jax.numpy.stack([p[1] for p in pairs])
-                    state, metrics = pt.multi_step(state, imgs_k, keys,
-                                                   lbls_k)
-                    images, labels = pairs[-1]
-                else:
+                with span("train/next"):
                     batches = [next(data) for _ in range(k)]
-                    imgs_k = jax.numpy.stack(batches)
-                    state, metrics = pt.multi_step(state, imgs_k, keys)
-                    images = batches[-1]
+                with span("train/dispatch"):
+                    if conditional:
+                        imgs_k = jax.numpy.stack([p[0] for p in batches])
+                        lbls_k = jax.numpy.stack([p[1] for p in batches])
+                        state, metrics = pt.multi_step(state, imgs_k, keys,
+                                                       lbls_k)
+                        images, labels = batches[-1]
+                    else:
+                        imgs_k = jax.numpy.stack(batches)
+                        state, metrics = pt.multi_step(state, imgs_k, keys)
+                        images = batches[-1]
             compiled_ks.add(k)  # dispatch returned: this shape is compiled
             new_step = step_num + k
             cur = {"step": new_step, "metrics": metrics,
@@ -1667,24 +1671,28 @@ def _train_run(cfg: TrainConfig, *, synthetic_data: bool,
                 # the tag is captured at dispatch, consumed whenever
                 cur["pipeline"] = pipeline.last_phase
 
-            host_t0 = time.perf_counter()
-            if deferred:
-                # lag-by-one metric window: consume the PREVIOUS step's
-                # scalars now — its D2H copies have had a full step to
-                # land, so the materialization below reads cached values
-                # instead of blocking dispatch on the device — and start
-                # this step's copies for the next iteration.
-                if pending is not None:
-                    prev, pending = pending, None
-                    if not _consume_or_rollback(prev):
-                        continue  # rolled back: restart from restored state
-                _stage(metrics)
-            else:
-                # inline escape hatch: NaN gate + step log at the original
-                # call site, synced to THIS step (true step latency)
-                if not _consume_or_rollback(cur):
-                    continue
-            timer.note_host(time.perf_counter() - host_t0)
+            # the dispatch thread's host work per iteration is what the two
+            # spans below time: their durations are perf/host_ms_mean and
+            # perf/dispatch_occupancy (StepTimer.note_host)
+            with span("train/consume") as consume:
+                if deferred:
+                    # lag-by-one metric window: consume the PREVIOUS step's
+                    # scalars now — its D2H copies have had a full step to
+                    # land, so the materialization below reads cached values
+                    # instead of blocking dispatch on the device — and start
+                    # this step's copies for the next iteration.
+                    if pending is not None:
+                        prev, pending = pending, None
+                        if not _consume_or_rollback(prev):
+                            continue  # rolled back: restart from restored
+                    _stage(metrics)
+                else:
+                    # inline escape hatch: NaN gate + step log at the
+                    # original call site, synced to THIS step (true step
+                    # latency)
+                    if not _consume_or_rollback(cur):
+                        continue
+            timer.note_host(consume.duration)
             # With per-step logging (the default, matching the reference's
             # every-step stdout log) each tick follows one metric
             # materialization — true step latency, lagged by one step in
@@ -1692,112 +1700,117 @@ def _train_run(cfg: TrainConfig, *, synthetic_data: bool,
             # cadence only.
             timer.tick(steps=k)
 
-            host_t0 = time.perf_counter()
-            if chief and writer.ready():
-                if deferred:
-                    cur["write_scalars"] = True  # written at the next flush
-                else:
-                    row = {**_host_vals(cur), **timer.summary(),
-                           **_health_extras(),
-                           **(prog.scalar_extras(new_step)
-                              if prog is not None else {})}
-                    svc.submit(lambda s=new_step, r=row:
-                               writer.write_scalars(s, r), tag="scalars")
-                snap = _snapshot_params(state["params"])
-                svc.submit(lambda s=new_step, t=snap:
-                           writer.write_histograms(s, param_histograms(t)),
-                           tag="histograms")
-            if deferred:
-                pending = cur
-            watchdog.disarm()  # dispatch/consume window completed
-
-            # Fleet health plane (ISSUE 6): one compact float32 allgather
-            # per cadence, issued HERE on the dispatch thread (collective-
-            # thread rule — a background-thread collective would interleave
-            # nondeterministically against step dispatches and wedge the
-            # mesh). Every process contributes its HEALTH_FIELDS vector;
-            # the chief materializes fleet/* (straggler skew, slowest
-            # host, queue/drop/recovery totals) and the slowest-host line
-            # is parked on the watchdog + flight recorder so a later trip
-            # names the likely wedged peer.
-            if cfg.fleet_health_steps and \
-                    new_step % cfg.fleet_health_steps == 0:
-                tsum = timer.summary()
-                c = registry.snapshot()
-                vec = np.asarray(
-                    [new_step, tsum.get("perf/step_ms_mean", 0.0),
-                     tsum.get("perf/host_ms_mean", 0.0), c.services_queue,
-                     c.services_dropped, c.rollbacks, c.corrupt_records,
-                     c.progressive_phase],
-                    np.float32)
-                with _guard("fleet-health", new_step):
-                    table = coordination.fleet_health_gather(vec)
-                frow, fleet_note = coordination.fleet_metrics(table)
-                watchdog.set_note(fleet_note)
-                flight.note = fleet_note
-                if chief:
-                    svc.submit(lambda s=new_step, r=frow:
-                               writer.write_scalars(s, r),
-                               tag="fleet-health")
-
-            # per-layer activation histograms + sparsity (the reference's
-            # _activation_summary channel, distriubted_model.py:75-80). The
-            # summarize DISPATCH runs on every process — it is a compiled
-            # mesh program — only the chief's device_get + write moves to
-            # the worker (the outputs are fresh replicated arrays; nothing
-            # donates them).
-            if cfg.activation_summary_steps and \
-                    new_step % cfg.activation_summary_steps == 0:
-                acts = pt.summarize(state, images,
-                                    jax.random.fold_in(key, 1),
-                                    labels) if conditional else \
-                    pt.summarize(state, images, jax.random.fold_in(key, 1))
-                if chief:
-                    _stage(acts)
-                    svc.submit(lambda s=new_step, a=acts:
-                               writer.write_activations(s,
-                                                        jax.device_get(a)),
-                               tag="activations")
-
-            if cfg.sample_every_steps and \
-                    new_step % cfg.sample_every_steps == 0:
-                imgs_dev = pt.sample(state, sample_z, sample_labels) \
-                    if sample_labels is not None \
-                    else pt.sample(state, sample_z)
-                if chief:
-                    _stage(imgs_dev)
-                    path = os.path.join(cfg.sample_dir,
-                                        f"train_{new_step:08d}.png")
-
-                    def _grid_task(s=new_step, a=imgs_dev, p=path):
-                        imgs = jax.device_get(a)
-                        save_sample_grid(p, imgs[:rows * cols], (rows, cols))
-                        writer.write_image_event(s, "samples", p)
-                    svc.submit(_grid_task, tag="sample-grid")
-                # held-out loss probe on the sample pipeline's batch with
-                # the fixed z — the reference's sess.run([sampler, d_loss,
-                # g_loss]) + print every 100 steps (image_train.py:179-192)
-                if sample_data is not None:
-                    if conditional:
-                        s_imgs, s_labels = next(sample_data)
-                        ev = pt.eval_losses(state, s_imgs, eval_z, s_labels)
+            with span("train/services") as services:
+                if chief and writer.ready():
+                    if deferred:
+                        # written at the next flush
+                        cur["write_scalars"] = True
                     else:
-                        s_imgs = next(sample_data)
-                        ev = pt.eval_losses(state, s_imgs, eval_z)
-                    if chief:
-                        _stage(ev)
+                        row = {**_host_vals(cur), **timer.summary(),
+                               **_health_extras(),
+                               **(prog.scalar_extras(new_step)
+                                  if prog is not None else {})}
+                        svc.submit(lambda s=new_step, r=row:
+                                   writer.write_scalars(s, r), tag="scalars")
+                    snap = _snapshot_params(state["params"])
+                    svc.submit(lambda s=new_step, t=snap:
+                               writer.write_histograms(s, param_histograms(t)),
+                               tag="histograms")
+                if deferred:
+                    pending = cur
+                watchdog.disarm()  # dispatch/consume window completed
 
-                        def _probe_task(s=new_step, e=ev):
-                            vals = {k: float(v) for k, v in
-                                    jax.device_get(e).items()}
-                            print(f"[dcgan_tpu] [sample] step {s} "
-                                  f"d_loss {vals['d_loss']:.8f} "
-                                  f"g_loss {vals['g_loss']:.8f}")
-                            writer.write_scalars(
-                                s, {f"sample/{k}": v
-                                    for k, v in vals.items()})
-                        svc.submit(_probe_task, tag="sample-probe")
-            timer.note_host(time.perf_counter() - host_t0)
+                # Fleet health plane (ISSUE 6): one compact float32 allgather
+                # per cadence, issued HERE on the dispatch thread
+                # (collective-thread rule — a background-thread collective
+                # would interleave nondeterministically against step
+                # dispatches and wedge the mesh). Every process
+                # contributes its HEALTH_FIELDS vector;
+                # the chief materializes fleet/* (straggler skew, slowest
+                # host, queue/drop/recovery totals) and the slowest-host line
+                # is parked on the watchdog + flight recorder so a later trip
+                # names the likely wedged peer.
+                if cfg.fleet_health_steps and \
+                        new_step % cfg.fleet_health_steps == 0:
+                    tsum = timer.summary()
+                    c = registry.snapshot()
+                    vec = np.asarray(
+                        [new_step, tsum.get("perf/step_ms_mean", 0.0),
+                         tsum.get("perf/host_ms_mean", 0.0), c.services_queue,
+                         c.services_dropped, c.rollbacks, c.corrupt_records,
+                         c.progressive_phase],
+                        np.float32)
+                    with _guard("fleet-health", new_step):
+                        table = coordination.fleet_health_gather(vec)
+                    frow, fleet_note = coordination.fleet_metrics(table)
+                    watchdog.set_note(fleet_note)
+                    flight.note = fleet_note
+                    if chief:
+                        svc.submit(lambda s=new_step, r=frow:
+                                   writer.write_scalars(s, r),
+                                   tag="fleet-health")
+
+                # per-layer activation histograms + sparsity (the reference's
+                # _activation_summary channel, distriubted_model.py:75-80). The
+                # summarize DISPATCH runs on every process — it is a compiled
+                # mesh program — only the chief's device_get + write moves to
+                # the worker (the outputs are fresh replicated arrays; nothing
+                # donates them).
+                if cfg.activation_summary_steps and \
+                        new_step % cfg.activation_summary_steps == 0:
+                    acts = pt.summarize(state, images,
+                                        jax.random.fold_in(key, 1),
+                                        labels) if conditional else \
+                        pt.summarize(state, images, jax.random.fold_in(key, 1))
+                    if chief:
+                        _stage(acts)
+                        svc.submit(lambda s=new_step, a=acts:
+                                   writer.write_activations(s,
+                                                            jax.device_get(a)),
+                                   tag="activations")
+
+                if cfg.sample_every_steps and \
+                        new_step % cfg.sample_every_steps == 0:
+                    imgs_dev = pt.sample(state, sample_z, sample_labels) \
+                        if sample_labels is not None \
+                        else pt.sample(state, sample_z)
+                    if chief:
+                        _stage(imgs_dev)
+                        path = os.path.join(cfg.sample_dir,
+                                            f"train_{new_step:08d}.png")
+
+                        def _grid_task(s=new_step, a=imgs_dev, p=path):
+                            imgs = jax.device_get(a)
+                            save_sample_grid(p, imgs[:rows * cols],
+                                             (rows, cols))
+                            writer.write_image_event(s, "samples", p)
+                        svc.submit(_grid_task, tag="sample-grid")
+                    # held-out loss probe on the sample pipeline's batch with
+                    # the fixed z — the reference's sess.run([sampler,
+                    # d_loss, g_loss]) + print every 100 steps
+                    # (image_train.py:179-192)
+                    if sample_data is not None:
+                        if conditional:
+                            s_imgs, s_labels = next(sample_data)
+                            ev = pt.eval_losses(state, s_imgs, eval_z,
+                                                s_labels)
+                        else:
+                            s_imgs = next(sample_data)
+                            ev = pt.eval_losses(state, s_imgs, eval_z)
+                        if chief:
+                            _stage(ev)
+
+                            def _probe_task(s=new_step, e=ev):
+                                vals = {k: float(v) for k, v in
+                                        jax.device_get(e).items()}
+                                print(f"[dcgan_tpu] [sample] step {s} "
+                                      f"d_loss {vals['d_loss']:.8f} "
+                                      f"g_loss {vals['g_loss']:.8f}")
+                                writer.write_scalars(
+                                    s, {f"sample/{k}": v
+                                        for k, v in vals.items()})
+                            svc.submit(_probe_task, tag="sample-probe")
+            timer.note_host(services.duration)
 
             # The in-training FID/KID probe stays ENTIRELY on the dispatch
             # thread: its real-side streaming, feature all-gathers, and

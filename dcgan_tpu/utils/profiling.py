@@ -6,6 +6,13 @@ and the dead `log_device_placement` flag (image_train.py:36). SURVEY.md names
 the TPU-native equivalent explicitly — "jax.profiler trace capture + per-step
 timing" — and this module is it:
 
+- `span`: the one span primitive (ISSUE 24). A context manager that puts a
+  `jax.profiler.TraceAnnotation` on the profiler's clock (so any capture —
+  --profile_dir, --profile_trigger, a benchmark's traced window — shows the
+  span on the host line above the device's kernels) and appends one
+  `SpanRecord` to a bounded in-memory ring per name; `spans()` hands the
+  records out. The feed (`feed/*`, data/pipeline.py) and the trainer loop
+  (`train/*`, train/trainer.py) record through it.
 - `StepTimer`: rolling per-step wall-time statistics (mean/p50/p90/max,
   steps/sec, images/sec) over a sliding window, emitted through the
   MetricWriter alongside the loss scalars.
@@ -43,7 +50,67 @@ import collections
 import contextlib
 import os
 import time
-from typing import Callable, Dict, Optional
+from typing import Callable, Dict, List, NamedTuple, Optional
+
+from jax.profiler import TraceAnnotation
+
+SPAN_RING = 4096  # records kept per span name; older ones fall off
+
+
+class SpanRecord(NamedTuple):
+    name: str
+    start: float             # time.perf_counter() at entry
+    duration: float          # seconds
+    count: Optional[int]     # what was counted at this boundary (the
+    #                          feed's queue depth), where there is one
+
+
+# One ring per name. No lock: a name's ring is made by dict.setdefault and
+# filled by deque.append, both atomic in CPython, so the threads that record
+# (the feed's producer, the dispatch thread) share nothing they could wait on.
+_rings: Dict[str, collections.deque] = {}
+
+
+class span:
+    """`with span("train/dispatch"):` — one span at a layer boundary.
+
+    Always on: with no capture running the TraceAnnotation is a no-op and
+    the cost is two clock reads and a deque append. `duration` is readable
+    after the block. A block left by an exception closes its annotation and
+    leaves no record.
+    """
+
+    __slots__ = ("name", "count", "start", "duration", "_annotation")
+
+    def __init__(self, name: str, count: Optional[int] = None):
+        self.name, self.count = name, count
+        self.duration = 0.0
+
+    def __enter__(self) -> "span":
+        self._annotation = TraceAnnotation(self.name)
+        self._annotation.__enter__()
+        self.start = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self.duration = time.perf_counter() - self.start
+        self._annotation.__exit__(exc_type, exc, tb)
+        if exc_type is None:
+            ring = _rings.get(self.name)
+            if ring is None:
+                ring = _rings.setdefault(
+                    self.name, collections.deque(maxlen=SPAN_RING))
+            ring.append(SpanRecord(self.name, self.start, self.duration,
+                                   self.count))
+
+
+def spans(name: Optional[str] = None) -> List[SpanRecord]:
+    """The records kept, oldest first: of one name, or of every name (by
+    start time). Nothing is written anywhere unless a caller asks here."""
+    if name is not None:
+        return list(_rings.get(name, ()))
+    out = [r for ring in list(_rings.values()) for r in list(ring)]
+    return sorted(out, key=lambda r: r.start)
 
 
 class StepTimer:
