@@ -12,20 +12,37 @@ instead of reading a stored probability matrix. O(S) HBM traffic in S instead
 of O(S^2) — the property that makes sequence length a free axis.
 
 Layout notes (TPU):
-- Blocks are [TQ, d] / [TK, d] with TQ = 256, TK = 1024 (chip-swept, see
-  BLOCK_Q/BLOCK_K below — NOT the 128 MXU edge: the systolic array stays
+- Forward blocks are [TQ, d] / [TK, d] with TQ = 256, TK = 1024 (chip-swept,
+  see BLOCK_Q/BLOCK_K below — NOT the 128 MXU edge: the systolic array stays
   busy either way, and wide k-tiles quarter the serialized online-softmax
   iterations); `q @ k^T` and `p @ v` land on the MXU in the input dtype
-  with f32 accumulation (`preferred_element_type`).
-- Grid is (B, S/TQ) for forward/dq and (B, S/TK) for dk/dv — the kernel loops
-  over the opposite axis with `lax.fori_loop`, keeping per-program state in
-  VMEM scratch.
+  with f32 accumulation (`preferred_element_type`). Grid (B, S/TQ), both
+  axes parallel; the kernel loops over k-tiles with `lax.fori_loop`.
+- The backward is ONE kernel, `flash_dq_dkv`: each [TQ, TK] score tile is
+  rebuilt once (s, p = exp(s - lse), dp, ds) and feeds all three gradient
+  products. Grid (B, S/TK): the batch axis is parallel, the k-tile axis is
+  SEQUENTIAL ("arbitrary") because dQ is a whole-sequence f32 accumulator
+  [S, dk] in VMEM scratch that every k-tile adds to, cast to the gradient
+  dtype into its resident output block at the last k-tile. The kernel loops
+  over q-tiles (BWD_BLOCK_Q = 1024) with dK^T / dV^T carried as values.
+- Every product over the tile is a PLAIN matmul: dQ = ds @ k, and dK^T =
+  q^T @ ds, dV^T = do^T @ p with q^T / do^T handed in already transposed
+  ([B, d, S], sequence on the lane axis — `_bwd_stats` builds them once).
+  Contracting over the tile's leading axis instead (ds^T @ q) sends the
+  whole [TQ, TK] tile through the transpose unit: 38 ms an instance where
+  this form takes 23 (v5e, batch 256, S 4096, d 8/32; PERF.md section 6).
+  dK^T / dV^T leave the kernel as [B, d, S] and XLA transposes them back.
+- Residents per batch row: q^T, do^T, lse, delta — all lane-dense along S,
+  so none pads; the dQ accumulator and its output block are the only
+  [S, d] (lane-padded) residents, which keeps S = 65536 compiling at
+  batch > 1 like the two-kernel backward it replaces.
 - The head dims here are narrow (SAGAN: d_qk = C/8, d_v = C/2); they ride the
   lane axis zero-padded. That wastes lanes but not HBM, and the kernels are
   shape-agnostic — the same code serves wide heads.
 - Off-TPU the kernels run under `interpret=True`, so the CPU test mesh
-  exercises the identical code path (tests/test_pallas_attention.py asserts
-  exactness against ops/attention.py::full_attention, gradients included).
+  exercises the identical code path (tests/test_flash_backward.py and
+  tests/test_pallas_attention.py assert exactness against
+  ops/attention.py::full_attention, gradients included).
 
 Composition: `ops/attention.py::attn_apply(use_pallas=True)` routes its dense
 path here (single chip, or per-shard under the shard_map backend — pallas_call
@@ -59,6 +76,11 @@ from jax.experimental.pallas import tpu as pltpu
 # shape; sweeps use a fresh process per grid point (bench_attention.py).
 BLOCK_Q = 256
 BLOCK_K = 1024
+# The backward's q-tile: it carries no softmax state from tile to tile, so a
+# taller tile only amortizes the per-iteration relayouts (lse/delta to
+# columns, the q^T/do^T tiles back to rows). 1024 beat 256 and 512 at every
+# shape swept on the v5e (S 1024-65536, d 8/32 and 64/64; PERF.md section 6).
+BWD_BLOCK_Q = 1024
 
 # Measurement generation: bump on ANY change that alters attention-kernel
 # performance characteristics (tile defaults, precision policy, block
@@ -66,8 +88,8 @@ BLOCK_K = 1024
 # tools/capture_all.py publishes only the highest generation present per
 # sequence length — so crossover tables never mix measurements of
 # different kernel code. Gen 2 = bf16-operand policy + (256, 1024) tiles +
-# lane-major backward stats.
-ATTN_GEN = 2
+# lane-major backward stats. Gen 3 = one backward kernel (flash_dq_dkv).
+ATTN_GEN = 3
 
 _NEG_INF = -1e30  # finite stand-in for -inf: keeps exp()/max() NaN-free
 
@@ -76,16 +98,17 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _compiler_params():
-    """Grid programs are independent (softmax state is loop-carried INSIDE a
-    program, never across grid steps), so both grid axes are parallel."""
+def _compiler_params(inner: str):
+    """The batch axis is always parallel. The forward's q-tile programs are
+    independent too (softmax state is loop-carried INSIDE a program);
+    the backward's k-tile axis is "arbitrary": dQ accumulates across it."""
     if _interpret():
         return None
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel"),
-        # the dkv kernel holds full-sequence q/do residents (double-buffered
-        # across the batch grid axis); the default VMEM budget is tighter
-        # than the hardware's — claim most of the 128 MiB explicitly
+        dimension_semantics=("parallel", inner),
+        # both kernels hold full-sequence residents (double-buffered across
+        # the batch grid axis); the default VMEM budget is tighter than the
+        # hardware's — claim most of the 128 MiB explicitly
         vmem_limit_bytes=100 * 1024 * 1024)
 
 
@@ -111,8 +134,8 @@ def _tile(s: int, which: str, default: int) -> int:
     return s
 
 
-def _blocks(s: int) -> tuple:
-    return _tile(s, "TQ", BLOCK_Q), _tile(s, "TK", BLOCK_K)
+def _blocks(s: int, block_q: int = BLOCK_Q) -> tuple:
+    return _tile(s, "TQ", block_q), _tile(s, "TK", BLOCK_K)
 
 
 # ---------------------------------------------------------------------------
@@ -173,7 +196,7 @@ def _fwd_impl(q, k, v, scale):
                    pl.BlockSpec((1, tq, 1), lambda b, i: (b, i, 0))),
         out_shape=(jax.ShapeDtypeStruct((B, S, dv), jnp.float32),
                    jax.ShapeDtypeStruct((B, S, 1), jnp.float32)),
-        compiler_params=_compiler_params(),
+        compiler_params=_compiler_params("parallel"),
         interpret=_interpret(),
     )(q, k, v)
     return out, lse
@@ -183,147 +206,108 @@ def _fwd_impl(q, k, v, scale):
 # backward
 # ---------------------------------------------------------------------------
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
-               scale, tk):
-    # same operand-dtype / f32-accumulation policy as the forward; the
-    # cotangent do arrives pre-cast to the operand dtype (_bwd_impl)
-    q = q_ref[0]
-    mmdt = q.dtype
-    do = do_ref[0]
-    lse = lse_ref[0]                                     # [TQ, 1]
-    delta = delta_ref[0]                                 # [TQ, 1]
-    tq, dk = q.shape
-    n_k = k_ref.shape[1] // tk
-
-    def body(j, dq):
-        kb = k_ref[0, pl.ds(j * tk, tk), :]
-        vb = v_ref[0, pl.ds(j * tk, tk), :]
-        s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        p = jnp.exp(s - lse)                             # [TQ, TK]
-        dp = jax.lax.dot_general(do, vb, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        return dq + jnp.dot(ds.astype(mmdt), kb,
-                            preferred_element_type=jnp.float32) * scale
-
-    dq = lax.fori_loop(0, n_k, body, jnp.zeros((tq, dk), jnp.float32))
-    dq_ref[0] = dq.astype(dq_ref.dtype)
-
-
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, *, scale, tq):
-    # This kernel walks ALL q-tiles per program, so q/do/lse/delta enter as
-    # full-sequence residents. lse/delta arrive packed [1, 1, S] (sequence
-    # on the LANE axis — a [S, 1] layout would lane-pad 128x and scale
-    # VMEM residency with S, which walled compilation at large S/batch);
-    # do arrives pre-cast to the operand dtype by _bwd_impl.
+def _dq_dkv_kernel(k_ref, v_ref, qT_ref, doT_ref, lse_ref, delta_ref,
+                   dq_ref, dkT_ref, dvT_ref, dq_acc, *, scale, tq):
+    # same operand-dtype / f32-accumulation policy as the forward. One
+    # k-tile per program; q^T/do^T/lse/delta enter as full-sequence
+    # residents with the sequence on the LANE axis (a [S, d] or [S, 1]
+    # layout would lane-pad up to 128x and scale VMEM residency with S,
+    # which walled compilation at large S/batch).
+    j = pl.program_id(1)
     kb = k_ref[0]                                        # [TK, dk]
     vb = v_ref[0]                                        # [TK, dv]
     mmdt = kb.dtype
-    tk, dkd = kb.shape
-    dvd = vb.shape[-1]
-    n_q = q_ref.shape[1] // tq
+    n_q = qT_ref.shape[2] // tq
+
+    @pl.when(j == 0)
+    def _():
+        dq_acc[...] = jnp.zeros(dq_acc.shape, jnp.float32)
 
     def body(i, carry):
-        dk_acc, dv_acc = carry
-        q = q_ref[0, pl.ds(i * tq, tq), :]
-        do = do_ref[0, pl.ds(i * tq, tq), :]
-        lse = lse_ref[0, 0, pl.ds(i * tq, tq)][:, None]  # [TQ, 1]
-        delta = delta_ref[0, 0, pl.ds(i * tq, tq)][:, None]
-        s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
+        dkT, dvT = carry
+        rows = pl.ds(pl.multiple_of(i * tq, tq), tq)
+        qT = qT_ref[0, :, rows]                          # [dk, TQ]
+        doT = doT_ref[0, :, rows]                        # [dv, TQ]
+        lse = lse_ref[0, 0, rows][:, None]               # [TQ, 1]
+        delta = delta_ref[0, 0, rows][:, None]
+        s = jax.lax.dot_general(qT.T, kb, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         p = jnp.exp(s - lse)                             # [TQ, TK]
-        dp = jax.lax.dot_general(do, vb, (((1,), (1,)), ((), ())),
+        dp = jax.lax.dot_general(doT.T, vb, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)                            # [TQ, TK]
-        dk_acc = dk_acc + jax.lax.dot_general(
-            ds.astype(mmdt), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        dv_acc = dv_acc + jax.lax.dot_general(
-            p.astype(mmdt), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return dk_acc, dv_acc
+        ds = (p * (dp - delta)).astype(mmdt)             # [TQ, TK]
+        dq_acc[rows, :] += jnp.dot(ds, kb,
+                                   preferred_element_type=jnp.float32)
+        dkT = dkT + jnp.dot(qT, ds, preferred_element_type=jnp.float32)
+        dvT = dvT + jnp.dot(doT, p.astype(mmdt),
+                            preferred_element_type=jnp.float32)
+        return dkT, dvT
 
-    dk_acc, dv_acc = lax.fori_loop(
-        0, n_q, body, (jnp.zeros((tk, dkd), jnp.float32),
-                       jnp.zeros((tk, dvd), jnp.float32)))
-    dk_ref[0] = dk_acc.astype(dk_ref.dtype)
-    dv_ref[0] = dv_acc.astype(dv_ref.dtype)
+    dkT, dvT = lax.fori_loop(
+        0, n_q, body, (jnp.zeros(dkT_ref.shape[1:], jnp.float32),
+                       jnp.zeros(dvT_ref.shape[1:], jnp.float32)))
+    dkT_ref[0] = (dkT * scale).astype(dkT_ref.dtype)
+    dvT_ref[0] = dvT.astype(dvT_ref.dtype)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _():
+        dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
 def _bwd_stats(q, out, lse, g):
     """The hop-invariant backward inputs, computed once per backward pass
-    (the ring backward reuses them across every hop):
+    (the ring backward reuses them across every hop), all with the sequence
+    on the LANE axis — the kernel holds them full-sequence, and a [S, d]
+    block lane-pads up to 128x (8 MiB at S=16384 where 64 KiB is the data):
 
-    - delta_i = rowsum(dO_i * O_i) — the softmax-jacobian correction term;
-      one fused elementwise reduction, XLA handles it. [B, S, 1] like lse.
-    - do: the f32 cotangent cast to the matmul operand dtype ONCE — under
-      bf16 it halves do's HBM traffic and its full-array VMEM residency in
-      the dkv kernel.
-    - lse_r/delta_r: lane-major packing for the two per-row stats — the
-      dkv kernel holds them full-sequence, and a [S, 1] block lane-pads
-      128x (8 MiB at S=16384 where 64 KiB is the data); [1, S] keeps S on
-      the lane axis.
+    - qT [B, dk, S]: q transposed, so dK^T = q^T @ ds is a plain matmul.
+    - doT [B, dv, S]: the f32 cotangent cast to the matmul operand dtype
+      ONCE — under bf16 it halves its HBM traffic and VMEM residency — and
+      transposed like q.
+    - lse, and delta_i = rowsum(dO_i * O_i), the softmax-jacobian correction
+      term (one fused elementwise reduction, XLA handles it): [B, 1, S].
     """
     B, S, _ = q.shape
-    delta = jnp.sum(g.astype(jnp.float32) * out, axis=-1, keepdims=True)
-    do = g.astype(q.dtype)
-    return do, delta, lse.reshape(B, 1, S), delta.reshape(B, 1, S)
+    delta = jnp.sum(g.astype(jnp.float32) * out, axis=-1)
+    return (jnp.swapaxes(q, 1, 2), jnp.swapaxes(g.astype(q.dtype), 1, 2),
+            lse.reshape(B, 1, S), delta.reshape(B, 1, S))
 
 
 def _bwd_impl(scale, res, g):
     q, k, v, out, lse = res
-    do, delta, lse_r, delta_r = _bwd_stats(q, out, lse, g)
-    return _bwd_core(scale, q, k, v, do, lse, delta, lse_r, delta_r)
+    return _bwd_core(scale, k, v, *_bwd_stats(q, out, lse, g))
 
 
-def _bwd_core(scale, q, k, v, do, lse, delta, lse_r, delta_r,
-              grad_dtype=None):
-    """The two backward pallas_calls. grad_dtype overrides the gradient
-    output dtype (the ring backward asks for f32 so per-hop contributions
-    are not rounded to bf16 before the cross-hop accumulation)."""
-    B, S, dk = q.shape
+def _bwd_core(scale, k, v, qT, doT, lse, delta, grad_dtype=None):
+    """The backward pallas_call: dQ, dK and dV from one pass over the score
+    tiles. grad_dtype overrides the gradient output dtype (the ring
+    backward asks for f32 so per-hop contributions are not rounded to bf16
+    before the cross-hop accumulation)."""
+    B, S, dk = k.shape
     dv = v.shape[-1]
-    tq, tk = _blocks(S)
-    dq_dt = grad_dtype or q.dtype
-    dk_dt = grad_dtype or k.dtype
-    dv_dt = grad_dtype or v.dtype
+    tq, tk = _blocks(S, BWD_BLOCK_Q)
 
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, tk=tk),
-        name="flash_dq",
-        grid=(B, S // tq),
-        in_specs=[pl.BlockSpec((1, tq, dk), lambda b, i: (b, i, 0)),
-                  pl.BlockSpec((1, S, dk), lambda b, i: (b, 0, 0)),
-                  pl.BlockSpec((1, S, dv), lambda b, i: (b, 0, 0)),
-                  pl.BlockSpec((1, tq, dv), lambda b, i: (b, i, 0)),
-                  pl.BlockSpec((1, tq, 1), lambda b, i: (b, i, 0)),
-                  pl.BlockSpec((1, tq, 1), lambda b, i: (b, i, 0))],
-        out_specs=pl.BlockSpec((1, tq, dk), lambda b, i: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, S, dk), dq_dt),
-        compiler_params=_compiler_params(),
-        interpret=_interpret(),
-    )(q, k, v, do, lse, delta)
+    def resident(d):
+        return pl.BlockSpec((1, d, S), lambda b, j: (b, 0, 0))
 
-    dk_arr, dv_arr = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=scale, tq=tq),
-        name="flash_dkv",
+    dq, dkT, dvT = pl.pallas_call(
+        functools.partial(_dq_dkv_kernel, scale=scale, tq=tq),
+        name="flash_dq_dkv",
         grid=(B, S // tk),
-        in_specs=[pl.BlockSpec((1, S, dk), lambda b, j: (b, 0, 0)),
-                  pl.BlockSpec((1, tk, dk), lambda b, j: (b, j, 0)),
+        in_specs=[pl.BlockSpec((1, tk, dk), lambda b, j: (b, j, 0)),
                   pl.BlockSpec((1, tk, dv), lambda b, j: (b, j, 0)),
-                  pl.BlockSpec((1, S, dv), lambda b, j: (b, 0, 0)),
-                  pl.BlockSpec((1, 1, S), lambda b, j: (b, 0, 0)),
-                  pl.BlockSpec((1, 1, S), lambda b, j: (b, 0, 0))],
-        out_specs=(pl.BlockSpec((1, tk, dk), lambda b, j: (b, j, 0)),
-                   pl.BlockSpec((1, tk, dv), lambda b, j: (b, j, 0))),
-        out_shape=(jax.ShapeDtypeStruct((B, S, dk), dk_dt),
-                   jax.ShapeDtypeStruct((B, S, dv), dv_dt)),
-        compiler_params=_compiler_params(),
+                  resident(dk), resident(dv), resident(1), resident(1)],
+        out_specs=(pl.BlockSpec((1, S, dk), lambda b, j: (b, 0, 0)),
+                   pl.BlockSpec((1, dk, tk), lambda b, j: (b, 0, j)),
+                   pl.BlockSpec((1, dv, tk), lambda b, j: (b, 0, j))),
+        out_shape=(jax.ShapeDtypeStruct((B, S, dk), grad_dtype or qT.dtype),
+                   jax.ShapeDtypeStruct((B, dk, S), grad_dtype or k.dtype),
+                   jax.ShapeDtypeStruct((B, dv, S), grad_dtype or v.dtype)),
+        scratch_shapes=[pltpu.VMEM((S, dk), jnp.float32)],
+        compiler_params=_compiler_params("arbitrary"),
         interpret=_interpret(),
-    )(q, k, v, do, lse_r, delta_r)
-    return dq, dk_arr, dv_arr
+    )(k, v, qT, doT, lse, delta)
+    return dq, jnp.swapaxes(dkT, 1, 2), jnp.swapaxes(dvT, 1, 2)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -362,7 +346,7 @@ def ring_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     `_fwd_impl` — each block contributes a normalized partial (out_b, lse_b)
     and partials merge associatively: lse = logaddexp(lse_a, lse_b),
     out = out_a*exp(lse_a-lse) + out_b*exp(lse_b-lse). The backward
-    re-rotates (k, v) around the ring and reuses `_bwd_impl` per hop with
+    re-rotates (k, v) around the ring and reuses `_bwd_core` per hop with
     the GLOBAL lse (p = exp(s - lse_global) gives each block's true global
     probabilities), accumulating dq locally while (dk, dv) ride the ring
     with their blocks and land home after the full cycle.
@@ -407,10 +391,10 @@ def _ring_flash_vjp_bwd(scale, axis_name, n_shards, res, g):
     q, k, v, out, lse = res
     fwd = [(i, (i + 1) % n_shards) for i in range(n_shards)]
 
-    # hop-invariant backward inputs computed ONCE (delta, the operand-dtype
-    # cotangent, and the lane-major stat packings) — only the two pallas
-    # kernels re-run per hop
-    do, delta, lse_r, delta_r = _bwd_stats(q, out, lse, g)
+    # hop-invariant backward inputs computed ONCE (q^T, the operand-dtype
+    # cotangent transposed, lse and delta lane-major) — only the backward
+    # kernel re-runs per hop
+    stats = _bwd_stats(q, out, lse, g)
 
     def hop(carry, _):
         # (k, v) and their accumulated gradients travel TOGETHER: each
@@ -420,9 +404,8 @@ def _ring_flash_vjp_bwd(scale, axis_name, n_shards, res, g):
         # terms come out of the kernels ALREADY f32 (grad_dtype) so the
         # cross-hop accumulation never rounds through bf16.
         k_blk, v_blk, dk_c, dv_c, dq = carry
-        dq_h, dk_h, dv_h = _bwd_core(
-            scale, q, k_blk, v_blk, do, lse, delta, lse_r, delta_r,
-            grad_dtype=jnp.float32)
+        dq_h, dk_h, dv_h = _bwd_core(scale, k_blk, v_blk, *stats,
+                                     grad_dtype=jnp.float32)
         dq = dq + dq_h
         dk_c = dk_c + dk_h
         dv_c = dv_c + dv_h

@@ -92,12 +92,16 @@ class TestFlashAttention:
         out = flash_attention(q, k, v, scale)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-6)
-        g_ref = jax.grad(lambda q: jnp.sum(
-            full_attention(q, k, v, scale=scale) ** 2))(q)
-        g_fl = jax.grad(lambda q: jnp.sum(
-            flash_attention(q, k, v, scale) ** 2))(q)
-        np.testing.assert_allclose(np.asarray(g_fl), np.asarray(g_ref),
-                                   atol=2e-5)
+        # all three: dq sums over k-tiles across grid steps, dk/dv over
+        # q-tiles inside one (the fused backward, flash_dq_dkv)
+        g_ref = jax.grad(lambda q, k, v: jnp.sum(
+            full_attention(q, k, v, scale=scale) ** 2), argnums=(0, 1, 2))(
+                q, k, v)
+        g_fl = jax.grad(lambda q, k, v: jnp.sum(
+            flash_attention(q, k, v, scale) ** 2), argnums=(0, 1, 2))(q, k, v)
+        for a, b in zip(g_ref, g_fl):
+            np.testing.assert_allclose(np.asarray(b), np.asarray(a),
+                                       atol=2e-5)
 
 
 class TestRingFlash:

@@ -22,7 +22,7 @@ PACKAGE = os.path.join(ROOT, "dcgan_tpu")
 TRAIN_SPANS = ("train/next", "train/dispatch", "train/consume",
                "train/services")
 PALLAS_NAMES = {
-    "ops/pallas_attention.py": ["flash_fwd", "flash_dq", "flash_dkv"],
+    "ops/pallas_attention.py": ["flash_fwd", "flash_dq_dkv"],
     "ops/pallas_kernels.py": ["bn_moments", "bn_apply", "bn_bwd"],
     "ops/pallas_fused.py": ["fused_conv_stats", "fused_conv_apply"],
 }
@@ -301,7 +301,7 @@ class TestNamesInTheLoweredStep:
             "d_step/loss/jvp(disc)", "d_step/loss/jvp(gen)",
             "g_step/loss/jvp(disc)", "g_step/loss/jvp(gen)"]
         # one location per site; D's real and fake pass share theirs
-        assert len(sites("flash_dq")) == len(sites("flash_dkv")) == 3
+        assert len(sites("flash_dq_dkv")) == 3
 
 
 class TestNothingIsLeftUnnamed:

@@ -139,16 +139,19 @@ def test_fused_tiles_are_lane_aligned_and_fit_vmem(preset):
 
 
 @pytest.mark.usefixtures("compiled_kernels")
-@pytest.mark.parametrize("seq", [1024, 4096])
+@pytest.mark.parametrize("seq", [1024, 4096, 16384])
 def test_flash_attention_fwd_and_vjp(v5e, seq):
-    """sagan64 (S=1024) and sagan128 (S=4096): 64 channels at the attention
-    site, so q/k project to 8 lanes and v to 32 (ops/attention.py)."""
+    """sagan64 (S=1024), sagan128 (S=4096) and sagan256-lc (S=16384): 64
+    channels at the attention site, so q/k project to 8 lanes and v to 32
+    (ops/attention.py). The backward's whole-sequence dQ accumulator is
+    what grows with S."""
     def loss(q, k, v):
         return jnp.sum(pallas_attention.flash_attention(q, k, v, 8 ** -0.5))
 
     qk = _sds((BATCH, seq, 8), jnp.bfloat16, v5e)
-    _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), qk, qk,
-             _sds((BATCH, seq, 32), jnp.bfloat16, v5e))
+    text = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), qk, qk,
+                    _sds((BATCH, seq, 32), jnp.bfloat16, v5e))
+    assert "flash_fwd" in text and "flash_dq_dkv" in text
 
 
 @pytest.mark.slow
