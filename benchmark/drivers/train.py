@@ -13,6 +13,12 @@ cadence, NaN gate, flight recorder, services, checkpoints) is not in it.
 After the window: the memory peak is read, the trace reduced, the
 program's state freed, and only then the plain reference follows the first
 two of those steps (`benchmark/check.py` says what is compared).
+
+This file is the same for every model family. What depends on the family
+(the draw of a batch and of each leaf, what is read from the state, the
+reference, the numbers worked out from the two, the operation counts) is
+looked up by the configuration's `family` key: `manifest.family`,
+`families/<family>.py`.
 """
 
 from __future__ import annotations
@@ -24,11 +30,11 @@ import math
 import shutil
 import tempfile
 import time
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List
 
 import numpy as np
 
-from benchmark import check, manifest, reference, tracing, traffic, weights
+from benchmark import check, manifest, tracing, traffic, weights
 
 WARMUP_STEPS = 3      # the program's first steps, all through the window's call
 CHECK_STEPS = 2       # of which the reference follows two: at 4 s a step for
@@ -65,26 +71,15 @@ def program_config(cell: manifest.Cell):
         mesh=MeshConfig(**mix["mesh"]), backend=mix["backend"])
 
 
-def reference_configs(conf: dict):
-    mcfg = dict(conf["model"], attn_qk_div=conf["attn_qk_div"],
-                attn_v_div=conf["attn_v_div"])
-    return mcfg, dict(conf["train"])
-
-
-def _moment_leaves(opt_state, moment: str) -> Dict[str, Any]:
-    """{"gen/deconv1/w": leaf} out of the optimizer state: the leaves under
-    Adam's `mu` or `nu`, named by the dict keys that follow it."""
-    import jax
-
-    out = {}
-    for net in ("gen", "disc"):
-        flat, _ = jax.tree_util.tree_flatten_with_path(opt_state[net])
-        for path, leaf in flat:
-            keys = [getattr(k, "name", getattr(k, "key", None)) for k in path]
-            if moment in keys:
-                tail = [str(k) for k in keys[keys.index(moment) + 1:]]
-                out["/".join([net] + tail)] = leaf
-    return out
+@dataclasses.dataclass
+class Inputs:
+    """What program and reference share, and what outlives the program when
+    its state is freed: the family, the mesh, and the draws from the seed."""
+    family: Any             # the module of the configuration's model family
+    mesh: Any
+    batch_shape: tuple      # of one global batch, as the family states it
+    batch_sharding: Any
+    draw: Callable          # (key, dtype=None) -> the model state, drawn
 
 
 @dataclasses.dataclass
@@ -92,77 +87,68 @@ class Program:
     """The compiled step with what the harness needs around it."""
     cfg: Any
     pt: Any
-    mesh: Any
-    img_sharding: Any
-    shapes: Any
+    inputs: Inputs
     overwrite: Callable     # (state, key) -> state with benchmark weights
-    grad_norms: Callable    # (opt state) -> {leaf: ||first gradient||}
-    grad_leaves: Callable   # (opt state) -> {leaf: first gradient}
-    stat_leaves: Callable   # (bn state, key) -> {leaf: its change since init}
-    delta_norms: Callable   # (params, key) -> {leaf: ||change||}
+    read_first: Dict[str, Callable]   # after step 1: name -> (state, key)
+    read_last: Dict[str, Callable]    # after step CHECK_STEPS
 
 
-def build_program(cell: manifest.Cell, devices) -> Program:
+def build_program(cell: manifest.Cell, devices, wanted=None) -> Program:
+    """`wanted`: the numbers this run compares (a family may read less where
+    none of them needs it, as `gan` the first gradient); None reads all."""
     import jax
-    import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from dcgan_tpu.parallel import batch_sharding, make_mesh, make_parallel_train
 
+    fam = manifest.family(cell.root, cell.config)
     cfg = program_config(cell)
     mesh = make_mesh(cfg.mesh, devices[:cell.chips])
     pt = make_parallel_train(cfg, mesh)
-    shapes = jax.eval_shape(lambda k: pt.init(k), jax.random.key(0))
+    shapes = fam.drawn(jax.eval_shape(lambda k: pt.init(k), jax.random.key(0)))
     rep = NamedSharding(mesh, P())
+    shape = tuple(fam.batch_shape(cell.config, cfg.batch_size))
 
-    def overwrite(state, key):
-        ms = weights.make_model_state(shapes, key)
-        return {**state, "params": ms["params"], "bn": ms["bn"],
-                "ema_gen": jax.tree.map(jnp.copy, ms["params"]["gen"])}
+    def draw(key, dtype=None):
+        return weights.draw_tree(shapes, key, fam.draw_leaf, dtype)
 
-    def grad_norms(opt_state):
-        return {n: jnp.sqrt(jnp.sum(v.astype(jnp.float32)) / (1.0 - BETA2))
-                for n, v in _moment_leaves(opt_state, "nu").items()}
+    def reading(fn):
+        return jax.jit(lambda state, key: fn(state, draw(key)),
+                       out_shardings=rep)
 
-    def grad_leaves(opt_state):
-        # after one step from zero moments mu is (1 - beta1) x the gradient
-        return {n: m.astype(jnp.float32) / (1.0 - cfg.beta1)
-                for n, m in _moment_leaves(opt_state, "mu").items()}
-
-    def stat_leaves(bn, key):
-        return reference.stat_changes(
-            bn, weights.make_model_state(shapes, key)["bn"])
-
-    def delta_norms(params, key):
-        return reference.delta_norms(
-            params, weights.make_model_state(shapes, key)["params"])
-
+    reads = fam.program_readings(cell.config, wanted)
     return Program(
-        cfg=cfg, pt=pt, mesh=mesh, img_sharding=batch_sharding(mesh, 4),
-        shapes=shapes,
-        overwrite=jax.jit(overwrite, out_shardings=pt.shardings,
-                          donate_argnums=(0,)),
-        grad_norms=jax.jit(grad_norms, out_shardings=rep),
-        grad_leaves=jax.jit(grad_leaves, out_shardings=rep),
-        stat_leaves=jax.jit(stat_leaves, out_shardings=rep),
-        delta_norms=jax.jit(delta_norms, out_shardings=rep))
+        cfg=cfg, pt=pt,
+        inputs=Inputs(family=fam, mesh=mesh, batch_shape=shape,
+                      batch_sharding=batch_sharding(mesh, len(shape)),
+                      draw=draw),
+        overwrite=jax.jit(
+            lambda state, key: fam.initial_state(state, draw(key)),
+            out_shardings=pt.shardings, donate_argnums=(0,)),
+        read_first={n: reading(f) for n, f in reads["first"].items()},
+        read_last={n: reading(f) for n, f in reads["last"].items()})
 
 
 def initial_state(prog: Program, seed: int):
-    """The program's own init (optimizer state, counters), then every
-    weight, BN statistic and power-iteration vector drawn by the benchmark."""
-    state = prog.pt.init(weights.seed_key(seed, 9))
-    return prog.overwrite(state, weights.seed_key(seed, 0))
+    """The program's own init (optimizer state, counters), then every leaf
+    the family has the benchmark draw (for `gan`: weights, BN statistics,
+    power-iteration vectors)."""
+    state = prog.pt.init(weights.seed_key(seed, weights.PROGRAM_INIT))
+    return prog.overwrite(state, weights.seed_key(seed, weights.WEIGHTS))
+
+
+def resident_batches(cell: manifest.Cell, inp: Inputs, seed: int) -> List:
+    return traffic.resident_batches(
+        weights.seed_key(seed, weights.BATCHES),
+        int(cell.traffic["resident_batches"]), inp.batch_shape,
+        inp.batch_sharding, inp.family.draw_batch)
 
 
 def make_feed(cell: manifest.Cell, prog: Program, seed: int, cache_root: str):
     """(iterator of device batches, close())."""
-    mix, m = cell.traffic, prog.cfg.model
-    shape = (prog.cfg.batch_size, m.output_size, m.output_size, m.c_dim)
+    mix, inp = cell.traffic, prog.inputs
     if mix["feed"] == "resident":
-        batches = traffic.resident_batches(
-            weights.seed_key(seed, 1), int(mix["resident_batches"]),
-            shape, prog.img_sharding)
+        batches = resident_batches(cell, inp, seed)
 
         def cycle():
             i = 0
@@ -174,10 +160,11 @@ def make_feed(cell: manifest.Cell, prog: Program, seed: int, cache_root: str):
 
     from dcgan_tpu.data import DataConfig, make_dataset
 
-    data_dir = traffic.ensure_records(cache_root, mix["records"],
-                                      m.output_size, m.c_dim)
+    _, size, _, channels = inp.batch_shape      # records hold images
+    data_dir = traffic.ensure_records(cache_root, mix["records"], size,
+                                      channels)
     dcfg = DataConfig(
-        data_dir=data_dir, image_size=m.output_size, channels=m.c_dim,
+        data_dir=data_dir, image_size=size, channels=channels,
         batch_size=prog.cfg.batch_size // jax.process_count(),
         record_dtype=mix["records"]["dtype"],
         min_after_dequeue=int(mix.get("shuffle_buffer",
@@ -186,48 +173,43 @@ def make_feed(cell: manifest.Cell, prog: Program, seed: int, cache_root: str):
                               prog.cfg.num_loader_threads)),
         seed=int(seed) % (2 ** 31), normalize=prog.cfg.normalize_inputs,
         prefetch_device_batches=prog.cfg.prefetch_device_batches)
-    feed = make_dataset(dcfg, prog.img_sharding)
+    feed = make_dataset(dcfg, inp.batch_sharding)
     return feed, getattr(feed, "close", lambda: None)
 
 
-def first_steps(prog: Program, state, feed, seed: int, keep_batches: bool,
-                keep_gradient: bool = True):
+def first_steps(prog: Program, state, feed, seed: int, keep_batches: bool):
     """The first WARMUP_STEPS steps through the window's own call and feed,
-    with the readings of the first CHECK_STEPS of them (the first gradient
-    itself only where a number of the cell needs it).
+    with what the family reads of the first CHECK_STEPS of them.
     Returns (state, base key, readings, the batches if asked for)."""
     import jax
 
-    base = weights.seed_key(seed, 2)
-    losses, kept, grad, gvec, stats, delta = [], [], None, None, None, None
-    key0 = weights.seed_key(seed, 0)
+    base = weights.seed_key(seed, weights.STEP_KEYS)
+    key0 = weights.seed_key(seed, weights.WEIGHTS)
+    losses, kept, read = [], [], {}
     for i in range(WARMUP_STEPS):
-        images = next(feed)
+        batch = next(feed)
         if keep_batches and i < CHECK_STEPS:
-            kept.append(images)
-        state, m = prog.pt.step(state, images, jax.random.fold_in(base, i))
+            kept.append(batch)
+        state, m = prog.pt.step(state, batch, jax.random.fold_in(base, i))
         if i < CHECK_STEPS:
             losses.append(m)
         if i == 0:
-            grad = prog.grad_norms(state["opt"])
-            if keep_gradient:
-                gvec = prog.grad_leaves(state["opt"])
-            stats = prog.stat_leaves(state["bn"], key0)
+            read.update({n: f(state, key0) for n, f in prog.read_first.items()})
         if i == CHECK_STEPS - 1:
-            delta = prog.delta_norms(state["params"], key0)
+            read.update({n: f(state, key0) for n, f in prog.read_last.items()})
     # reading the last warm-up step's losses back too drains the device, so
-    # that the window starts with nothing in flight. Where the first
-    # gradient itself is kept (the size of the parameters) it waits on the
+    # that the window starts with nothing in flight. What is read as whole
+    # leaves (the first gradient, the size of the parameters) waits on the
     # host for the reference's: the window's device memory is the program's
-    got = jax.device_get({"losses": losses, "grad": grad, "delta": delta,
-                          "gvec": gvec, "stats": stats, "last": m})
-    del gvec
-    readings = {
-        "losses": [{k: float(v) for k, v in m.items()} for m in got["losses"]],
-        "grad": {k: float(v) for k, v in got["grad"].items()},
-        "delta": {k: float(v) for k, v in got["delta"].items()},
-        "gvec": got["gvec"], "stats": got["stats"]}
-    return state, base, readings, kept
+    got = jax.device_get({"losses": losses, **read, "last": m})
+    del read, got["last"]
+    return state, base, _scalars_to_float(got), kept
+
+
+def _scalars_to_float(tree):
+    import jax
+
+    return jax.tree.map(lambda v: float(v) if np.ndim(v) == 0 else v, tree)
 
 
 def window(prog: Program, state, feed, base, seconds: float, in_flight: int):
@@ -255,12 +237,12 @@ def window(prog: Program, state, feed, base, seconds: float, in_flight: int):
         while True:
             t = clock()
             with TraceAnnotation("bench_next"):
-                images = next(feed)
+                batch = next(feed)
             spans["next"].append(clock() - t)
             key = jax.random.fold_in(base, WARMUP_STEPS + steps)
             t = clock()
             with TraceAnnotation("bench_step"):
-                state, m = prog.pt.step(state, images, key)
+                state, m = prog.pt.step(state, batch, key)
             spans["step"].append(clock() - t)
             pending.append(m)
             steps += 1
@@ -325,74 +307,20 @@ def replica_gap(params, n_devices: int) -> float:
 
 # --- the reference's side ----------------------------------------------------
 
-def reference_readings(cell: manifest.Cell, mesh, shapes, seed: int,
-                       batches: List, *, operand: str = "float32",
-                       rows: Optional[slice] = None) -> dict:
-    """The plain reference through the same CHECK_STEPS steps: same weights,
-    batches and keys. `operand` and `rows` are the control's and the
-    faults' knobs (lower precision; a part of the batch only)."""
-    import jax
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    mcfg, tcfg = reference_configs(cell.config)
-    rep = NamedSharding(mesh, P())
-    key0 = weights.seed_key(seed, 0)
-    f32 = jax.tree.map(
-        lambda s: jax.ShapeDtypeStruct(s.shape, "float32"), shapes)
-    make = jax.jit(lambda k: reference.init_state(
-        weights.make_model_state(f32, k)), out_shardings=rep)
-    params0 = jax.jit(lambda k: weights.make_model_state(f32, k)["params"],
-                      out_shardings=rep)
-    n_shards = 1 if rows is not None else mesh.shape["data"]
-    step = reference.make_step(mcfg, tcfg, operand, n_shards)
-    state = make(key0)
-    base = weights.seed_key(seed, 2)
-    first_gradient = jax.jit(
-        lambda opt: reference.first_gradient(opt, tcfg), out_shardings=rep)
-    stat_changes = jax.jit(
-        lambda bn, k: reference.stat_changes(
-            bn, weights.make_model_state(f32, k)["bn"]), out_shardings=rep)
-    losses, grad, gvec, stats = [], None, None, None
-    for i in range(CHECK_STEPS):
-        images = batches[i] if rows is None else \
-            jax.device_put(batches[i][rows], rep)
-        state, loss, norms = step(state, images, jax.random.fold_in(base, i))
-        losses.append(loss)
-        if i == 0:
-            grad, gvec = norms, first_gradient(state["opt"])
-            stats = stat_changes(state["bn"], key0)
-    delta = jax.jit(reference.delta_norms, out_shardings=rep)(
-        state["params"], params0(key0))
-    got = jax.device_get({"losses": losses, "grad": grad, "delta": delta,
-                          "stats": stats})
-    del state
-    return {"losses": [{k: float(v) for k, v in m.items()}
-                       for m in got["losses"]],
-            "grad": {k: float(v) for k, v in got["grad"].items()},
-            "delta": {k: float(v) for k, v in got["delta"].items()},
-            "gvec": gvec, "stats": got["stats"]}
+def reference_readings(cell: manifest.Cell, inp: Inputs, seed: int,
+                       batches: List, **variant) -> dict:
+    """The family's plain reference through the same CHECK_STEPS steps: the
+    same weights (drawn in float32), batches and keys. `variant`: the
+    keyword arguments of one of the family's `variants` (the control in
+    lower precision, a planted fault)."""
+    return inp.family.reference_readings(
+        cell.config, inp.mesh, lambda key: inp.draw(key, "float32"),
+        weights.seed_key(seed, weights.WEIGHTS),
+        weights.seed_key(seed, weights.STEP_KEYS), batches, CHECK_STEPS,
+        **variant)
 
 
-def compare(read: dict, ref: dict, mesh) -> Dict[str, float]:
-    """The training numbers of `read` (the program's readings, or those of
-    the reference put in its place) against the reference's `ref`. The
-    first gradients meet on the device here, leaf by leaf."""
-    import jax
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    norm = lambda x: float(np.sqrt(np.sum(np.square(x, dtype=np.float64))))
-    read = {**read, "stat_diff": {k: norm(read["stats"][k] - v)
-                                  for k, v in ref["stats"].items()}}
-    if read.get("gvec") is not None:
-        diff = jax.device_get(jax.jit(
-            reference.diff_norms, out_shardings=NamedSharding(mesh, P()))(
-                read["gvec"], ref["gvec"]))
-        read["grad_diff"] = {k: float(v) for k, v in diff.items()}
-    return check.training_numbers(
-        read, {**ref, "stat": {k: norm(v) for k, v in ref["stats"].items()}})
-
-
-def check_batches(cell: manifest.Cell, prog_cfg, img_sharding, seed: int,
+def check_batches(cell: manifest.Cell, inp: Inputs, seed: int,
                   delivered: List[np.ndarray]):
     """The reference's batches, made by the benchmark alone, and the fed
     cell's `feed_gap`. Resident: drawn again from the seed. Records: each
@@ -400,13 +328,11 @@ def check_batches(cell: manifest.Cell, prog_cfg, img_sharding, seed: int,
     the benchmark wrote and the delivered rows are held against it."""
     import jax
 
-    mix, m = cell.traffic, prog_cfg.model
-    shape = (prog_cfg.batch_size, m.output_size, m.output_size, m.c_dim)
-    if mix["feed"] == "resident":
-        return traffic.resident_batches(
-            weights.seed_key(seed, 1), int(mix["resident_batches"]),
-            shape, img_sharding)[:CHECK_STEPS], {}
-    records = traffic.record_images(mix["records"], m.output_size, m.c_dim)
+    if cell.traffic["feed"] == "resident":
+        return resident_batches(cell, inp, seed)[:CHECK_STEPS], {}
+    mix = cell.traffic
+    _, size, _, channels = inp.batch_shape
+    records = traffic.record_images(mix["records"], size, channels)
     gap, out = 0.0, []
     for rows in delivered:
         ids = traffic.record_ids(rows)
@@ -415,13 +341,13 @@ def check_batches(cell: manifest.Cell, prog_cfg, img_sharding, seed: int,
             ids = np.clip(ids, 0, len(records) - 1)
         want = traffic.normalize(records[ids])
         gap = max(gap, float(np.max(np.abs(rows - want))))
-        out.append(jax.device_put(want, img_sharding))
+        out.append(jax.device_put(want, inp.batch_sharding))
     return out, {"feed_gap": gap}
 
 
 # --- one run -------------------------------------------------------------------
 
-def run(cell: manifest.Cell, *, root: str, seed: int, seconds: float,
+def run(cell: manifest.Cell, *, seed: int, seconds: float,
         trace: bool, t_start: float, devices, cache_root: str,
         device_metrics: bool = True) -> dict:
     """One run of a training cell; returns the result line as a dict.
@@ -433,7 +359,7 @@ def run(cell: manifest.Cell, *, root: str, seed: int, seconds: float,
     traffic.check_mix(cell.traffic)
     devices = list(devices)[:cell.chips]
     kind = devices[0].device_kind
-    peaks = manifest.peaks(root, kind) if device_metrics else None
+    peaks = manifest.peaks(cell.root, kind) if device_metrics else None
     compiles: List[float] = []
     in_window = False
 
@@ -443,15 +369,17 @@ def run(cell: manifest.Cell, *, root: str, seed: int, seconds: float,
 
     jax.monitoring.register_event_duration_secs_listener(on_event)
 
-    prog = build_program(cell, devices)
+    prog = build_program(cell, devices, wanted=set(cell.limits))
     state = initial_state(prog, seed)
     feed, close_feed = make_feed(cell, prog, seed, cache_root)
     fed = cell.traffic["feed"] == "records"
     trace_dir = None
     try:
-        state, base, prog_read, kept = first_steps(
-            prog, state, feed, seed, keep_batches=fed,
-            keep_gradient=bool(check.GRADIENT_NUMBERS & set(cell.limits)))
+        state, base, prog_read, kept = first_steps(prog, state, feed, seed,
+                                                   keep_batches=fed)
+        # a full collection now, so that none of the interpreter's (50-120 ms
+        # in this process) falls into the window by the luck of the count
+        gc.collect()
         if trace:
             seconds = min(seconds, TRACE_SECONDS)
             trace_dir = tempfile.mkdtemp(prefix="bench_trace_")
@@ -478,23 +406,22 @@ def run(cell: manifest.Cell, *, root: str, seed: int, seconds: float,
         shutil.rmtree(trace_dir, ignore_errors=True)
 
     # free the program's state before the reference takes the chip
-    mesh, shapes, cfg, img_sh = prog.mesh, prog.shapes, prog.cfg, prog.img_sharding
+    inp, batch = prog.inputs, prog.cfg.batch_size
     del state, kept, feed, prog
     gc.collect()
     t_ref = time.time()
-    batches, feed_numbers = check_batches(cell, cfg, img_sh, seed, delivered)
-    ref_read = reference_readings(cell, mesh, shapes, seed, batches)
-    numbers.update(compare(prog_read, ref_read, mesh))
+    batches, feed_numbers = check_batches(cell, inp, seed, delivered)
+    ref_read = reference_readings(cell, inp, seed, batches)
+    numbers.update(inp.family.numbers(prog_read, ref_read, inp.mesh))
     del ref_read
     numbers.update(feed_numbers)
     verdict = check.judge(numbers, cell.limits)
     reference_s = time.time() - t_ref
 
-    batch = cfg.batch_size
     ctx = {"reduced": reduced, "spans": facts["spans"],
            "window_s": facts["window_s"], "steps": facts["steps"],
            "global_batch": batch, "chips": cell.chips, "config": cell.config,
-           "traffic": cell.traffic, "peaks": peaks,
+           "family": inp.family, "traffic": cell.traffic, "peaks": peaks,
            "memory_peak_bytes": mem["memory_peak_bytes"]}
     metrics: Dict[str, dict] = {}
     if device_metrics and not trace:
@@ -509,7 +436,7 @@ def run(cell: manifest.Cell, *, root: str, seed: int, seconds: float,
         if reduced is None:
             raise RuntimeError("the traced window holds no device operation")
         for m in cell.per_layer:
-            value = manifest.layer_metric_reader(root, m["name"])(ctx)
+            value = manifest.layer_metric_reader(cell.root, m["name"])(ctx)
             if value is not None:
                 metrics[m["name"]] = {"value": float(value), "unit": m["unit"]}
     device = {"platform": devices[0].platform, "kind": kind,

@@ -1,29 +1,31 @@
 """Kernels: the flash-attention kernels' share of their roofline. The least
 time the chip could take for one step's attention forward and backward
-passes (benchmark/flops.py: the larger of operations over the bf16 peak and
-bytes over the HBM peak; at 4,096 tokens with heads 8 and 32 wide the
+passes (`kernel_costs(...)["flash_attn"]` of the configuration's family;
+for `gan` benchmark/flops.py: the larger of operations over the bf16 peak
+and bytes over the HBM peak; at 4,096 tokens with heads 8 and 32 wide the
 operations bound it, 20.9 ms against 2.5 ms a step at batch 256) over the
-summed device time of the step's Pallas custom calls. In sagan128
-(`use_pallas`, BN on XLA, no fused stages) every `tpu_custom_call` of the
-step is a flash kernel. Nothing to read in a configuration without
-attention or a trace without Pallas calls."""
+device time per step of the instructions that hold `flash_` in their name
+(`ops/pallas_attention.py` names its kernels `flash_fwd`, `flash_dq_dkv`),
+read on the first device, so against one chip's share of the batch. Nothing
+to read in a configuration whose family counts no flash kernel, or in a
+trace without such an instruction."""
 
-from benchmark import flops, tracing
+from benchmark import tracing
 
 
 def read(ctx):
-    r, model = ctx["reduced"], ctx["config"]["model"]
-    if r is None or not ctx["peaks"] or not model.get("attn_res") \
-            or not model.get("use_pallas") or r["kind_s"]["pallas"] <= 0:
+    r = ctx["reduced"]
+    if r is None or not ctx["peaks"]:
         return None
     found = tracing.step_module(r)
-    if found is None or not found[1]["count"]:
+    kernel_s = sum(s for name, s in r["ops"]
+                   if name.startswith("pallas:") and "flash_" in name)
+    if kernel_s <= 0 or found is None or not found[1]["count"]:
         return None
-    model = dict(model, attn_qk_div=ctx["config"]["attn_qk_div"],
-                 attn_v_div=ctx["config"]["attn_v_div"])
-    # one chip's share of the batch: kernel time is read on the first device
-    cost = flops.flash_step_cost(model, ctx["global_batch"] // ctx["chips"])
+    cost = ctx["family"].kernel_costs(
+        ctx["config"], ctx["global_batch"] // ctx["chips"]).get("flash_attn")
+    if cost is None:
+        return None
     least = max(cost["ops"] / ctx["peaks"]["bf16_flops_per_s"],
                 cost["bytes"] / ctx["peaks"]["hbm_bytes_per_s"])
-    kernel_s = r["kind_s"]["pallas"] / found[1]["count"]
-    return 100.0 * least / kernel_s
+    return 100.0 * least * found[1]["count"] / kernel_s

@@ -1,11 +1,12 @@
 """`BENCHMARK.json` and the by-name lookup of everything a cell needs.
 
-Nothing here knows a cell, a configuration, a traffic mix or a metric by
-name: a later PR adds `benchmark/configs/<config>.json`,
-`benchmark/traffic/<mix>.json`, `benchmark/limits/<cell>.json`,
-`benchmark/layer_metrics/<metric>.py` and entries in `BENCHMARK.json`, and
-edits no file that is there. `root` is the directory that holds
-`BENCHMARK.json` (the checkout; a temporary directory in the tests).
+Nothing here knows a cell, a configuration, a model family, a traffic mix
+or a metric by name: a later PR adds `benchmark/configs/<config>.json`,
+`benchmark/families/<family>.py`, `benchmark/traffic/<mix>.json`,
+`benchmark/limits/<cell>.json`, `benchmark/layer_metrics/<metric>.py` and
+entries in `BENCHMARK.json`, and edits no file that is there. `root` is the
+directory that holds `BENCHMARK.json` (the checkout; a temporary directory
+in the tests).
 """
 
 from __future__ import annotations
@@ -15,6 +16,7 @@ import importlib.util
 import json
 import os
 import sys
+import zlib
 from typing import Any, Callable, Dict, List, Optional
 
 BENCH_DIR = "benchmark"
@@ -37,6 +39,7 @@ def _load_json(path: str) -> Any:
 @dataclasses.dataclass(frozen=True)
 class Cell:
     """One entry of `workloads`, with its files resolved and loaded."""
+    root: str
     name: str
     chips: int
     config_name: str
@@ -75,7 +78,7 @@ def cell(root: str, name: str, bench: Optional[dict] = None) -> Cell:
     layer = [m for m in bench["per_layer"]
              if _reported_in(m, name) and m["moves"] in moved]
     return Cell(
-        name=name, chips=int(w["chips"]), config_name=w["config"],
+        root=root, name=name, chips=int(w["chips"]), config_name=w["config"],
         config=_load_json(os.path.join(root, c["file"])),
         traffic_name=w["traffic"],
         traffic=_load_json(os.path.join(root, BENCH_DIR, "traffic",
@@ -114,6 +117,34 @@ def driver(root: str, kind: str):
         raise ManifestError(
             f"traffic kind {kind!r} has no driver module yet ({path})")
     return _load_module(path, "bench_driver_" + kind)
+
+
+FAMILY_API = ("step_ops", "kernel_costs", "batch_shape", "draw_batch",
+              "drawn", "draw_leaf", "initial_state", "program_readings",
+              "reference_readings", "numbers", "variants")
+
+
+def family(root: str, config: Dict[str, Any]):
+    """The module of the configuration's model family,
+    `families/<family>.py`, named by the configuration file's `family` key:
+    the operation counts, the draw of inputs and weights, the readings, the
+    plain reference and its variants (benchmark/README.md lists the
+    functions). The path decides the module, so a temporary root loads its
+    own copy; the same path is loaded once."""
+    name = config.get("family")
+    if not isinstance(name, str) or not name:
+        raise ManifestError(
+            f"configuration {config.get('name')!r} names no `family`")
+    path = os.path.join(root, BENCH_DIR, "families", name + ".py")
+    if not os.path.isfile(path):
+        raise ManifestError(
+            f"model family {name!r} has no module yet ({path})")
+    mod_name = f"bench_family_{name}_{zlib.crc32(path.encode()):08x}"
+    mod = sys.modules.get(mod_name) or _load_module(path, mod_name)
+    missing = [f for f in FAMILY_API if not callable(getattr(mod, f, None))]
+    if missing:
+        raise ManifestError(f"model family {name!r} lacks {missing}")
+    return mod
 
 
 def peaks(root: str, device_kind: str) -> Dict[str, float]:

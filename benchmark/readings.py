@@ -1,11 +1,14 @@
 """The readings a cell's limits are set from (not part of a benchmark run).
 
-    python3 benchmark/readings.py --workload <cell> --seeds 12 --variants reference_fp8:3,half_batch:1 --out <file.json>
+    python3 benchmark/readings.py --workload <cell> --seeds 12 [--variants reference_fp8:3,half_batch:1] [--out <file.json>]
     python3 benchmark/readings.py --rejudge <file.json>
 
 In one process, at the cell's own size: the program as the configuration
 states it against the plain reference on `--seeds` seeds (the lower
-readings), and each of the `--variants` on the first `:n` of them:
+readings), and each of the `--variants` on the first `:n` of them. The
+variants are the model family's (`variants` of `families/<family>.py`, each
+a set of keyword arguments of its `reference_readings` and whether it has
+to come out correct); for the `gan` family:
 - the control: the reference put in the program's place with the operands
   of every matmul and convolution rounded to fp8 (`reference_fp8`);
 - a witness: the reference with bfloat16 operands (`reference_bf16`), which
@@ -35,8 +38,7 @@ import time
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-MUST_PASS = ("stated", "reference_bf16")
-MUST_FAIL = ("reference_fp8", "half_batch", "no_exchange")
+STATED = "stated"    # the program as the configuration states it
 
 
 def memory_facts(train, prog, state, images, key, devices) -> dict:
@@ -63,7 +65,7 @@ def program_phase(train, cell, devices, seeds, cache_root):
     import jax
     import numpy as np
 
-    prog = train.build_program(cell, devices)
+    prog = train.build_program(cell, devices)     # reads every reading
     fed = cell.traffic["feed"] == "records"
     out, facts = {}, {}
     for seed in seeds:
@@ -85,16 +87,18 @@ def program_phase(train, cell, devices, seeds, cache_root):
             close()
         out[seed] = (read, [np.asarray(b) for b in kept], extra)
         del state, kept, feed
-    shared = (prog.mesh, prog.shapes, prog.cfg, prog.img_sharding)
+    inputs = prog.inputs
     del prog
     gc.collect()
-    return out, shared, facts
+    return out, inputs, facts
 
 
-def judge_rows(rows: dict, limits: dict, check) -> dict:
+def judge_rows(rows: dict, limits: dict, check, must_pass=()) -> dict:
     """Every variant of every seed through `check.judge`; the summary with
     each number's lower reading (largest of the sound runs) and each
-    variant's smallest, and whether all came out as they have to."""
+    variant's smallest, and whether all came out as they have to: the
+    sound runs and the variants named in `must_pass` (a witness) correct,
+    every other variant (the control, the faults) not."""
     verdicts, as_due = {}, True
     for seed, row in rows.items():
         for variant, numbers in row.items():
@@ -102,20 +106,27 @@ def judge_rows(rows: dict, limits: dict, check) -> dict:
                 continue
             ok = check.judge(numbers, limits)["correct"]
             verdicts.setdefault(variant, {})[str(seed)] = ok
-            if variant in MUST_PASS:
-                as_due = as_due and ok
-            elif variant in MUST_FAIL:
-                as_due = as_due and not ok
-    stated = [r["stated"] for r in rows.values()]
+            as_due = as_due and ok == (variant == STATED
+                                       or variant in must_pass)
+    stated = [r[STATED] for r in rows.values()]
     summary = {"limits": limits,
                "lower": {n: max(r[n] for r in stated) for n in stated[0]}}
     for variant in verdicts:
         got = [r[variant] for r in rows.values() if variant in r]
-        if variant != "stated":
+        if variant != STATED:
             summary[variant] = {n: min(g[n] for g in got) for n in got[0]}
     summary["correct"] = verdicts
     summary["all_as_due"] = as_due
     return summary
+
+
+def cell_variants(cell) -> dict:
+    from benchmark import manifest
+
+    mix = cell.traffic
+    return manifest.family(cell.root, cell.config).variants(
+        cell.config, int(mix["per_chip_batch"]) * int(mix["chips"]),
+        cell.chips)
 
 
 def rejudge(path: str) -> int:
@@ -123,14 +134,24 @@ def rejudge(path: str) -> int:
 
     with open(path) as f:
         saved = json.load(f)
-    limits = manifest.cell(ROOT, saved["workload"]).limits
-    summary = judge_rows(saved["rows"], limits, check)
+    cell = manifest.cell(ROOT, saved["workload"])
+    summary = judge_rows(saved["rows"], cell.limits, check,
+                         must_pass_of(cell_variants(cell)))
     print(json.dumps({"workload": saved["workload"], **summary}, indent=1))
     return 0 if summary["all_as_due"] else 1
 
 
+def must_pass_of(variants: dict) -> tuple:
+    return tuple(n for n, v in variants.items() if v["must_pass"])
+
+
 def strip(read: dict) -> dict:
-    return {k: v for k, v in read.items() if k not in ("gvec", "stats")}
+    """The readings a file can hold: those made of numbers, not of leaves."""
+    import jax
+    import numpy as np
+
+    return {k: v for k, v in read.items()
+            if all(np.ndim(x) == 0 for x in jax.tree.leaves(v))}
 
 
 def main(argv=None) -> int:
@@ -138,15 +159,15 @@ def main(argv=None) -> int:
     p.add_argument("--workload")
     p.add_argument("--seeds", type=int, default=12)
     p.add_argument("--first-seed", type=int, default=2_500_000_001)
-    p.add_argument("--variants", default="reference_fp8:3,reference_bf16:3,"
-                   "half_batch:3,no_exchange:3")
+    p.add_argument("--variants", default="3", help="name:n,name:n ... or "
+                   "one n for every variant of the cell's family")
     p.add_argument("--out")
     p.add_argument("--rejudge", metavar="FILE")
     args = p.parse_args(argv)
     if args.rejudge:
         return rejudge(args.rejudge)
-    if not (args.workload and args.out):
-        p.error("--workload and --out are required")
+    if not args.workload:
+        p.error("--workload is required")
 
     import jax
 
@@ -160,11 +181,14 @@ def main(argv=None) -> int:
     manifest.peaks(ROOT, devices[0].device_kind)   # a chip, or an error
     devices = devices[:cell.chips]
     seeds = [args.first_seed + 7919 * i for i in range(args.seeds)]
-    wanted = {name: seeds[:int(n)] for name, n in
-              (v.split(":") for v in args.variants.split(","))}
+    variants = cell_variants(cell)
+    counts = (dict.fromkeys(variants, args.variants)
+              if args.variants.isdigit()
+              else dict(v.split(":") for v in args.variants.split(",")))
+    wanted = {name: seeds[:int(n)] for name, n in counts.items()}
     t0 = time.time()
 
-    stated, (mesh, shapes, cfg, img_sh), memory = program_phase(
+    stated, inputs, memory = program_phase(
         train, cell, devices, seeds, cache_root)
     t_prog = time.time() - t0
 
@@ -172,29 +196,23 @@ def main(argv=None) -> int:
     ref_s = []
     for seed in seeds:
         read, delivered, extra = stated.pop(seed)
-        batches, feed_numbers = train.check_batches(cell, cfg, img_sh, seed,
+        batches, feed_numbers = train.check_batches(cell, inputs, seed,
                                                     delivered)
         feed_numbers.update(extra)
         t = time.time()
-        ref = train.reference_readings(cell, mesh, shapes, seed, batches)
+        ref = train.reference_readings(cell, inputs, seed, batches)
         ref_s.append(time.time() - t)
-        rows[seed] = {"stated": {**train.compare(read, ref, mesh),
-                                 **feed_numbers},
+        numbers = inputs.family.numbers
+        rows[seed] = {STATED: {**numbers(read, ref, inputs.mesh),
+                               **feed_numbers},
                       "raw": {"program": strip(read),
                               "reference": strip(ref)}}
-        b = cfg.batch_size
-        variants = {
-            "reference_fp8": dict(operand="fp8"),
-            "reference_bf16": dict(operand="bfloat16"),
-            "half_batch": dict(rows=slice(0, b // 2))}
-        if cell.chips > 1:
-            variants["no_exchange"] = dict(rows=slice(0, b // cell.chips))
-        for name, kw in variants.items():
+        for name, variant in variants.items():
             if seed not in wanted.get(name, ()):
                 continue
-            got = train.reference_readings(cell, mesh, shapes, seed,
-                                           batches, **kw)
-            rows[seed][name] = {**train.compare(got, ref, mesh),
+            got = train.reference_readings(cell, inputs, seed, batches,
+                                           **variant["kwargs"])
+            rows[seed][name] = {**numbers(got, ref, inputs.mesh),
                                 **feed_numbers}
             rows[seed]["raw"][name] = strip(got)
             del got
@@ -204,16 +222,17 @@ def main(argv=None) -> int:
               file=sys.stderr, flush=True)
     gc.collect()
 
-    summary = judge_rows(rows, cell.limits, check)
+    summary = judge_rows(rows, cell.limits, check, must_pass_of(variants))
     out = {"workload": args.workload, "device": devices[0].device_kind,
            "chips": cell.chips, "seeds": seeds,
            "variant_seeds": wanted,
            "program_phase_s": t_prog, "reference_s_per_seed": ref_s,
            "total_s": time.time() - t0, "memory": memory,
            "summary": summary, "rows": {str(k): v for k, v in rows.items()}}
-    os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
-    with open(args.out, "w") as f:
-        json.dump(out, f, indent=1)
+    if args.out:
+        os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
+        with open(args.out, "w") as f:
+            json.dump(out, f, indent=1)
     print(json.dumps(summary, indent=1))
     return 0 if summary["all_as_due"] else 1
 

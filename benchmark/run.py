@@ -70,7 +70,7 @@ def main(argv=None) -> int:
               f"finds {len(devices)}", file=sys.stderr)
         return 3
 
-    result = driver.run(cell, root=ROOT, seed=args.seed,
+    result = driver.run(cell, seed=args.seed,
                         seconds=args.seconds, trace=bool(args.trace),
                         t_start=T_START, devices=devices,
                         cache_root=cache_root)

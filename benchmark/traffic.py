@@ -5,8 +5,9 @@ A training mix states `feed` (`resident` | `records`), `per_chip_batch`,
 for a resident feed `resident_batches`, and for records their `count`,
 `shards`, `dtype` and `seed`; none of them has a default in code. From those and the run's `--seed` this module makes
 
-- resident batches: `resident_batches` float32 batches in the tanh range,
-  drawn on the device in one jitted call, every row different;
+- resident batches: `resident_batches` batches drawn on the device in one
+  jitted call, every row different, each by the model family's draw
+  (float32 images in the tanh range for `gan`);
 - records: a uint8 TFRecord data set in the layout and with the manifest
   that the program's `data.prepare` writes (one `tf.train.Example` per
   image with the bytes feature `image_raw`; `dataset.json` beside the
@@ -162,19 +163,25 @@ def ensure_records(cache_root: str, spec: dict, image_size: int,
 
 # --- resident batches -------------------------------------------------------
 
-def resident_batches(key, count: int, shape: Tuple[int, ...], sharding
-                     ) -> List:
-    """`count` float32 batches in [-1, 1), drawn on the device in one jitted
-    call from the key, laid out with the step's batch sharding."""
+def uniform_images(key, shape: Tuple[int, ...]):
+    """One float32 batch in [-1, 1), the tanh range, every row different."""
     import jax
     import jax.numpy as jnp
 
-    def draw(k):
-        return [jax.random.uniform(jax.random.fold_in(k, i), shape,
-                                   jnp.float32, -1.0, 1.0)
-                for i in range(count)]
+    return jax.random.uniform(key, shape, jnp.float32, -1.0, 1.0)
 
-    return jax.jit(draw, out_shardings=[sharding] * count)(key)
+
+def resident_batches(key, count: int, shape: Tuple[int, ...], sharding,
+                     draw=uniform_images) -> List:
+    """`count` batches, each `draw(key_i, shape)` (the model family's draw
+    of one batch; images where none is given), drawn on the device in one
+    jitted call from the key and laid out with the step's batch sharding."""
+    import jax
+
+    def draw_all(k):
+        return [draw(jax.random.fold_in(k, i), shape) for i in range(count)]
+
+    return jax.jit(draw_all, out_shardings=[sharding] * count)(key)
 
 
 def check_mix(traffic: Dict) -> None:

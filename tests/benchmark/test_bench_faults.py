@@ -24,23 +24,23 @@ def root(tmp_path_factory):
 
 def test_control_in_lower_precision_is_not_correct(root):
     """The tiny configuration states float32, so its control is the
-    reference with bfloat16 operands in the program's place."""
+    reference with bfloat16 operands in the program's place: the family's
+    `reference_bf16` variant, which at the shipped bfloat16 is the witness."""
     import jax
-
-    from benchmark import traffic, weights
 
     cell = manifest.cell(root, "tiny_dcgan.resident")
     train = manifest.driver(root, "train")
-    prog = train.build_program(cell, jax.devices())
+    inp = train.build_program(cell, jax.devices()).inputs
     seed = 2 ** 31 + 77
-    batches = traffic.resident_batches(
-        weights.seed_key(seed, 1), 4, (8, 16, 16, 3), prog.img_sharding)[:3]
-    args = (cell, prog.mesh, prog.shapes, seed, batches)
-    ref = train.reference_readings(*args)
-    same = check.judge(train.compare(ref, ref, prog.mesh), TIGHT)
-    low = check.judge(train.compare(
-        train.reference_readings(*args, operand="bfloat16"), ref, prog.mesh),
-        TIGHT)
+    assert inp.batch_shape == (8, 16, 16, 3)
+    batches = train.resident_batches(cell, inp, seed)[:3]
+    bf16 = inp.family.variants(cell.config, 8, 1)["reference_bf16"]["kwargs"]
+    ref = train.reference_readings(cell, inp, seed, batches)
+    numbers = inp.family.numbers
+    same = check.judge(numbers(ref, ref, inp.mesh), TIGHT)
+    low = check.judge(numbers(
+        train.reference_readings(cell, inp, seed, batches, **bf16), ref,
+        inp.mesh), TIGHT)
     assert same["correct"] and not low["correct"]
     over = {n: c["value"] / c["limit"] for n, c in low["compared"].items()}
     # it fails by a margin, not by a hair; a leaf's vector error is never
@@ -64,7 +64,7 @@ def _run(root, name, tmp, monkeypatch, breaker):
     monkeypatch.setattr(parallel, "make_parallel_train", broken)
     cell = manifest.cell(root, name)
     return manifest.driver(root, "train").run(
-        cell, root=root, seed=2 ** 31 + 5, seconds=0.2, trace=False,
+        cell, seed=2 ** 31 + 5, seconds=0.2, trace=False,
         t_start=time.time(), devices=jax.devices(),
         cache_root=os.path.join(str(tmp), "cache"), device_metrics=False)
 

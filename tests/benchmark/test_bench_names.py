@@ -1,7 +1,9 @@
 """The per-layer readers that read the program's own names and spans
 (ISSUE 24): `flash_fwd_ms`, `flash_bwd_ms`, `flash_fwd_calls` from the
 kernel names in the trace reduction, `feed_wait_share` and
-`feed_produce_ms` from the program's span store."""
+`feed_produce_ms` from the program's span store; and what the reduction
+keeps of the program's scopes and host spans (ISSUE 26): `scope_s` from the
+`op_name` of each operation, idle gaps put down to `feed/*` and `train/*`."""
 
 import json
 import os
@@ -48,11 +50,17 @@ def recorded_trace(named: bool) -> tracing.Trace:
     return tracing.Trace.from_json(obj)
 
 
+def shipped_config(name):
+    with open(os.path.join(REPO, "benchmark", "configs", name + ".json")) as f:
+        return json.load(f)
+
+
 def ctx_of(reduced, **over):
     ctx = {"reduced": reduced, "spans": {"next": [], "step": [],
                                          "readback": []},
            "window_s": 0.43, "steps": 1, "global_batch": 256, "chips": 1,
-           "config": {}, "traffic": {"feed": "resident"}, "peaks": None,
+           "config": {}, "family": manifest.family(REPO, {"family": "gan"}),
+           "traffic": {"feed": "resident"}, "peaks": None,
            "memory_peak_bytes": 0}
     ctx.update(over)
     return ctx
@@ -120,6 +128,180 @@ def test_flash_time_is_per_execution_of_the_step_program():
     assert reader("flash_fwd_ms")(ctx) == pytest.approx(200e-6)
     assert reader("flash_bwd_ms")(ctx) == pytest.approx(400e-6)
     assert reader("flash_fwd_calls")(ctx) == 2.0
+
+
+# --- scopes: the trace recorded from the PR 25 step -------------------------------
+
+@pytest.fixture(scope="module")
+def scoped():
+    """The first whole step (260 ms) of a sagan128 batch-256 window on one
+    v5e chip, recorded by PR 26 from the PR 25 step with each operation's
+    scope (`tracing.load_xplane`, then `Trace.to_json`)."""
+    with open(os.path.join(DATA, "trace_sagan128_1chip_scopes.json")) as f:
+        return tracing.reduce(tracing.Trace.from_json(json.load(f)))
+
+
+def test_scope_seconds_on_the_recorded_trace(scoped):
+    r = scoped
+    name, step = tracing.step_module(r)
+    assert name == "jit_train_step" and step["count"] == 1
+    assert step["total_s"] == pytest.approx(0.258197, abs=1e-6)
+    # the two halves of the step hold all of it but the weight average,
+    # the key's split and the copies that carry no op_name (PERF.md 3)
+    halves = r["scope_s"]["d_step"] + r["scope_s"]["g_step"]
+    assert 0.9 * step["total_s"] <= halves <= step["total_s"]
+    assert r["scope_s"]["d_step"] > r["scope_s"]["g_step"] > 0.1
+    # by path prefix: a scope holds what its children hold
+    assert r["scope_s"]["d_step"] >= r["scope_s"]["d_step/loss"] \
+        >= r["scope_s"]["d_step/loss/disc"] \
+        >= r["scope_s"]["d_step/loss/disc/attn"] > 0
+    # under `attn`, wherever it lies: the kernels and the projections,
+    # transposes and relayouts around them
+    ctx = ctx_of(r, window_s=r["window_s"], config=shipped_config("sagan128"))
+    fwd, bwd = reader("flash_fwd_ms")(ctx), reader("flash_bwd_ms")(ctx)
+    assert fwd == pytest.approx(83.006, abs=0.01)
+    assert bwd == pytest.approx(86.27, abs=0.01)
+    attn = tracing.under(r, "attn")
+    assert attn == sum(r["scope_s"][p] for p in (
+        "d_step/loss/disc/attn", "d_step/loss/gen/attn",
+        "g_step/loss/disc/attn", "g_step/loss/gen/attn"))
+    assert 1e-3 * (fwd + bwd) <= attn <= halves
+    # a scope is no kernel name: the kernels' `pallas_call` scope also holds
+    # 2.8 ms of XLA `reduce` instructions that kept the call's op_name, so
+    # a kernel's time is read by instruction name and a scope's by scope
+    calls = sum(s for p, s in r["scope_s"].items()
+                if p.endswith(("/flash_fwd/pallas_call",
+                               "/flash_dq_dkv/pallas_call")))
+    assert 1e-3 * (fwd + bwd) < calls < 1.03e-3 * (fwd + bwd)
+    assert calls == pytest.approx(tracing.under(r, "pallas_call"))
+    assert tracing.under(r, "no_such_scope") == 0.0
+
+
+def test_flash_roofline_reads_its_kernels_by_name(scoped):
+    """Equal to the Pallas class's time where every custom call is a flash
+    kernel (12.364 in PERF.md section 5), and blind to another kernel's."""
+    peaks = manifest.peaks(REPO, "TPU v5 lite")
+    ctx = ctx_of(scoped, window_s=scoped["window_s"], peaks=peaks,
+                 config=shipped_config("sagan128"))
+    got = reader("flash_attn_roofline")(ctx)
+    cost = ctx["family"].kernel_costs(ctx["config"], 256)["flash_attn"]
+    least = cost["ops"] / peaks["bf16_flops_per_s"]
+    assert got == pytest.approx(100 * least / scoped["kind_s"]["pallas"],
+                                rel=1e-9)
+    assert got == pytest.approx(12.364, abs=2e-3)
+    # four chips: one chip's kernels against one chip's share of the batch
+    assert reader("flash_attn_roofline")(
+        dict(ctx, chips=4, global_batch=1024)) == pytest.approx(got)
+    other = dict(scoped, ops=scoped["ops"] + [("pallas:bn_apply.3", 0.05)])
+    assert reader("flash_attn_roofline")(dict(ctx, reduced=other)) == got
+    # nothing to read without the peaks, in a family that counts no flash
+    # kernel for the configuration, or with no kernel of that name
+    assert reader("flash_attn_roofline")(dict(ctx, peaks=None)) is None
+    assert reader("flash_attn_roofline")(
+        dict(ctx, config=shipped_config("dcgan128"))) is None
+    unnamed = tracing.reduce(recorded_trace(named=False))
+    assert reader("flash_attn_roofline")(dict(ctx, reduced=unnamed)) is None
+
+
+@pytest.mark.parametrize("op_name, scope", [
+    ("jit(train_step)/d_step/loss/jvp(disc)/conv3/conv_general_dilated:",
+     "d_step/loss/disc/conv3/conv_general_dilated"),
+    ("jit(train_step)/d_step/loss/transpose(jvp(disc))/attn/dot_general:",
+     "d_step/loss/disc/attn/dot_general"),
+    # the backward pass repeats the path it was traced under
+    ("jit(train_step)/d_step/loss/transpose(d_step)/loss/jvp(disc)/attn/"
+     "flash_dq_dkv/pallas_call:",
+     "d_step/loss/disc/attn/flash_dq_dkv/pallas_call"),
+    ("jit(train_step)/g_step/loss/jvp(gen)/attn/shard_map/flash_fwd/"
+     "pallas_call", "g_step/loss/gen/attn/shard_map/flash_fwd/pallas_call"),
+    ("jit(train_step)/jit(_uniform)/while", "while"),
+    ("jit(train_step)/ema/mul:", "ema/mul"),
+    ("state['params']['gen']['deconv1']['w']", ""),     # a parameter's name
+    ("", ""),
+])
+def test_scope_of_an_op_name(op_name, scope):
+    assert tracing.scope_of(op_name) == scope
+
+
+def _varint(n):
+    out = bytearray()
+    while True:
+        out.append((n & 0x7F) | (0x80 if n > 0x7F else 0))
+        n >>= 7
+        if not n:
+            return bytes(out)
+
+
+def _msg(*fields):
+    """A protobuf message of (number, int | bytes | str) fields."""
+    out = b""
+    for num, val in fields:
+        if isinstance(val, int):
+            out += _varint(num << 3) + _varint(val)
+        else:
+            val = val.encode() if isinstance(val, str) else val
+            out += _varint(num << 3 | 2) + _varint(len(val)) + val
+    return out
+
+
+def test_op_names_from_the_metadata_of_a_hand_made_xplane(tmp_path):
+    """The wire reader against an XSpace written field by field: the
+    `tf_op` stat of an event's metadata, as a string and as a reference to
+    the plane's stat names; events without one and host planes are left."""
+    stat_meta = [_msg((1, k), (2, _msg((1, k), (2, n))))
+                 for k, n in ((7, "hlo_category"), (9, "tf_op"),
+                              (300, "jit(f)/g_step/adam/mul:"))]
+
+    def event_meta(key, name, *stats):
+        return _msg((1, key), (2, _msg((1, key), (2, name),
+                                       *[(5, st) for st in stats])))
+
+    plane = _msg(
+        (1, 0), (2, "/device:TPU:0"),
+        (4, event_meta(1, "%fusion.1 = f32[8] fusion(f32[8] %p), kind=kLoop",
+                       _msg((1, 7), (5, "loop fusion")),
+                       _msg((1, 9), (5, "jit(f)/d_step/loss/jvp(disc)/mul:")))),
+        (4, event_meta(2, "%fusion.2 = f32[8] fusion(f32[8] %q), kind=kLoop",
+                       _msg((1, 9), (7, 300)))),
+        (4, event_meta(4000, "%copy.3 = f32[8] copy(f32[8] %r)",
+                       _msg((1, 7), (5, "copy")))),
+        *[(5, m) for m in stat_meta])
+    host = _msg((2, "/host:CPU"), (4, event_meta(1, "bench_step")))
+    path = tmp_path / "hand.xplane.pb"
+    path.write_bytes(_msg((1, plane), (1, host)))
+    got = tracing.op_names(str(path))
+    assert got == {"/device:TPU:0": {
+        "%fusion.1 = f32[8] fusion(f32[8] %p), kind=kLoop":
+            "jit(f)/d_step/loss/jvp(disc)/mul:",
+        "%fusion.2 = f32[8] fusion(f32[8] %q), kind=kLoop":
+            "jit(f)/g_step/adam/mul:"}}
+    assert [tracing.scope_of(v) for v in got["/device:TPU:0"].values()] == [
+        "d_step/loss/disc/mul", "g_step/adam/mul"]
+
+
+def test_idle_gaps_are_put_down_to_the_programs_spans():
+    """Host events named `feed/*` and `train/*` lie beside the harness's
+    own: of two spans that cover a gap alike the inner one names it."""
+    us = 1e3
+    dev = tracing.DeviceTrace(
+        modules=[("jit_train_step", "module", 0.0, 100 * us)],
+        ops=[("fusion.1", "convolution", 0.0, 40 * us, "d_step/loss/mul"),
+             ("fusion.2", "other", 60 * us, 20 * us, ""),
+             ("fusion.3", "other", 90 * us, 10 * us)], async_ops=[])
+    trace = tracing.Trace({"/device:TPU:0": dev}, [
+        ("bench_window", "host", 0.0, 100 * us),
+        ("bench_next", "host", 38 * us, 24 * us),
+        ("feed/wait", "host", 39 * us, 22 * us),
+        ("train/consume", "host", 79 * us, 12 * us)])
+    r = tracing.reduce(trace)
+    assert dict(r["idle_by_host"]) == pytest.approx(
+        {"in_feed/wait": 20e-6, "in_train/consume": 10e-6})
+    assert r["scope_s"] == pytest.approx(
+        {"d_step": 40e-6, "d_step/loss": 40e-6, "d_step/loss/mul": 40e-6})
+    # a trace without scopes (the PR 23 fixture) has none to give
+    assert tracing.reduce(recorded_trace(named=True))["scope_s"] == {}
+    back = tracing.Trace.from_json(json.loads(json.dumps(trace.to_json())))
+    assert back == trace
 
 
 # --- spans of the feed ------------------------------------------------------------
@@ -193,7 +375,7 @@ def test_feed_readers_after_a_fed_rehearsal(tmp_path):
     cell = manifest.cell(root, "tiny_dcgan.fed")
     t0 = time.perf_counter()
     line = manifest.driver(root, "train").run(
-        cell, root=root, seed=3_000_000_021, seconds=0.3, trace=False,
+        cell, seed=3_000_000_021, seconds=0.3, trace=False,
         t_start=time.time(), devices=jax.devices(),
         cache_root=os.path.join(str(tmp_path), "cache"),
         device_metrics=False)
@@ -225,8 +407,9 @@ def test_new_entries_name_their_cells_and_layers():
         == by["flash_attn_roofline"]["layer"] == "kernels"
     four = [n for n, w in cells.items() if w["chips"] == 4]
     assert by["collective_exposed_share"]["workloads"] == four
-    assert by["flash_attn_roofline"]["workloads"] == \
-        ["sagan128.resident-b256"]       # the accepted entry is as it was
+    # since PR 26 the roofline is read by kernel name, on the first device
+    # against one chip's share of the batch: every sagan128 cell reports it
+    assert by["flash_attn_roofline"]["workloads"] == sagan
     # the fed cell was measured and left out (PERF.md section 7 row 1b): its
     # readers, its mix and its limits wait as files, with no entry
     fed = {"loader_wait_share", *FEED}

@@ -26,7 +26,7 @@ def rehearse(root, name, tmp, seed=3_000_000_019, trace=False):
 
     cell = manifest.cell(root, name)
     return manifest.driver(root, cell.traffic["kind"]).run(
-        cell, root=root, seed=seed, seconds=0.3, trace=trace,
+        cell, seed=seed, seconds=0.3, trace=trace,
         t_start=time.time(), devices=jax.devices(),
         cache_root=os.path.join(str(tmp), "cache"), device_metrics=False)
 
@@ -34,6 +34,7 @@ def rehearse(root, name, tmp, seed=3_000_000_019, trace=False):
 @pytest.mark.parametrize("name, extra", [
     ("tiny_sagan.resident", ()),         # attention, spectral norm, hinge
     ("tiny_dcgan.fed", ("feed_gap",)),   # records through the native loader
+    ("tiny_dcgan.dp4", ("replica_gap",)),   # the readings over a sharded state
 ])
 def test_rehearsal_last_line(root, tmp_path, name, extra):
     line = json.loads(json.dumps(rehearse(root, name, tmp_path)))
@@ -41,7 +42,8 @@ def test_rehearsal_last_line(root, tmp_path, name, extra):
     assert {"correct", "attempted", "failed", "metrics", "device"} <= set(line)
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] >= 1
-    assert line["device"]["platform"] == "cpu" and line["device"]["count"] == 1
+    assert line["device"]["platform"] == "cpu"
+    assert line["device"]["count"] == manifest.cell(root, name).chips
     assert "memory_peak_bytes" in line["device"]
     # a CPU run reports no number under the name of a device metric
     assert line["metrics"] == {}

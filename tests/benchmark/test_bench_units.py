@@ -153,6 +153,31 @@ def test_attention_ops_and_bytes():
     assert cost["ops"] / 197e12 > 5 * cost["bytes"] / 819e9
 
 
+@pytest.mark.parametrize("config, batch, total", [
+    ("sagan128", 256, 8438050029568), ("dcgan128", 512, 8372064813056)])
+def test_step_ops_of_the_shipped_cells_through_their_family(config, batch,
+                                                            total):
+    """What `step_mfu` divides by the peak, to the last digit as it was
+    before the count was looked up by the configuration's family (PR 25)."""
+    with open(os.path.join(REPO, "benchmark", "configs", config + ".json")) as f:
+        conf = json.load(f)
+    fam = manifest.family(REPO, conf)
+    assert fam is manifest.family(REPO, {"family": "gan"})   # loaded once
+    assert fam.step_ops(conf, batch)["total"] == total
+    model = dict(conf["model"], attn_qk_div=conf["attn_qk_div"],
+                 attn_v_div=conf["attn_v_div"])
+    assert fam.step_ops(conf, batch) == flops.step_ops(model, batch)
+    costs = fam.kernel_costs(conf, batch)
+    if config == "sagan128":
+        assert costs == {"flash_attn": flops.flash_step_cost(model, batch)}
+        assert costs["flash_attn"]["ops"] == 4123168604160.0
+        # no flash kernel runs where attention is dense
+        dense = dict(conf, model=dict(conf["model"], use_pallas=False))
+        assert fam.kernel_costs(dense, batch) == {}
+    else:
+        assert costs == {}
+
+
 # --- the data set writer ----------------------------------------------------------
 
 def test_records_read_back_by_the_programs_reader(tmp_path):
@@ -239,7 +264,10 @@ def test_readings_judge_every_variant_with_the_cells_limits(control, as_due):
                   "raw": {}},
             "8": {"stated": {"stat_err": 0.02, "grad_gap": 0.03, "loss_gap": 1.0},
                   "raw": {}}}
-    got = readings.judge_rows(rows, limits, check)
+    gan = manifest.family(REPO, {"family": "gan"})
+    must_pass = readings.must_pass_of(gan.variants({}, 8, 4))
+    assert must_pass == ("reference_bf16",)
+    got = readings.judge_rows(rows, limits, check, must_pass)
     assert got["all_as_due"] is as_due
     assert got["lower"]["stat_err"] == 0.02 and got["lower"]["loss_gap"] == 9.0
     assert got["reference_fp8"]["stat_err"] == control
@@ -288,39 +316,63 @@ def test_benchmark_json_keeps_to_the_contract():
     assert len(json.dumps(bench)) < 64 * 1024
 
 
-def test_shipped_configs_are_the_presets_as_shipped():
-    """`reduced` is empty: applying a shipped configuration file to its
-    preset changes nothing but batch, mesh and backend."""
+def _departures(cfg, preset) -> set:
+    """The keys in which a cell's TrainConfig departs from its preset,
+    beside what the traffic mix sets (batch, mesh, backend)."""
     import dataclasses
 
+    out = {f.name for f in dataclasses.fields(cfg.model)
+           if getattr(cfg.model, f.name) != getattr(preset.model, f.name)}
+    lr = lambda c, net: getattr(c, net) or c.learning_rate
+    for f in dataclasses.fields(cfg):
+        if f.name in ("model", "batch_size", "mesh", "backend"):
+            continue
+        a, b = getattr(cfg, f.name), getattr(preset, f.name)
+        if f.name in ("d_learning_rate", "g_learning_rate"):
+            a, b = lr(cfg, f.name), lr(preset, f.name)
+        if a != b:
+            out.add(f.name)
+    return out
+
+
+@pytest.mark.parametrize("config", [c["name"] for c in
+                                    manifest.load(REPO)["configs"]])
+def test_shipped_configs_are_the_presets_as_shipped(config):
+    """A configuration file differs from its preset in exactly the keys its
+    `reduced` lists (none for the two shipped): applying it to the preset
+    changes nothing else but batch, mesh and backend."""
     from dcgan_tpu.presets import get_preset
 
+    bench = manifest.load(REPO)
+    entry = next(c for c in bench["configs"] if c["name"] == config)
     train = manifest.driver(REPO, "train")
-    for w in manifest.load(REPO)["workloads"]:
-        cell = manifest.cell(REPO, w["name"])
+    cells = [w["name"] for w in bench["workloads"] if w["config"] == config]
+    assert cells
+    for name in cells:
+        cell = manifest.cell(REPO, name)
+        assert cell.config["reduced"] == entry["reduced"]
         cfg = train.program_config(cell)
         preset = get_preset(cell.config["preset"])
-        assert cfg.model == preset.model
-        lr = lambda c, net: getattr(c, net) or c.learning_rate
-        for net in ("d_learning_rate", "g_learning_rate"):
-            assert lr(cfg, net) == lr(preset, net)
-        same = dataclasses.replace(
-            cfg, batch_size=preset.batch_size, mesh=preset.mesh,
-            backend=preset.backend, d_learning_rate=preset.d_learning_rate,
-            g_learning_rate=preset.g_learning_rate)
-        assert same == preset
+        assert _departures(cfg, preset) == set(entry["reduced"])
         assert cfg.batch_size == (cell.traffic["per_chip_batch"]
                                   * cell.traffic["chips"])
 
 
+def test_a_cut_configuration_departs_in_the_keys_it_lists(tmp_path):
+    """The rule on a file that IS cut: the tiny configurations of the tests
+    depart from their presets in the keys `tiny_config` changes."""
+    from dcgan_tpu.presets import get_preset
+
+    root = make_root(str(tmp_path))
+    cell = manifest.cell(root, "tiny_sagan.resident")
+    cfg = manifest.driver(root, "train").program_config(cell)
+    assert _departures(cfg, get_preset(cell.config["preset"])) == {
+        "output_size", "gf_dim", "df_dim", "attn_res", "compute_dtype"}
+
+
 def test_a_cell_a_mix_and_a_metric_are_added_as_new_files(tmp_path):
     root = make_root(str(tmp_path))
-    before = {}
-    for d, _, files in os.walk(os.path.join(REPO, "benchmark")):
-        for f in files:
-            if "__pycache__" not in d:
-                p = os.path.join(d, f)
-                before[os.path.relpath(p, REPO)] = open(p, "rb").read()
+    before = _shipped_files()
     # a later PR's per-layer metric: one new file and one new entry
     with open(os.path.join(root, "benchmark", "layer_metrics",
                            "steps_per_dispatch.train.py"), "w") as f:
@@ -344,6 +396,144 @@ def test_a_cell_a_mix_and_a_metric_are_added_as_new_files(tmp_path):
         assert open(os.path.join(root, rel), "rb").read() == content
     with pytest.raises(manifest.ManifestError, match="unknown workload"):
         manifest.cell(root, "no_such.cell", bench)
+
+
+TOY_FAMILY = '''"""A later PR's family, as one new file: the `gan` family's functions with
+an operation count and one leaf rule of its own."""
+import os
+
+from benchmark import manifest
+
+_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+_gan = manifest.family(_ROOT, {"family": "gan"})
+globals().update({name: getattr(_gan, name) for name in manifest.FAMILY_API})
+
+
+def step_ops(config, global_batch):
+    return {"total": 1000.0 * global_batch}
+
+
+def draw_leaf(path, shape, key):
+    if path == "params/disc/head/b":
+        import jax.numpy as jnp
+        return jnp.full(shape, 0.25, jnp.float32)
+    return _gan.draw_leaf(path, shape, key)
+'''
+
+
+def _shipped_files():
+    out = {}
+    for d, _, files in os.walk(os.path.join(REPO, "benchmark")):
+        for f in files:
+            if "__pycache__" not in d:
+                p = os.path.join(d, f)
+                with open(p, "rb") as fh:
+                    out[os.path.relpath(p, REPO)] = fh.read()
+    return out
+
+
+def test_a_family_is_added_as_new_files(tmp_path):
+    """A configuration of a new model family lands as `families/<family>.py`,
+    a configuration file that names it, a cell and its limits: the driver,
+    the readers and the check run it without an edit."""
+    import time
+
+    import jax
+    import numpy as np
+
+    from bench_testlib import TIGHT, tiny_config
+
+    root = make_root(str(tmp_path))
+    before = _shipped_files()
+    with open(os.path.join(root, "benchmark", "families", "toy.py"), "w") as f:
+        f.write(TOY_FAMILY)
+    conf = dict(tiny_config("dcgan128", 0), name="toy_dcgan", family="toy")
+    with open(os.path.join(root, "benchmark", "configs", "toy_dcgan.json"),
+              "w") as f:
+        json.dump(conf, f)
+    with open(os.path.join(root, "benchmark", "limits",
+                           "toy_dcgan.resident.json"), "w") as f:
+        json.dump(TIGHT, f)
+    bench = manifest.load(root)
+    bench["configs"].append({"name": "toy_dcgan", "source": "test",
+                             "file": "benchmark/configs/toy_dcgan.json",
+                             "reduced": [], "why": "test"})
+    bench["workloads"].append({"name": "toy_dcgan.resident",
+                               "config": "toy_dcgan",
+                               "traffic": "tiny-resident", "chips": 1,
+                               "why": "test"})
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(bench, f)
+
+    cell = manifest.cell(root, "toy_dcgan.resident")
+    toy = manifest.family(root, cell.config)
+    gan = manifest.family(root, {"family": "gan"})
+    assert toy.__file__.endswith("families/toy.py") and toy is not gan
+    assert toy.numbers is gan.numbers and toy.step_ops is not gan.step_ops
+    # the whole run on the CPU, through the one training driver
+    train = manifest.driver(root, "train")
+    line = train.run(cell, seed=3_000_000_023, seconds=0.3,
+                     trace=False, t_start=time.time(), devices=jax.devices(),
+                     cache_root=os.path.join(str(tmp_path), "cache"),
+                     device_metrics=False)
+    assert line["correct"] is True and line["failed"] == 0
+    assert set(line["check"]) == set(TIGHT)
+    # program and reference both started from the toy's own leaf rule ...
+    inp = train.build_program(cell, jax.devices()).inputs
+    assert inp.family is toy
+    drawn = inp.draw(jax.random.key(1))["params"]["disc"]["head"]
+    assert np.all(np.asarray(drawn["b"]) == 0.25)
+    assert np.std(np.asarray(drawn["w"])) == pytest.approx(0.02, rel=0.2)
+    # ... and the share of the peak is worked out from the toy's count
+    ctx = {"family": toy, "config": cell.config, "global_batch": 8, "chips": 1,
+           "steps": 5, "window_s": 2.0, "peaks": {"bf16_flops_per_s": 1e6}}
+    assert manifest.layer_metric_reader(root, "step_mfu")(ctx) == \
+        pytest.approx(100.0 * 8000.0 * 5 / 2.0 / 1e6)
+    assert manifest.layer_metric_reader(root, "step_mfu")(
+        dict(ctx, family=gan)) != manifest.layer_metric_reader(
+            root, "step_mfu")(ctx)
+    with pytest.raises(manifest.ManifestError, match="no module yet"):
+        manifest.family(root, dict(conf, family="no_such_family"))
+    with pytest.raises(manifest.ManifestError, match="names no `family`"):
+        manifest.family(root, {k: v for k, v in conf.items()
+                               if k != "family"})
+    with open(os.path.join(root, "benchmark", "families", "half.py"),
+              "w") as f:
+        f.write("def step_ops(config, global_batch):\n    return {}\n")
+    with pytest.raises(manifest.ManifestError, match="lacks"):
+        manifest.family(root, dict(conf, family="half"))
+    # every shipped file is there byte for byte
+    for rel, content in before.items():
+        with open(os.path.join(root, rel), "rb") as f:
+            assert f.read() == content
+    assert _shipped_files() == before
+
+
+def test_family_bound_code_is_reached_through_the_lookup_only():
+    """The driver, `readings.py` and the readers import neither the GAN's
+    reference nor its operation count, and name no leaf rule: what depends
+    on the model family goes through `manifest.family`."""
+    bench_dir = os.path.join(REPO, "benchmark")
+    files = [os.path.join(bench_dir, "drivers", "train.py"),
+             os.path.join(bench_dir, "readings.py")]
+    files += [os.path.join(bench_dir, "layer_metrics", f)
+              for f in sorted(os.listdir(os.path.join(bench_dir,
+                                                      "layer_metrics")))
+              if f.endswith(".py")]
+    assert len(files) > 10
+    banned = re.compile(
+        r"^\s*(from\s+benchmark(\.\w+)*\s+import\s+.*\b(reference|flops)\b"
+        r"|import\s+benchmark\.(reference|flops)\b"
+        r"|from\s+benchmark\.(reference|flops)\s+import\b"
+        r"|from\s+benchmark\.families\b|import\s+benchmark\.families\b)",
+        re.M)
+    for path in files:
+        with open(path) as f:
+            text = f.read()
+        assert not banned.search(text), path
+        assert "draw_leaf(" not in text and "_leaf(" not in text, path
+    with open(files[0]) as f:
+        assert "manifest.family(" in f.read()
 
 
 def test_a_serving_mix_is_data_the_harness_refuses_to_run(tmp_path):
@@ -379,6 +569,7 @@ def test_readers_return_nothing_where_there_is_nothing_to_read():
            "window_s": 1.0, "steps": 0, "global_batch": 8, "chips": 1,
            "config": {"model": dict(DCGAN128), "attn_qk_div": 8,
                       "attn_v_div": 2},
+           "family": manifest.family(REPO, {"family": "gan"}),
            "traffic": {"feed": "resident"}, "peaks": None,
            "memory_peak_bytes": 0}
     for m in manifest.load(REPO)["per_layer"]:
