@@ -215,6 +215,6 @@ def wrap_parallel_train(pt) -> None:
         return
     for name in _PROGRAM_FIELDS:
         fn = getattr(pt, name)
-        if isinstance(fn, _GuardedFn):
-            continue
+        if fn is None or isinstance(fn, _GuardedFn):
+            continue        # None: a program this family does not have
         object.__setattr__(pt, name, _GuardedFn(fn, f"pt.{name}"))

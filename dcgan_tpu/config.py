@@ -210,6 +210,95 @@ class ModelConfig:
         return int(round(math.log2(self.output_size / self.base_size)))
 
 
+#: the one-network token family's `arch` (models/mla_moe.py): a causal
+#: language model with latent attention, routed experts and a multi-token
+#: head, trained by a likelihood step (TrainConfig.loss == LM_LOSS)
+TOKEN_ARCH = "mla_moe"
+LM_LOSS = "lm"
+
+
+@dataclasses.dataclass(frozen=True)
+class TokenModelConfig:
+    """A causal token model: latent attention (MLA), one leading dense
+    SwiGLU layer, then layers of routed + shared experts, and multi-token
+    prediction modules (DeepSeek-V3, arXiv:2412.19437). Fields carry the
+    names of the public `config.json` of that family where it has one; the
+    defaults are a small model, the presets hold published ones.
+
+    What a chip holds of a layer is stated, never inferred: `experts_held`
+    experts starting at `first_expert` (the router still scores all
+    `n_routed_experts` and selects `num_experts_per_tok` of them wherever
+    they live), and `vocab_size` rows of the embedding and the head.
+    """
+
+    arch: str = TOKEN_ARCH
+    vocab_size: int = 256          # rows of embedding and head held here
+    hidden_size: int = 64
+    num_hidden_layers: int = 3     # trunk layers, the leading dense included
+    first_k_dense_replace: int = 1  # leading layers with a dense FFN
+    intermediate_size: int = 128   # the dense FFN's width
+    moe_intermediate_size: int = 32  # one expert's width
+    n_routed_experts: int = 8      # the router's outputs
+    n_shared_experts: int = 1
+    num_experts_per_tok: int = 2
+    norm_topk_prob: bool = True    # weights divided by their sum over ALL
+                                   # selected experts, held here or not
+    routed_scaling_factor: float = 2.5
+    num_attention_heads: int = 2
+    q_lora_rank: int = 48
+    kv_lora_rank: int = 32
+    qk_nope_head_dim: int = 16
+    qk_rope_head_dim: int = 8
+    v_head_dim: int = 16
+    rope_theta: float = 10000.0
+    rope_interleave: bool = True   # stored rotary dims are pairs (2i, 2i+1)
+    rms_norm_eps: float = 1e-6
+    num_nextn_predict_layers: int = 1  # multi-token modules (0 or 1)
+    experts_held: int = 8          # of n_routed_experts, on this chip
+    first_expert: int = 0          # index of the first one held
+    seq_len: int = 32              # tokens of one row of the batch
+    mtp_loss_weight: float = 0.3   # the multi-token loss's share
+    compute_dtype: str = "bfloat16"
+    param_dtype: str = "float32"
+    use_pallas: bool = True        # causal flash kernels; False = dense
+                                   # masked attention (short sequences)
+
+    # what the shared trainer and config code asks of any model: this
+    # family has no classes and no quantization policy
+    num_classes = property(lambda self: 0)
+    quant = property(lambda self: "")
+
+    def __post_init__(self):
+        if self.arch != TOKEN_ARCH:
+            raise ValueError(
+                f"TokenModelConfig.arch must be {TOKEN_ARCH!r}, got "
+                f"{self.arch!r}")
+        if not 0 < self.first_k_dense_replace <= self.num_hidden_layers:
+            raise ValueError(
+                "first_k_dense_replace must lie in 1..num_hidden_layers")
+        if self.num_nextn_predict_layers not in (0, 1):
+            raise ValueError("num_nextn_predict_layers must be 0 or 1")
+        if not (0 < self.experts_held <= self.n_routed_experts
+                and 0 <= self.first_expert
+                and self.first_expert + self.experts_held
+                <= self.n_routed_experts):
+            raise ValueError(
+                f"experts held [{self.first_expert}, {self.first_expert} + "
+                f"{self.experts_held}) must lie within the "
+                f"{self.n_routed_experts} routed experts")
+        if not 0 < self.num_experts_per_tok <= self.n_routed_experts:
+            raise ValueError("num_experts_per_tok out of range")
+        if self.qk_rope_head_dim % 2:
+            raise ValueError("qk_rope_head_dim must be even")
+        if self.seq_len < 3:
+            raise ValueError("seq_len must be >= 3 (the multi-token loss "
+                             "needs a target two ahead)")
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+
 @dataclasses.dataclass(frozen=True)
 class MeshConfig:
     """Device-mesh topology. Replaces ClusterSpec/Server/ps-role entirely
@@ -735,7 +824,51 @@ class TrainConfig:
                                    # a single leaf above the cap gets its
                                    # own bucket)
 
+    def _refuse_image_services(self):
+        """A one-network token family has no sampler, critic or image
+        pipeline: every option that needs one is refused here, by name, at
+        config time, instead of failing inside a trace."""
+        image_only = {
+            "sample_every_steps": "sample grids need a sampler program",
+            "activation_summary_steps": "activation summaries walk the "
+                                        "G/D stacks",
+            "fid_every_steps": "FID/KID score images",
+            "progressive": "progressive resolution is an image schedule",
+            "pipeline_gd": "the G/D stage pipeline needs two players",
+            "diffaug": "augmentation acts on images",
+            "r1_gamma": "R1 regularizes a critic",
+            "label_smoothing": "label smoothing belongs to the GAN loss",
+            "g_ema_decay": "the weight average is the generator's",
+            "elastic_target_devices": "live resharding covers the GAN "
+                                      "state tree only",
+            "precision": "the precision ladder rewrites the image "
+                         "families' dtypes",
+        }
+        for name, why in image_only.items():
+            if getattr(self, name):
+                raise ValueError(
+                    f"{name}={getattr(self, name)!r} is an image-family "
+                    f"service ({why}); arch={TOKEN_ARCH!r} refuses it")
+        if self.steps_per_call != 1 or self.grad_accum != 1 \
+                or self.n_critic != 1:
+            raise ValueError(
+                f"arch={TOKEN_ARCH!r} runs one likelihood step per call: "
+                "steps_per_call, grad_accum and n_critic must be 1")
+        if self.backend != "gspmd" or self.mesh.zero_stage != 1 \
+                or self.mesh.spatial or self.mesh.model != 1:
+            raise ValueError(
+                f"arch={TOKEN_ARCH!r} runs on the gspmd backend over a "
+                "data-parallel mesh (no expert axis, no exchange yet)")
+
     def __post_init__(self):
+        token = self.model.arch == TOKEN_ARCH
+        if token != (self.loss == LM_LOSS):
+            raise ValueError(
+                f"loss={LM_LOSS!r} (next-token likelihood) and model.arch="
+                f"{TOKEN_ARCH!r} go together: got loss={self.loss!r} with "
+                f"arch={self.model.arch!r}")
+        if token:
+            self._refuse_image_services()
         if self.precision not in ("", "f32", "bf16", "fp8"):
             raise ValueError(
                 f"precision must be one of '', 'f32', 'bf16', 'fp8', got "
@@ -794,7 +927,7 @@ class TrainConfig:
         if self.comm_bucket_mb <= 0:
             raise ValueError(
                 f"comm_bucket_mb must be > 0, got {self.comm_bucket_mb}")
-        if self.loss not in ("gan", "wgan-gp", "hinge"):
+        if self.loss not in ("gan", "wgan-gp", "hinge", LM_LOSS):
             raise ValueError(f"unknown loss {self.loss!r}")
         if self.update_mode not in ("sequential", "fused"):
             raise ValueError(f"unknown update_mode {self.update_mode!r}")
@@ -1050,8 +1183,9 @@ def _known_fields(cls, d: Dict[str, Any], *, context: str) -> Dict[str, Any]:
 
 def config_from_dict(d: Dict[str, Any]) -> TrainConfig:
     d = dict(d)
-    model = ModelConfig(**_known_fields(ModelConfig, dict(d.pop("model", {})),
-                                        context="model"))
+    saved = dict(d.pop("model", {}))
+    cls = TokenModelConfig if saved.get("arch") == TOKEN_ARCH else ModelConfig
+    model = cls(**_known_fields(cls, saved, context="model"))
     mesh = MeshConfig(**_known_fields(MeshConfig, dict(d.pop("mesh", {})),
                                       context="mesh"))
     rest = _known_fields(TrainConfig, d, context="train")

@@ -101,3 +101,25 @@ def synthetic_batches(batch_size: int, image_size: int = 64, channels: int = 3,
         if pool:
             cache.append(item)
         yield item
+
+
+def synthetic_id_batches(batch_size: int, seq_len: int, vocab_size: int,
+                         seed: int = 0, pool: int = 64) -> Iterator:
+    """Endless stream of int32 token-id batches [batch, seq_len], uniform
+    over `vocab_size` ids (one document per row, no structure to learn:
+    like `synthetic_batches`, it exists to exercise the training machinery).
+    The first `pool` batches are drawn, then cycled (pool=0: every batch
+    fresh)."""
+    if pool < 0:
+        raise ValueError(f"pool must be >= 0, got {pool}")
+    rng = np.random.default_rng(seed)
+    cache = []
+    while True:
+        if pool and len(cache) >= pool:
+            yield from cache
+            continue
+        ids = rng.integers(vocab_size, size=(batch_size, seq_len),
+                           dtype=np.int32)
+        if pool:
+            cache.append(ids)
+        yield ids

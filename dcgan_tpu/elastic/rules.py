@@ -94,6 +94,15 @@ PARTITION_RULES: Tuple[Tuple[str, LogicalSpec], ...] = (
     (r"(^|/)attn/gamma$", REPLICATED),
     # stylegan learned constant input [S, S, C]
     (r"(^|/)const$", REPLICATED),
+    # -- the token family (models/mla_moe.py): every leaf replicated; the
+    # mesh has no expert axis, so a chip's state IS its share of the experts
+    (r"(^|/)embed/table$", REPLICATED),
+    (r"(^|/)(q_a|q_b|kv_a|kv_b|o_proj|gate|up|down|eh_proj|lm_head|router)"
+     r"/w$", REPLICATED),
+    (r"(^|/)experts/(gate|up|down)$", REPLICATED),
+    (r"(^|/)(attn_norm|ffn_norm|q_norm|kv_norm|final_norm|enorm|hnorm)"
+     r"/scale$", REPLICATED),
+    (r"^moe_(bias|counts)/\w+$", REPLICATED),
     # Adam step counts (optax ScaleByAdamState / schedule counts)
     (r"(^|/)count$", REPLICATED),
     # the trainer's global step

@@ -36,9 +36,17 @@ Layout notes (TPU):
   so none pads; the dQ accumulator and its output block are the only
   [S, d] (lane-padded) residents, which keeps S = 65536 compiling at
   batch > 1 like the two-kernel backward it replaces.
-- The head dims here are narrow (SAGAN: d_qk = C/8, d_v = C/2); they ride the
-  lane axis zero-padded. That wastes lanes but not HBM, and the kernels are
-  shape-agnostic — the same code serves wide heads.
+- Head widths that have RUN on the v5e: q/k 8 with v 32 (SAGAN: d_qk = C/8,
+  d_v = C/2; they ride the lane axis zero-padded, which wastes lanes but not
+  HBM), 64/64 (tools/bench_attention.py), and since PR 27 q/k 192 with v 128
+  at S = 8192, causal, heads folded into the batch axis (the latent-attention
+  trunk of models/mla_moe.py; PERF.md section 6 has the times). Other widths
+  compile from the same code and have not been timed.
+- `causal=True` (a static argument) computes the lower triangle only: the
+  forward's k-loop ends at the q-tile's diagonal, the backward's q-loop
+  starts at the k-tile's, and only the tiles the diagonal crosses build a
+  mask. Tiles above the diagonal are skipped, not masked after the fact.
+  The non-causal call traces the same kernel bodies it always did.
 - Off-TPU the kernels run under `interpret=True`, so the CPU test mesh
   exercises the identical code path (tests/test_flash_backward.py and
   tests/test_pallas_attention.py assert exactness against
@@ -89,7 +97,8 @@ BWD_BLOCK_Q = 1024
 # sequence length — so crossover tables never mix measurements of
 # different kernel code. Gen 2 = bf16-operand policy + (256, 1024) tiles +
 # lane-major backward stats. Gen 3 = one backward kernel (flash_dq_dkv).
-ATTN_GEN = 3
+# Gen 4 = the static `causal` argument (non-causal programs unchanged).
+ATTN_GEN = 4
 
 _NEG_INF = -1e30  # finite stand-in for -inf: keeps exp()/max() NaN-free
 
@@ -142,7 +151,16 @@ def _blocks(s: int, block_q: int = BLOCK_Q) -> tuple:
 # forward
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, tk):
+def _causal_keep(row0, col0, tq: int, tk: int):
+    """[TQ, TK] mask of a score tile whose first row is query `row0` and
+    first column is key `col0`: True where the key is not after the query."""
+    rows = row0 + lax.broadcasted_iota(jnp.int32, (tq, tk), 0)
+    cols = col0 + lax.broadcasted_iota(jnp.int32, (tq, tk), 1)
+    return cols <= rows
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, tk,
+                causal=False):
     # Precision policy (shared with ops/attention.py::full_attention):
     # matmul operands stay in the INPUT dtype — bf16 rides the MXU fast
     # path — while scores/stats/accumulator are f32 via
@@ -154,13 +172,16 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, tk):
     tq = q.shape[0]
     dv = v_ref.shape[-1]
     n_k = k_ref.shape[1] // tk
+    row0 = pl.program_id(1) * tq if causal else None
 
-    def body(j, carry):
+    def body(j, carry, masked=False):
         m, l, acc = carry
         kb = k_ref[0, pl.ds(j * tk, tk), :]
         vb = v_ref[0, pl.ds(j * tk, tk), :]
         s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
+        if masked:
+            s = jnp.where(_causal_keep(row0, j * tk, tq, tk), s, _NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m - m_new)
@@ -172,7 +193,18 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, tk):
     m0 = jnp.full((tq, 1), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((tq, 1), jnp.float32)
     acc0 = jnp.zeros((tq, dv), jnp.float32)
-    m, l, acc = lax.fori_loop(0, n_k, body, (m0, l0, acc0))
+    if causal:
+        # k-tiles wholly on or below the diagonal of this q-tile, then the
+        # ones the diagonal crosses; the rest are never touched. Key 0 is in
+        # the first tile and no query precedes it, so every row's running
+        # max is a real score from the first tile on.
+        n_full = (row0 + 1) // tk
+        n_here = (row0 + tq + tk - 1) // tk
+        carry = lax.fori_loop(0, n_full, body, (m0, l0, acc0))
+        m, l, acc = lax.fori_loop(
+            n_full, n_here, functools.partial(body, masked=True), carry)
+    else:
+        m, l, acc = lax.fori_loop(0, n_k, body, (m0, l0, acc0))
     o_ref[0] = (acc / l).astype(o_ref.dtype)
     # log-sum-exp per row — the single vector the backward needs to
     # reconstruct p tiles without storing them. Kept [S, 1] (not [S]):
@@ -181,12 +213,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, tk):
     lse_ref[0] = m + jnp.log(l)
 
 
-def _fwd_impl(q, k, v, scale):
+def _fwd_impl(q, k, v, scale, causal=False):
     B, S, dk = q.shape
     dv = v.shape[-1]
     tq, tk = _blocks(S)
+    kernel = functools.partial(_fwd_kernel, scale=scale, tk=tk)
+    if causal:
+        kernel = functools.partial(kernel, causal=True)
     out, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=scale, tk=tk),
+        kernel,
         name="flash_fwd",
         grid=(B, S // tq),
         in_specs=[pl.BlockSpec((1, tq, dk), lambda b, i: (b, i, 0)),
@@ -207,7 +242,8 @@ def _fwd_impl(q, k, v, scale):
 # ---------------------------------------------------------------------------
 
 def _dq_dkv_kernel(k_ref, v_ref, qT_ref, doT_ref, lse_ref, delta_ref,
-                   dq_ref, dkT_ref, dvT_ref, dq_acc, *, scale, tq):
+                   dq_ref, dkT_ref, dvT_ref, dq_acc, *, scale, tq,
+                   causal=False):
     # same operand-dtype / f32-accumulation policy as the forward. One
     # k-tile per program; q^T/do^T/lse/delta enter as full-sequence
     # residents with the sequence on the LANE axis (a [S, d] or [S, 1]
@@ -223,7 +259,7 @@ def _dq_dkv_kernel(k_ref, v_ref, qT_ref, doT_ref, lse_ref, delta_ref,
     def _():
         dq_acc[...] = jnp.zeros(dq_acc.shape, jnp.float32)
 
-    def body(i, carry):
+    def body(i, carry, masked=False):
         dkT, dvT = carry
         rows = pl.ds(pl.multiple_of(i * tq, tq), tq)
         qT = qT_ref[0, :, rows]                          # [dk, TQ]
@@ -232,6 +268,9 @@ def _dq_dkv_kernel(k_ref, v_ref, qT_ref, doT_ref, lse_ref, delta_ref,
         delta = delta_ref[0, 0, rows][:, None]
         s = jax.lax.dot_general(qT.T, kb, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
+        if masked:
+            tk = kb.shape[0]
+            s = jnp.where(_causal_keep(i * tq, j * tk, tq, tk), s, _NEG_INF)
         p = jnp.exp(s - lse)                             # [TQ, TK]
         dp = jax.lax.dot_general(doT.T, vb, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
@@ -243,9 +282,20 @@ def _dq_dkv_kernel(k_ref, v_ref, qT_ref, doT_ref, lse_ref, delta_ref,
                             preferred_element_type=jnp.float32)
         return dkT, dvT
 
-    dkT, dvT = lax.fori_loop(
-        0, n_q, body, (jnp.zeros(dkT_ref.shape[1:], jnp.float32),
-                       jnp.zeros(dvT_ref.shape[1:], jnp.float32)))
+    zeros = (jnp.zeros(dkT_ref.shape[1:], jnp.float32),
+             jnp.zeros(dvT_ref.shape[1:], jnp.float32))
+    if causal:
+        # q-tiles the diagonal crosses within this k-tile, then the ones
+        # wholly on or below it; q-tiles above the k-tile are skipped (their
+        # rows of the dQ accumulator take nothing from it)
+        col0 = j * kb.shape[0]
+        i_first = col0 // tq
+        i_full = jnp.minimum((col0 + kb.shape[0] + tq - 2) // tq, n_q)
+        carry = lax.fori_loop(i_first, i_full,
+                              functools.partial(body, masked=True), zeros)
+        dkT, dvT = lax.fori_loop(i_full, n_q, body, carry)
+    else:
+        dkT, dvT = lax.fori_loop(0, n_q, body, zeros)
     dkT_ref[0] = (dkT * scale).astype(dkT_ref.dtype)
     dvT_ref[0] = dvT.astype(dvT_ref.dtype)
 
@@ -273,12 +323,13 @@ def _bwd_stats(q, out, lse, g):
             lse.reshape(B, 1, S), delta.reshape(B, 1, S))
 
 
-def _bwd_impl(scale, res, g):
+def _bwd_impl(scale, causal, res, g):
     q, k, v, out, lse = res
-    return _bwd_core(scale, k, v, *_bwd_stats(q, out, lse, g))
+    return _bwd_core(scale, k, v, *_bwd_stats(q, out, lse, g), causal=causal)
 
 
-def _bwd_core(scale, k, v, qT, doT, lse, delta, grad_dtype=None):
+def _bwd_core(scale, k, v, qT, doT, lse, delta, grad_dtype=None,
+              causal=False):
     """The backward pallas_call: dQ, dK and dV from one pass over the score
     tiles. grad_dtype overrides the gradient output dtype (the ring
     backward asks for f32 so per-hop contributions are not rounded to bf16
@@ -290,8 +341,11 @@ def _bwd_core(scale, k, v, qT, doT, lse, delta, grad_dtype=None):
     def resident(d):
         return pl.BlockSpec((1, d, S), lambda b, j: (b, 0, 0))
 
+    kernel = functools.partial(_dq_dkv_kernel, scale=scale, tq=tq)
+    if causal:
+        kernel = functools.partial(kernel, causal=True)
     dq, dkT, dvT = pl.pallas_call(
-        functools.partial(_dq_dkv_kernel, scale=scale, tq=tq),
+        kernel,
         name="flash_dq_dkv",
         grid=(B, S // tk),
         in_specs=[pl.BlockSpec((1, tk, dk), lambda b, j: (b, j, 0)),
@@ -310,18 +364,20 @@ def _bwd_core(scale, k, v, qT, doT, lse, delta, grad_dtype=None):
     return dq, jnp.swapaxes(dkT, 1, 2), jnp.swapaxes(dvT, 1, 2)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                    scale: float) -> jax.Array:
+                    scale: float, causal: bool = False) -> jax.Array:
     """softmax(q k^T * scale) v over [B, S, d] blocks without ever
     materializing the [S, S] score matrix in HBM. Returns float32 (matching
-    ops/attention.py::full_attention's accumulation contract)."""
-    out, _ = _fwd_impl(q, k, v, scale)
+    ops/attention.py::full_attention's accumulation contract). `causal`
+    (static): query i sees keys 0..i only, and the tiles above the diagonal
+    are not computed."""
+    out, _ = _fwd_impl(q, k, v, scale, causal)
     return out
 
 
-def _flash_vjp_fwd(q, k, v, scale):
-    out, lse = _fwd_impl(q, k, v, scale)
+def _flash_vjp_fwd(q, k, v, scale, causal):
+    out, lse = _fwd_impl(q, k, v, scale, causal)
     return out, (q, k, v, out, lse)
 
 

@@ -16,7 +16,7 @@ from typing import Any, Callable, Dict, Optional
 import jax
 from jax.sharding import Mesh
 
-from dcgan_tpu.config import TrainConfig
+from dcgan_tpu.config import TOKEN_ARCH, TrainConfig
 from dcgan_tpu.parallel.mesh import make_mesh
 from dcgan_tpu.parallel.sharding import (
     batch_sharding,
@@ -46,17 +46,26 @@ class ParallelTrain:
     step(state, images, key)          (unconditional models)
     step(state, images, key, labels)  (conditional models)
     sample(state, z[, labels]) -> images (replicated output for host saving)
+
+    A one-network likelihood family (arch "mla_moe", loss "lm") has `init`
+    and `step(state, ids [B, S] int32, key) -> (state, scalar metrics)`
+    and nothing else: `programs` holds "init" and "train_step" alone, and
+    every other surface (`sample`, `summarize`, `eval_losses`, `multi_step`,
+    `gen_fakes`, `d_update`, `g_update`) raises by its name when called.
     """
     mesh: Mesh
     cfg: TrainConfig
     shardings: Pytree
     init: Callable
     step: Callable
-    sample: Callable
-    summarize: Callable  # (state, images, key[, labels]) -> activation stats
-    eval_losses: Callable  # (state, images, z[, labels]) -> loss metrics
+    sample: Optional[Callable] = None
+    summarize: Optional[Callable] = None
+                         # (state, images, key[, labels]) -> activation stats
+    eval_losses: Optional[Callable] = None
+                           # (state, images, z[, labels]) -> loss metrics
                            # on a held-out batch, no state update
-    multi_step: Callable   # (state, images [K,B,...], keys [K][, labels
+    multi_step: Optional[Callable] = None
+                           # (state, images [K,B,...], keys [K][, labels
                            # [K,B]]) -> (state, last step's metrics): K train
                            # steps as ONE compiled lax.scan program — one
                            # host dispatch instead of K (the host round-trip
@@ -65,13 +74,16 @@ class ParallelTrain:
     # pipelined stage programs (ISSUE 7, --pipeline_gd; unconditional
     # models only — traced lazily, so merely building them for a
     # conditional config is harmless):
-    gen_fakes: Callable    # (state, key) -> [n_critic, B, H, W, C] fake
+    gen_fakes: Optional[Callable] = None
+                           # (state, key) -> [n_critic, B, H, W, C] fake
                            # stack — the fill/refill program
-    d_update: Callable     # (state, images, fakes, key) -> (state,
+    d_update: Optional[Callable] = None
+                           # (state, images, fakes, key) -> (state,
                            # metrics): critic update(s) consuming the
                            # provided stack (dead after this dispatch —
                            # the trainer's buffer manager drops it)
-    g_update: Callable     # (state, key) -> (state, fakes, metrics):
+    g_update: Optional[Callable] = None
+                           # (state, key) -> (state, fakes, metrics):
                            # generator update returning the next step's
                            # d_update input (staleness 1)
     programs: Dict[str, Callable] = dataclasses.field(default_factory=dict)
@@ -95,13 +107,54 @@ class ParallelTrain:
 
         tripwire.wrap_parallel_train(self)
         if not self.programs:
-            object.__setattr__(self, "programs", {
+            named = {
                 "init": self.init, "train_step": self.step,
                 "multi_step": self.multi_step, "sampler": self.sample,
                 "summarize": self.summarize,
                 "eval_losses": self.eval_losses,
                 "gen_fakes": self.gen_fakes, "d_update": self.d_update,
-                "g_update": self.g_update})
+                "g_update": self.g_update}
+            object.__setattr__(self, "programs", {
+                n: f for n, f in named.items() if f is not None})
+        for field in ("sample", "summarize", "eval_losses", "multi_step",
+                      "gen_fakes", "d_update", "g_update"):
+            if getattr(self, field) is None:
+                object.__setattr__(self, field, _refusal(field, self.cfg))
+
+
+def _refusal(name: str, cfg: TrainConfig) -> Callable:
+    def refuse(*args, **kwargs):
+        raise NotImplementedError(
+            f"ParallelTrain.{name}: a one-network likelihood family "
+            f"(arch={cfg.model.arch!r}) has the programs 'init' and "
+            f"'train_step' only")
+    return refuse
+
+
+def make_lm_parallel_train(cfg: TrainConfig, mesh: Mesh) -> ParallelTrain:
+    """The likelihood step of the token family over a data-parallel mesh:
+    state replicated (the rule table; the mesh has no expert axis), id
+    batches [B, S] sharded over "data", the state donated."""
+    from dcgan_tpu.train.steps import make_lm_train_step
+
+    if mesh.shape["model"] != 1:
+        raise ValueError(
+            f"arch={cfg.model.arch!r} runs over a data-parallel mesh, got "
+            f"{dict(mesh.shape)}")
+    if cfg.batch_size % mesh.shape["data"]:
+        raise ValueError(
+            f"batch_size {cfg.batch_size} must divide over the "
+            f"{mesh.shape['data']}-way data axis")
+    fns = make_lm_train_step(cfg, mesh=mesh)
+    shardings = state_shardings(
+        jax.eval_shape(fns.init, jax.random.key(0)), mesh)
+    rep = replicated(mesh)
+    return ParallelTrain(
+        mesh=mesh, cfg=cfg, shardings=shardings,
+        init=jax.jit(fns.init, out_shardings=shardings),
+        step=jax.jit(fns.train_step,
+                     in_shardings=(shardings, batch_sharding(mesh, 2), rep),
+                     out_shardings=(shardings, rep), donate_argnums=(0,)))
 
 
 def make_multi_step_body(step_fn: Callable) -> Callable:
@@ -134,6 +187,8 @@ def make_parallel_train(cfg: TrainConfig,
 
         return make_shard_map_train(cfg, mesh)
     mesh = mesh or make_mesh(cfg.mesh)
+    if cfg.model.arch == TOKEN_ARCH:
+        return make_lm_parallel_train(cfg, mesh)
     pallas_mesh = None
     if cfg.model.use_pallas and mesh.size > 1:
         # pallas_call is opaque to GSPMD: left alone, the partitioner would

@@ -18,8 +18,8 @@ tests/bench code) can materialize them without repeating knob soup:
 - ``wgan-gp``     — WGAN-GP loss variant: Wasserstein critic + gradient
   penalty (grad-of-grad), canonical lr 1e-4 / β1 0 hyperparameters.
 
-Plus five beyond-BASELINE presets across three further model/recipe
-families (ten registered configs total — keep this count in sync with
+Plus seven beyond-BASELINE presets across four further model/recipe
+families (twelve registered configs total — keep this count in sync with
 ``PRESETS`` below):
 
 - ``sagan64``     — self-attention GAN (hinge + TTUR + EMA, attention at
@@ -31,6 +31,9 @@ families (ten registered configs total — keep this count in sync with
   dense form cannot allocate at batch 64 (DESIGN.md §8b).
 - ``sngan-cifar10`` / ``stylegan64`` — the resnet and stylegan families'
   canonical recipes (see their factory docstrings).
+- ``joyai_llm_flash`` / ``mla_moe_tiny`` — the one-network token family
+  (latent attention, routed experts, a multi-token head; likelihood step):
+  a published 48B model as published, and the size the tests train.
 
 Every preset factory takes overrides as keyword arguments forwarded to
 `dataclasses.replace`-style reconstruction, so the CLI's explicit flags win
@@ -42,7 +45,13 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable, Dict
 
-from dcgan_tpu.config import MeshConfig, ModelConfig, TrainConfig
+from dcgan_tpu.config import (
+    LM_LOSS,
+    MeshConfig,
+    ModelConfig,
+    TokenModelConfig,
+    TrainConfig,
+)
 
 
 def _build(model: ModelConfig, mesh: MeshConfig, **train_kw) -> TrainConfig:
@@ -199,6 +208,50 @@ def stylegan64(**overrides) -> TrainConfig:
     return dataclasses.replace(cfg, **overrides)
 
 
+def _lm(model: TokenModelConfig, **train_kw) -> TrainConfig:
+    """The token family's run knobs: the likelihood loss, the program's
+    Adam at beta1 0.9, no decay, no clipping, and every image-only service
+    (sample grids, activation summaries) off."""
+    kw = dict(loss=LM_LOSS, beta1=0.9, learning_rate=2.2e-4,
+              sample_every_steps=0, activation_summary_steps=0)
+    kw.update(train_kw)
+    return _build(model, MeshConfig(), **kw)
+
+
+def joyai_llm_flash(**overrides) -> TrainConfig:
+    """JoyAI-LLM-Flash (48B-A2.7B) AS PUBLISHED (jdopensource, config.json
+    on huggingface.co/jdopensource/JoyAI-LLM-Flash; the layer equations
+    are DeepSeek-V3's, arXiv:2412.19437): 40 layers of latent attention (32
+    heads, q rank 1536, kv rank 512, 128 + 64 rotary | 128 value), one
+    leading dense layer of width 7168, then 256 routed experts of width 768
+    (8 per token, sigmoid scores, scale 2.5) beside one shared expert, one
+    multi-token module, vocabulary 129,280, 8,192-token rows. No chip
+    holds it whole (one expert layer is 19.8 GB of training state): a
+    configuration that runs cuts depth, experts held and vocabulary to
+    one chip's share of a stated deployment
+    (benchmark/configs/joyai-llm-flash.json)."""
+    model = TokenModelConfig(
+        vocab_size=129280, hidden_size=2048, num_hidden_layers=40,
+        first_k_dense_replace=1, intermediate_size=7168,
+        moe_intermediate_size=768, n_routed_experts=256, n_shared_experts=1,
+        num_experts_per_tok=8, norm_topk_prob=True,
+        routed_scaling_factor=2.5, num_attention_heads=32,
+        q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, rope_theta=32000000.0,
+        rope_interleave=True, rms_norm_eps=1e-6, num_nextn_predict_layers=1,
+        experts_held=256, first_expert=0, seq_len=8192)
+    return dataclasses.replace(_lm(model, batch_size=1), **overrides)
+
+
+def mla_moe_tiny(**overrides) -> TrainConfig:
+    """The token family at a size the CPU tests and a smoke run train:
+    hidden 64, 2 heads, 8 experts of which 4 held, vocabulary 256, rows of
+    32 tokens, float32, dense masked attention in place of the kernels."""
+    model = TokenModelConfig(experts_held=4, compute_dtype="float32",
+                             use_pallas=False)
+    return dataclasses.replace(_lm(model, batch_size=8), **overrides)
+
+
 PRESETS: Dict[str, Callable[..., TrainConfig]] = {
     "celeba64": celeba64,
     "lsun64-dp8": lsun64_dp8,
@@ -210,6 +263,8 @@ PRESETS: Dict[str, Callable[..., TrainConfig]] = {
     "sagan256-lc": sagan256_lc,
     "sngan-cifar10": sngan_cifar10,
     "stylegan64": stylegan64,
+    "joyai_llm_flash": joyai_llm_flash,
+    "mla_moe_tiny": mla_moe_tiny,
 }
 
 # Preset revisions: bump when a preset's PERF-RELEVANT config changes

@@ -204,29 +204,38 @@ class ZeroHooks:
 
 @dataclasses.dataclass(frozen=True)
 class TrainStepFns:
-    """Bundle of the compiled-surface functions for one TrainConfig."""
+    """Bundle of the compiled-surface functions for one TrainConfig. A
+    one-network likelihood family (`make_lm_train_step`) has `train_step`
+    and `init` only: the sampler, the probes and the stage programs stay
+    None, and `parallel/api.py` turns them into refusals by name."""
     train_step: Callable  # (state, images, key[, labels]) -> (state, metrics)
-    sample: Callable      # (state, z[, labels]) -> images (EMA-stat BN)
     init: Callable        # (key,) -> state
-    summarize: Callable   # (state, images, key[, labels]) -> per-layer
+    sample: Optional[Callable] = None  # (state, z[, labels]) -> images
+                          # (EMA-stat BN)
+    summarize: Optional[Callable] = None
+                          # (state, images, key[, labels]) -> per-layer
                           # activation histogram/sparsity stats (on device)
-    eval_losses: Callable  # (state, images, z[, labels]) -> loss metrics,
+    eval_losses: Optional[Callable] = None
+                           # (state, images, z[, labels]) -> loss metrics,
                            # no state update — the reference's sample-batch
                            # loss probe (image_train.py:179-192)
     # pipelined stage programs (ISSUE 7; unconditional models only — the
     # trainer's --pipeline_gd validation enforces that):
-    gen_fakes: Callable   # (state, key) -> [n_critic, B, H, W, C] fake
+    gen_fakes: Optional[Callable] = None
+                          # (state, key) -> [n_critic, B, H, W, C] fake
                           # stack — fresh z per critic slot, train-mode BN
                           # (updates discarded, like the fused D branch),
                           # constrain_fake applied. The FILL program: run
                           # start, restart, and rollback refill
-    d_update: Callable    # (state, images, fakes, key) -> (state, metrics):
+    d_update: Optional[Callable] = None
+                          # (state, images, fakes, key) -> (state, metrics):
                           # the critic update(s) consuming a provided fake
                           # stack; touches ONLY the disc half of the state
                           # (params/opt/bn.disc) — gen/ema_gen/step ride
                           # through untouched, so the tree shape is the
                           # fused step's exactly
-    g_update: Callable    # (state, key) -> (state, fakes, metrics): the
+    g_update: Optional[Callable] = None
+                          # (state, key) -> (state, fakes, metrics): the
                           # generator update against the CURRENT D
                           # (sequential semantics — the trainer dispatches
                           # it after d_update), returning the fake stack it
@@ -1037,3 +1046,88 @@ def make_train_step(cfg: TrainConfig, *, axis_name: Optional[str] = None,
                         summarize=summarize, eval_losses=eval_losses,
                         gen_fakes=gen_fakes, d_update=d_update,
                         g_update=g_update)
+
+
+# ---------------------------------------------------------------------------
+# the one-network likelihood step (TrainConfig.loss == "lm")
+# ---------------------------------------------------------------------------
+
+def init_lm_state(key, cfg: TrainConfig) -> Pytree:
+    """State of the token family: parameters with the optimizer state
+    beside them, the routers' selection biases (no gradient, no optimizer
+    state; the step leaves them as they are) and the per-expert pair counts
+    the step accumulates."""
+    from dcgan_tpu.models.mla_moe import token_init
+
+    params, bias = token_init(key, cfg.model)
+    held = cfg.model.experts_held
+    return {
+        "params": params,
+        "moe_bias": bias,
+        "moe_counts": {n: jnp.zeros((held,), jnp.int32) for n in bias},
+        "opt": make_optimizer(cfg).init(params),
+        "step": jnp.zeros((), jnp.int32),
+    }
+
+
+def make_lm_train_step(cfg: TrainConfig, *, mesh=None) -> TrainStepFns:
+    """Loss, gradient and the program's Adam for the token family, as the
+    same `TrainStepFns` the two-player step returns. `train_step(state, ids
+    [B, S] int32, key)`: the key is unused (nothing is drawn). Under a mesh
+    with a data axis > 1 loss and gradient run per data shard inside a
+    `shard_map` (each shard's rows through attention, router and the
+    experts held, as every chip of a deployment runs its own tokens) and
+    are averaged across it; the kernels are opaque to the partitioner."""
+    from jax.sharding import PartitionSpec as P
+
+    from dcgan_tpu.models.mla_moe import token_loss
+    from dcgan_tpu.utils.backend import shard_map
+
+    mcfg = cfg.model
+    opt = make_optimizer(cfg)
+    n_data = 1 if mesh is None else mesh.shape["data"]
+
+    def loss_grad(params, bias, ids):
+        (_, aux), grads = jax.value_and_grad(
+            lambda p: token_loss(p, bias, ids, mcfg), has_aux=True)(params)
+        if n_data > 1:
+            grads, aux["loss"], aux["loss_mtp"] = lax.pmean(
+                (grads, aux["loss"], aux["loss_mtp"]), "data")
+            aux["counts"], aux["rows"] = lax.psum(
+                (aux["counts"], aux["rows"]), "data")
+        return grads, aux
+
+    if n_data > 1:
+        loss_grad = shard_map(loss_grad, mesh=mesh,
+                              in_specs=(P(), P(), P("data")),
+                              out_specs=P(), check=False)
+
+    def train_step(state: Pytree, ids: jax.Array, key: jax.Array
+                   ) -> Tuple[Pytree, dict]:
+        del key
+        grads, aux = loss_grad(state["params"], state["moe_bias"], ids)
+        with jax.named_scope("adam"):
+            updates, opt_state = opt.update(grads, state["opt"],
+                                            state["params"])
+            params = optax.apply_updates(state["params"], updates)
+        counts = aux["counts"]
+        per_expert = jnp.concatenate(list(counts.values()))
+        pairs = jnp.sum(per_expert)
+        metrics = {
+            "loss": aux["loss"], "loss_mtp": aux["loss_mtp"],
+            # (token, expert) pairs routed to experts held here, all layers
+            "moe_pairs_here": pairs.astype(jnp.float32),
+            # the fullest held expert's pairs over the mean
+            "moe_load_max": jnp.max(per_expert) * per_expert.size
+            / jnp.maximum(pairs, 1).astype(jnp.float32),
+            # rows the grouped kernels computed (whole tiles)
+            "moe_rows_computed": aux["rows"].astype(jnp.float32),
+        }
+        state = {**state, "params": params, "opt": opt_state,
+                 "moe_counts": {n: state["moe_counts"][n] + c
+                                for n, c in counts.items()},
+                 "step": state["step"] + 1}
+        return state, metrics
+
+    return TrainStepFns(train_step=train_step,
+                        init=lambda key: init_lm_state(key, cfg))

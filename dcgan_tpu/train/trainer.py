@@ -57,7 +57,7 @@ import jax
 import numpy as np
 
 from dcgan_tpu.analysis import tripwire
-from dcgan_tpu.config import TrainConfig, load_config, save_config
+from dcgan_tpu.config import TOKEN_ARCH, TrainConfig, load_config, save_config
 from dcgan_tpu.data import (
     DataConfig,
     make_dataset,
@@ -113,6 +113,10 @@ def _data_iterator(cfg: TrainConfig, mesh, *, synthetic: bool,
     generator (cheap — no slicing, no upload); real-data loaders discard
     yielded batches (best-effort: a threaded shuffle stream has no exact
     position to restore anyway)."""
+    if cfg.model.arch == TOKEN_ARCH:
+        return _token_data_iterator(cfg, mesh, synthetic=synthetic,
+                                    seed_offset=seed_offset,
+                                    skip_batches=skip_batches)
     sharding = batch_sharding(mesh, 4, spatial=cfg.mesh.spatial)
     conditional = cfg.model.num_classes > 0
     label_sharding = batch_sharding(mesh, 1) if conditional else None
@@ -250,6 +254,33 @@ def _data_iterator(cfg: TrainConfig, mesh, *, synthetic: bool,
     for _ in range(skip_batches):
         next(ds)
     return ds
+
+
+def _token_data_iterator(cfg: TrainConfig, mesh, *, synthetic: bool,
+                         seed_offset: int = 0,
+                         skip_batches: int = 0) -> Iterator:
+    """Sharded int32 id batches [batch, seq_len] for the token family,
+    through the same `DevicePrefetcher` (or inline `to_global`) as image
+    batches. Synthetic ids only: token records have no loader yet."""
+    if not synthetic:
+        raise ValueError(
+            f"arch={TOKEN_ARCH!r} trains on synthetic ids only (--synthetic): "
+            "token records have no reader in data/ yet")
+    from dcgan_tpu.data.pipeline import DevicePrefetcher, process_local_box
+    from dcgan_tpu.data.synthetic import synthetic_id_batches
+
+    sharding = batch_sharding(mesh, 2)
+    m = cfg.model
+    box = process_local_box(sharding, (cfg.batch_size, m.seq_len))
+    src = synthetic_id_batches(box[0].stop - box[0].start, m.seq_len,
+                               m.vocab_size,
+                               seed=cfg.seed + seed_offset + box[0].start)
+    for _ in range(skip_batches):
+        next(src)
+    if cfg.prefetch_device_batches > 0:
+        return DevicePrefetcher(src, sharding, None,
+                                depth=cfg.prefetch_device_batches)
+    return (to_global(batch, sharding) for batch in src)
 
 
 def _sample_data_iterator(cfg: TrainConfig, mesh, *, synthetic: bool,
@@ -576,7 +607,9 @@ def _train_run(cfg: TrainConfig, *, synthetic_data: bool,
     n_samples = max(cfg.sample_size, rows * cols)
     data_axis = mesh.shape["data"]
     n_samples = -(-n_samples // data_axis) * data_axis  # data-axis multiple
-    sample_z = jax.random.uniform(
+    # a one-network token family has no sampler: no z, and the config
+    # already refused every service that would ask for one
+    sample_z = None if cfg.model.arch == TOKEN_ARCH else jax.random.uniform(
         jax.random.key(cfg.seed + 1), (n_samples, cfg.model.z_dim),
         minval=-1.0, maxval=1.0)
     sample_labels = None
@@ -1215,9 +1248,12 @@ def _train_run(cfg: TrainConfig, *, synthetic_data: bool,
         if chief and cfg.log_every_steps and s % cfg.log_every_steps == 0:
             m = _host_vals(p)
             epoch = s * pcfg.batch_size // epoch_size
+            # the two players' losses, or the token family's two heads'
+            losses = " ".join(f"{k} {m[k]:.4f}" for k in
+                              ("d_loss", "g_loss", "loss", "loss_mtp")
+                              if k in m)
             print(f"[dcgan_tpu] epoch {epoch} step {s} "
-                  f"time {time.time() - t_start:.1f}s "
-                  f"d_loss {m['d_loss']:.4f} g_loss {m['g_loss']:.4f}")
+                  f"time {time.time() - t_start:.1f}s {losses}")
         # record AFTER the step log so the ring rides the materialization
         # the log already paid for (default chief logs every step); still
         # never forces a readback of its own

@@ -57,6 +57,8 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 
+from dcgan_tpu.config import TOKEN_ARCH
+
 #: jax's own variable: where it is set the cache is kept there, and the
 #: only thing that overrides it is an explicit --compile_cache_dir
 CACHE_ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
@@ -253,11 +255,16 @@ def _program_args(cfg, pt, state, *, sample_z=None, sample_labels=None,
     from dcgan_tpu.parallel import batch_sharding
 
     mesh = pt.mesh
-    size = cfg.model.output_size
-    img_sh = batch_sharding(mesh, 4, spatial=cfg.mesh.spatial)
-    img = jax.ShapeDtypeStruct(
-        (cfg.batch_size, size, size, cfg.model.c_dim), jnp.float32,
-        sharding=img_sh)
+    if cfg.model.arch == TOKEN_ARCH:
+        # the token family's batch: int32 ids [batch, seq_len]
+        img = jax.ShapeDtypeStruct(
+            (cfg.batch_size, cfg.model.seq_len), jnp.int32,
+            sharding=batch_sharding(mesh, 2))
+    else:
+        size = cfg.model.output_size
+        img = jax.ShapeDtypeStruct(
+            (cfg.batch_size, size, size, cfg.model.c_dim), jnp.float32,
+            sharding=batch_sharding(mesh, 4, spatial=cfg.mesh.spatial))
     conditional = cfg.model.num_classes > 0
     key = jax.random.key(0)
     lbls = (jax.ShapeDtypeStruct((cfg.batch_size,), jnp.int32,

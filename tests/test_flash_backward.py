@@ -119,3 +119,63 @@ def test_default_backward_tiles():
         4096, pallas_attention.BWD_BLOCK_Q) == (1024, 1024)
     assert pallas_attention._blocks(
         512, pallas_attention.BWD_BLOCK_Q) == (512, 512)
+
+
+# --- the causal path (a static argument; tiles above the diagonal skipped) ------
+
+def dense_causal(q, k, v, scale):
+    s = jnp.einsum("bqd,bkd->bqk", q.astype(jnp.float32),
+                   k.astype(jnp.float32)) * scale
+    keep = jnp.tril(jnp.ones((q.shape[1], k.shape[1]), bool))
+    p = jax.nn.softmax(jnp.where(keep, s, -jnp.inf), axis=-1)
+    return jnp.einsum("bqk,bkd->bqd", p, v.astype(jnp.float32))
+
+
+# (S, TQ, TK): q-tiles inside one k-tile (the shipped 256/1024 ratio), k-tiles
+# inside one q-tile, square tiles (the backward's 1024/1024), one tile
+@pytest.mark.parametrize("S,tq,tk", [(256, 32, 128), (256, 128, 32),
+                                     (256, 64, 64), (128, 1024, 1024)])
+@pytest.mark.parametrize("d,dv", [(192, 128), (8, 32)])
+def test_causal_forward_and_gradients_match_dense_masked(tiles, S, tq, tk,
+                                                         d, dv):
+    """The latent-attention widths (q/k 192: more than one lane tile; v
+    128) and the narrow ones, against dense masked attention: the forward's
+    k-loop ends at the diagonal, the backward's q-loop starts at it, and
+    only the tiles the diagonal crosses build a mask."""
+    tiles(tq, tk)
+    q, k, v = qkv(S, d, dv)
+    scale = d ** -0.5
+    out = flash_attention(q, k, v, scale, True)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(dense_causal(q, k, v, scale)),
+                               atol=2e-5)
+    # the first query sees the first key alone
+    np.testing.assert_allclose(np.asarray(out[:, 0]), np.asarray(v[:, 0]),
+                               atol=1e-6)
+    ref = grads(lambda q, k, v: dense_causal(q, k, v, scale), q, k, v)
+    got = grads(lambda q, k, v: flash_attention(q, k, v, scale, True), q, k, v)
+    for name, a, b in zip(NAMES, ref, got):
+        assert b.dtype == jnp.float32 and b.shape == a.shape
+        np.testing.assert_allclose(np.asarray(b), np.asarray(a), atol=5e-5,
+                                   err_msg=name)
+
+
+def test_causal_is_static_and_leaves_the_full_kernels_alone():
+    """`causal` is a static argument: the non-causal call traces a kernel
+    with no mask in it (no `iota`, no `select_n`, its loops from 0), as it
+    did before the argument existed; the causal one builds its mask from
+    `iota` in the diagonal tiles only."""
+    q, k, v = qkv(128, 192, 128, jnp.bfloat16)
+
+    def text(causal):
+        f = (lambda q, k, v: flash_attention(q, k, v, 1.0, True)) if causal \
+            else (lambda q, k, v: flash_attention(q, k, v, 1.0))
+        return str(jax.make_jaxpr(jax.grad(
+            lambda q, k, v: jnp.sum(f(q, k, v)), argnums=(0, 1, 2)))(q, k, v))
+
+    full, causal = text(False), text(True)
+    assert "iota" not in full and "select_n" not in full
+    assert "iota" in causal and "select_n" in causal
+    for t in (full, causal):
+        assert len(re.findall(r"name=flash_fwd\b", t)) == 1
+        assert len(re.findall(r"name=flash_dq_dkv\b", t)) == 1

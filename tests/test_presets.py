@@ -11,11 +11,13 @@ class TestPresets:
     def test_all_baseline_configs_named(self):
         # BASELINE.json lists exactly five configurations; sagan64 and
         # sngan-cifar10 are the beyond-BASELINE attention / resnet families
-        # (presets.py docstrings).
+        # (presets.py docstrings); joyai_llm_flash and mla_moe_tiny are the
+        # one-network token family (a published model as published, and the
+        # size the tests train).
         assert set(PRESETS) == {
             "celeba64", "lsun64-dp8", "dcgan128", "cifar10-cond", "wgan-gp",
             "sagan64", "sagan128", "sagan256-lc", "sngan-cifar10",
-            "stylegan64"}
+            "stylegan64", "joyai_llm_flash", "mla_moe_tiny"}
 
     def test_celeba64_is_reference_headline(self):
         cfg = get_preset("celeba64")

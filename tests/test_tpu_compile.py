@@ -154,6 +154,51 @@ def test_flash_attention_fwd_and_vjp(v5e, seq):
     assert "flash_fwd" in text and "flash_dq_dkv" in text
 
 
+@pytest.mark.usefixtures("compiled_kernels")
+@pytest.mark.parametrize("heads, seq", [(32, 8192), (4, 16384)])
+def test_causal_flash_attention_at_latent_attention_widths(v5e, heads, seq):
+    """The token trunk's attention (models/mla_moe.py): q/k 192 wide (more
+    than one lane tile), v 128, causal, heads folded into the batch axis:
+    32 heads of 8,192 tokens as the shipped configuration runs them, and
+    16,384-token rows, whose backward residents (dQ accumulator 16,384 x
+    256 lanes x 4 B = 16 MiB, its output block, q^T and do^T
+    double-buffered: 52 MiB) pass the default scoped VMEM and need the
+    kernels' explicit `vmem_limit_bytes`; 65,536 tokens at this width
+    (224 MB of residents) do not fit the chip's 128 MiB at all."""
+    def loss(q, k, v):
+        return jnp.sum(pallas_attention.flash_attention(q, k, v, 192 ** -0.5,
+                                                        True))
+
+    qk = _sds((heads, seq, 192), jnp.bfloat16, v5e)
+    text = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), qk, qk,
+                    _sds((heads, seq, 128), jnp.bfloat16, v5e))
+    assert "flash_fwd" in text and "flash_dq_dkv" in text
+
+
+def test_grouped_expert_matmuls_compile_at_published_widths(v5e, monkeypatch):
+    """The expert layer's grouped products (megablox `gmm` / `tgmm` through
+    models/mla_moe.py::_gmm): 65,536 sorted rows (8,192 tokens x 8, the
+    worst case) against 16 experts of 2048 x 768 and back, forward and
+    gradients, with this module's own tile sizes."""
+    from dcgan_tpu.models import mla_moe
+
+    monkeypatch.setattr(mla_moe.jax, "default_backend", lambda: "tpu")
+
+    def loss(x, w1, w2, sizes):
+        h = mla_moe._gmm(x, w1, sizes, jnp.bfloat16)
+        return jnp.sum(mla_moe._gmm(h, w2, sizes, jnp.bfloat16)
+                       .astype(jnp.float32))
+
+    text = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                    _sds((65536, 2048), jnp.bfloat16, v5e),
+                    _sds((16, 2048, 768), jnp.bfloat16, v5e),
+                    _sds((16, 768, 2048), jnp.bfloat16, v5e),
+                    _sds((16,), jnp.int32, v5e))
+    # the instructions take the kernels' jitted names (`jvp_jit_gmm___.N`,
+    # `transpose_jvp_jit_tgmm___.N`): what `moe_gmm_roofline` reads
+    assert "_gmm_" in text and "_tgmm_" in text
+
+
 @pytest.mark.slow
 def test_celeba64_train_step_compiles_for_one_chip(v5e):
     """The whole flagship step (gspmd backend, batch 64, bf16) on one

@@ -11,6 +11,7 @@ sequence-parallel strategies. CPU runs are for smoke only.
     python tools/bench_attention.py --seq 1024 4096 16384
     python tools/bench_attention.py --mesh 4 --heads 4   # + ring/ulysses
     JAX_PLATFORMS=cpu python tools/bench_attention.py --seq 256 --steps 2
+    python tools/bench_attention.py --causal --heads 32 --d 192 --dv 128 --seq 8192
 
 Prints one JSON line per (form, S): {"form", "seq", "ms", "heads", ...};
 forms that fail to compile/allocate report {"error": ...} instead of dying,
@@ -44,6 +45,11 @@ def main() -> None:
                    help=">1: also run ring/ulysses over this many devices "
                         "(sequence axis)")
     p.add_argument("--forward_only", action="store_true")
+    p.add_argument("--causal", action="store_true",
+                   help="causal attention: the flash kernels' lower-"
+                        "triangle path against dense masked attention "
+                        "(e.g. --causal --heads 32 --d 192 --dv 128 --seq "
+                        "8192: the token trunk's shape)")
     p.add_argument("--platform", default=None,
                    help="force a JAX platform (e.g. cpu)")
     args = p.parse_args()
@@ -76,6 +82,23 @@ def main() -> None:
         "dense": lambda q, k, v: full_attention(q, k, v, scale=scale),
         "flash": lambda q, k, v: flash_attention(q, k, v, scale),
     }
+    if args.causal:
+        if args.mesh:
+            sys.exit("--causal times the single-device kernels (the ring "
+                     "and ulysses forms have no mask)")
+
+        def dense_causal(q, k, v):
+            s = jnp.einsum("bqd,bkd->bqk", q, k,
+                           preferred_element_type=jnp.float32) * scale
+            keep = jnp.tril(jnp.ones((q.shape[1], k.shape[1]), bool))
+            p = jax.nn.softmax(jnp.where(keep, s, -1e30), axis=-1)
+            return jnp.einsum("bqk,bkd->bqd", p.astype(v.dtype), v,
+                              preferred_element_type=jnp.float32)
+
+        forms = {
+            "dense": dense_causal,
+            "flash": lambda q, k, v: flash_attention(q, k, v, scale, True),
+        }
     if args.mesh == 1:
         sys.exit("--mesh must be > 1 (a 1-device ring/ulysses is the dense "
                  "path)")
@@ -151,6 +174,7 @@ def main() -> None:
                                   "ms": round(ms, 2), "heads": h,
                                   "batch": args.batch,
                                   "backward": not args.forward_only,
+                                  "causal": args.causal,
                                   "gen": ATTN_GEN}))
             except Exception as e:  # the dense wall is the measurement
                 print(json.dumps({"form": name, "seq": S,
