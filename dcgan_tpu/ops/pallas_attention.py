@@ -12,12 +12,31 @@ instead of reading a stored probability matrix. O(S) HBM traffic in S instead
 of O(S^2) — the property that makes sequence length a free axis.
 
 Layout notes (TPU):
-- Forward blocks are [TQ, d] / [TK, d] with TQ = 256, TK = 1024 (chip-swept,
-  see BLOCK_Q/BLOCK_K below — NOT the 128 MXU edge: the systolic array stays
-  busy either way, and wide k-tiles quarter the serialized online-softmax
-  iterations); `q @ k^T` and `p @ v` land on the MXU in the input dtype
-  with f32 accumulation (`preferred_element_type`). Grid (B, S/TQ), both
-  axes parallel; the kernel loops over k-tiles with `lax.fori_loop`.
+- The forward, `flash_fwd`, works in the TRANSPOSED domain, one kernel for
+  every shape. Grid (B, S/TQ), both axes parallel; inputs q^T [B, dk, S]
+  (block (1, dk, TQ)), k [B, S, dk] and v^T [B, dv, S] resident per batch
+  row; a `lax.fori_loop` over k-tiles inside. Each fold builds the score
+  tile as s^T = k_tile q^T, [TK, TQ] with keys on sublanes and queries on
+  lanes (a plain matmul, no transposed contraction). The running max and
+  sum are [1, TQ] ROWS: their reductions fold vregs elementwise down the
+  sublane axis (one 8-to-1 fold per 128 queries) where a [TQ, 1] column
+  folds 128 lanes per 8 rows through the cross-lane unit, and they
+  broadcast back along sublanes. The value product is acc^T [dv, TQ] +=
+  v^T_tile @ p^T: the p^T tile is the MXU's STATIONARY operand (its
+  weights) and the value head pushes only its dv rows against each weight
+  tile, where `acc += p v` pushed all TQ rows of p against weight tiles
+  that used dv of their 128 columns. Query columns are independent, so a
+  wide q-tile and several folds per loop iteration let one fold's products
+  overlap another's exp (FWD_BLOCK_Q / FWD_BLOCK_K / FWD_UNROLL below).
+- Lane-dense along S: the v^T resident and the outputs o^T [B, dv, S] f32
+  and lse [B, 1, S] (a [B, S, 32] f32 and a [B, S, 1] array lane-pad 4x and
+  128x in HBM). Lane-padded: the k resident [S, dk]. The q-tile rides the
+  LANE axis, so on the chip TQ and TK are multiples of 128 (or the whole
+  sequence); every power-of-two S gives such tiles.
+- Precision policy, shared with ops/attention.py::full_attention: matmul
+  operands in the input dtype (bf16 on the MXU's fast path), scores, exp,
+  max, sum and accumulator f32 (`preferred_element_type`), `scale` applied
+  in f32 to the scores, p^T cast to the operand dtype for the value product.
 - The backward is ONE kernel, `flash_dq_dkv`: each [TQ, TK] score tile is
   rebuilt once (s, p = exp(s - lse), dp, ds) and feeds all three gradient
   products. Grid (B, S/TK): the batch axis is parallel, the k-tile axis is
@@ -27,15 +46,19 @@ Layout notes (TPU):
   over q-tiles (BWD_BLOCK_Q = 1024) with dK^T / dV^T carried as values.
 - Every product over the tile is a PLAIN matmul: dQ = ds @ k, and dK^T =
   q^T @ ds, dV^T = do^T @ p with q^T / do^T handed in already transposed
-  ([B, d, S], sequence on the lane axis — `_bwd_stats` builds them once).
-  Contracting over the tile's leading axis instead (ds^T @ q) sends the
-  whole [TQ, TK] tile through the transpose unit: 38 ms an instance where
-  this form takes 23 (v5e, batch 256, S 4096, d 8/32; PERF.md section 6).
-  dK^T / dV^T leave the kernel as [B, d, S] and XLA transposes them back.
+  ([B, d, S], sequence on the lane axis). Contracting over the tile's
+  leading axis instead (ds^T @ q) sends the whole [TQ, TK] tile through
+  the transpose unit: 38 ms an instance where this form takes 23 (v5e,
+  batch 256, S 4096, d 8/32; PERF.md section 6). dK^T / dV^T leave the
+  kernel as [B, d, S] and XLA transposes them back.
 - Residents per batch row: q^T, do^T, lse, delta — all lane-dense along S,
   so none pads; the dQ accumulator and its output block are the only
   [S, d] (lane-padded) residents, which keeps S = 65536 compiling at
-  batch > 1 like the two-kernel backward it replaces.
+  batch > 1 like the two-kernel backward it replaced.
+- The hand-off: the forward's q^T, o^T and lse [B, 1, S] are saved as
+  residuals in the layouts the backward takes, so `_bwd_inputs` transposes
+  only the cotangent and forms delta from g^T and o^T. The [B, S, d] <->
+  [B, d, S] swaps at the edges of `flash_attention` are XLA's.
 - Head widths that have RUN on the v5e: q/k 8 with v 32 (SAGAN: d_qk = C/8,
   d_v = C/2; they ride the lane axis zero-padded, which wastes lanes but not
   HBM), 64/64 (tools/bench_attention.py), and since PR 27 q/k 192 with v 128
@@ -46,11 +69,11 @@ Layout notes (TPU):
   forward's k-loop ends at the q-tile's diagonal, the backward's q-loop
   starts at the k-tile's, and only the tiles the diagonal crosses build a
   mask. Tiles above the diagonal are skipped, not masked after the fact.
-  The non-causal call traces the same kernel bodies it always did.
+  The non-causal call traces kernel bodies with no mask in them.
 - Off-TPU the kernels run under `interpret=True`, so the CPU test mesh
-  exercises the identical code path (tests/test_flash_backward.py and
-  tests/test_pallas_attention.py assert exactness against
-  ops/attention.py::full_attention, gradients included).
+  exercises the identical code path (tests/test_flash_forward.py,
+  tests/test_flash_backward.py and tests/test_pallas_attention.py assert
+  exactness against ops/attention.py::full_attention, gradients included).
 
 Composition: `ops/attention.py::attn_apply(use_pallas=True)` routes its dense
 path here (single chip, or per-shard under the shard_map backend — pallas_call
@@ -65,6 +88,7 @@ are themselves long.
 from __future__ import annotations
 
 import functools
+import math
 import os
 
 import jax
@@ -73,21 +97,38 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Tile sizes. The per-tile softmax state update is loop-carried, so tile
-# COUNT — not matmul rate — dominates at the head dims this model uses;
-# large k-tiles amortize that serialization. (256, 1024) won a tile sweep on
-# the previous machine against the naive MXU-edge 128/128 (timings not
-# measured on the current one; DESIGN.md §8). Overridable via
-# the DCGAN_FLASH_TQ / DCGAN_FLASH_TK env vars — read at TRACE time, and
-# the resolved tiles are baked into the jit-compiled program (they are not
-# part of the jit cache key), so set them before the first call for a given
-# shape; sweeps use a fresh process per grid point (bench_attention.py).
-BLOCK_Q = 256
-BLOCK_K = 1024
+# Tile sizes, all chip-swept on the v5e (PERF.md section 6; CHANGES.md PR 28
+# has the tables). Overridable via the DCGAN_FLASH_TQ / DCGAN_FLASH_TK env
+# vars — read at TRACE time, and the resolved tiles are baked into the
+# jit-compiled program (they are not part of the jit cache key), so set them
+# before the first call for a given shape (tools/bench_attention.py makes a
+# new jitted function per grid point).
+#
+# The forward: its q-tile rides the LANE axis, and every query column of a
+# score tile is independent of the others, so a WIDE q-tile gives the
+# scheduler independent work to put beside each fold's serial chain (max,
+# exp, sum, value product) and amortizes the k/v tile loads; a SHORT k-tile
+# keeps one fold's tile small, and FWD_UNROLL folds share one loop iteration
+# (one basic block), so one fold's s^T product overlaps the exp and the
+# value product of the fold before it. 2048 / 256 won at every shape swept
+# (S 1,024-16,384 at 8/32 and 64/64; 192/128 at 8,192 causal or not);
+# 512 / 1024 with one fold an iteration is 35 % slower at 8/32, a q-tile of
+# 256 (the width the row form this kernel replaced had) 70 %. Folds an
+# iteration, sagan128's step at batch 256: 1 -> 1,161.5 images/s, 2 ->
+# 1,179.1, 4 -> 1,200.2, 8 -> 1,206.6; but the kernel is traced once per
+# call site with every fold written out, and at 8 that costs 1.3 s of every
+# start (4 % of a warm set-up) where 4 costs nothing over the row form.
+FWD_BLOCK_Q = 2048
+FWD_BLOCK_K = 256
+FWD_UNROLL = 4
 # The backward's q-tile: it carries no softmax state from tile to tile, so a
 # taller tile only amortizes the per-iteration relayouts (lse/delta to
 # columns, the q^T/do^T tiles back to rows). 1024 beat 256 and 512 at every
 # shape swept on the v5e (S 1024-65536, d 8/32 and 64/64; PERF.md section 6).
+# Its k-tile (one grid step) is BLOCK_K. BLOCK_Q is only `_blocks`' default:
+# both kernels pass their own q-tile.
+BLOCK_Q = 256
+BLOCK_K = 1024
 BWD_BLOCK_Q = 1024
 
 # Measurement generation: bump on ANY change that alters attention-kernel
@@ -98,7 +139,9 @@ BWD_BLOCK_Q = 1024
 # different kernel code. Gen 2 = bf16-operand policy + (256, 1024) tiles +
 # lane-major backward stats. Gen 3 = one backward kernel (flash_dq_dkv).
 # Gen 4 = the static `causal` argument (non-causal programs unchanged).
-ATTN_GEN = 4
+# Gen 5 = the forward in the transposed domain (s^T = k q^T, o^T and lse out
+# lane-dense, residuals handed to the backward as they are), every shape.
+ATTN_GEN = 5
 
 _NEG_INF = -1e30  # finite stand-in for -inf: keeps exp()/max() NaN-free
 
@@ -143,12 +186,122 @@ def _tile(s: int, which: str, default: int) -> int:
     return s
 
 
-def _blocks(s: int, block_q: int = BLOCK_Q) -> tuple:
-    return _tile(s, "TQ", block_q), _tile(s, "TK", BLOCK_K)
+def _blocks(s: int, block_q: int = BLOCK_Q, block_k: int = BLOCK_K) -> tuple:
+    return _tile(s, "TQ", block_q), _tile(s, "TK", block_k)
 
 
 # ---------------------------------------------------------------------------
 # forward
+# ---------------------------------------------------------------------------
+
+def _fwd_kernel(qT_ref, k_ref, vT_ref, oT_ref, lse_ref, *, scale, tk, unroll,
+                causal=False):
+    # Precision policy (shared with ops/attention.py::full_attention):
+    # matmul operands stay in the INPUT dtype — bf16 rides the MXU fast
+    # path — while scores/stats/accumulator are f32 via
+    # preferred_element_type, `scale` is applied in f32 to the scores, and
+    # p^T is cast back to the operand dtype for the value product (the
+    # flash-attention recipe). f32 inputs take the exact f32 path unchanged.
+    qT = qT_ref[0]                                      # [dk, TQ]
+    mmdt = qT.dtype
+    tq = qT.shape[1]
+    dv = vT_ref.shape[1]
+    n_k = k_ref.shape[1] // tk
+    q0 = pl.program_id(1) * tq if causal else None
+
+    def fold(j, carry, masked):
+        m, l, acc = carry                               # [1,TQ] x2, [dv,TQ]
+        keys = pl.ds(pl.multiple_of(j * tk, tk), tk)
+        kb = k_ref[0, keys, :]                          # [TK, dk]
+        vTb = vT_ref[0, :, keys]                        # [dv, TK]
+        # keys on sublanes, queries on lanes
+        sT = jnp.dot(kb, qT, preferred_element_type=jnp.float32) * scale
+        if masked:
+            key = j * tk + lax.broadcasted_iota(jnp.int32, (tk, tq), 0)
+            query = q0 + lax.broadcasted_iota(jnp.int32, (tk, tq), 1)
+            sT = jnp.where(key <= query, sT, _NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(sT, axis=0, keepdims=True))
+        pT = jnp.exp(sT - m_new)                        # [TK, TQ]
+        corr = jnp.exp(m - m_new)
+        l = l * corr + jnp.sum(pT, axis=0, keepdims=True)
+        # the tile is the STATIONARY operand: dv rows pushed per weight tile
+        acc = acc * corr + jnp.dot(vTb, pT.astype(mmdt),
+                                   preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    def loop(lo, hi, carry, masked=False):
+        # `unroll` folds per iteration; lo and hi are whole multiples of it
+        def group(g, carry):
+            for u in range(unroll):
+                carry = fold(g * unroll + u, carry, masked)
+            return carry
+        return lax.fori_loop(lo // unroll, hi // unroll, group, carry)
+
+    carry = (jnp.full((1, tq), _NEG_INF, jnp.float32),
+             jnp.zeros((1, tq), jnp.float32),
+             jnp.zeros((dv, tq), jnp.float32))
+    if causal:
+        # k-tiles wholly on or below the diagonal of this q-tile, then the
+        # ones the diagonal crosses; the rest are never touched. Key 0 is in
+        # the first tile and no query precedes it, so every column's running
+        # max is a real score from the first tile on.
+        n_full = (q0 + 1) // tk
+        n_here = (q0 + tq + tk - 1) // tk
+        carry = loop(0, n_full, carry)
+        m, l, acc = loop(n_full, n_here, carry, masked=True)
+    else:
+        m, l, acc = loop(0, n_k, carry)
+    oT_ref[0] = (acc / l).astype(oT_ref.dtype)
+    # log-sum-exp per query — the single vector the backward needs to
+    # reconstruct p tiles without storing them; a lane-dense [1, TQ] row
+    lse_ref[0] = m + jnp.log(l)
+
+
+def _fwd_unroll(tq: int, tk: int) -> int:
+    """Folds per loop iteration: a divisor of TQ/TK, so that the whole
+    sequence's tile count and the causal loops' bounds (a q-tile's first and
+    last key tile) are all whole multiples of it; 1 where TK does not
+    divide TQ."""
+    return math.gcd(FWD_UNROLL, tq // tk) if tq % tk == 0 else 1
+
+
+def _fwd_core(qT, k, vT, scale, causal=False):
+    """The forward pallas_call: q^T [B, dk, S], k [B, S, dk], v^T [B, dv, S]
+    -> (o^T [B, dv, S] f32, lse [B, 1, S] f32); q^T, o^T and lse are the
+    layouts `_bwd_core` takes its residents in."""
+    B, dk, S = qT.shape
+    dv = vT.shape[1]
+    tq, tk = _blocks(S, FWD_BLOCK_Q, FWD_BLOCK_K)
+    kernel = functools.partial(_fwd_kernel, scale=scale, tk=tk,
+                               unroll=_fwd_unroll(tq, tk))
+    if causal:
+        kernel = functools.partial(kernel, causal=True)
+    return pl.pallas_call(
+        kernel,
+        name="flash_fwd",
+        grid=(B, S // tq),
+        in_specs=[pl.BlockSpec((1, dk, tq), lambda b, i: (b, 0, i)),
+                  pl.BlockSpec((1, S, dk), lambda b, i: (b, 0, 0)),
+                  pl.BlockSpec((1, dv, S), lambda b, i: (b, 0, 0))],
+        out_specs=(pl.BlockSpec((1, dv, tq), lambda b, i: (b, 0, i)),
+                   pl.BlockSpec((1, 1, tq), lambda b, i: (b, 0, i))),
+        out_shape=(jax.ShapeDtypeStruct((B, dv, S), jnp.float32),
+                   jax.ShapeDtypeStruct((B, 1, S), jnp.float32)),
+        compiler_params=_compiler_params("parallel"),
+        interpret=_interpret(),
+    )(qT, k, vT)
+
+
+def _fwd_impl(q, k, v, scale, causal=False):
+    """The forward over row-major [B, S, d] blocks, XLA's swaps at its
+    edges: (out [B, S, dv] f32, lse [B, 1, S])."""
+    outT, lse = _fwd_core(jnp.swapaxes(q, 1, 2), k, jnp.swapaxes(v, 1, 2),
+                          scale, causal)
+    return jnp.swapaxes(outT, 1, 2), lse
+
+
+# ---------------------------------------------------------------------------
+# backward
 # ---------------------------------------------------------------------------
 
 def _causal_keep(row0, col0, tq: int, tk: int):
@@ -158,88 +311,6 @@ def _causal_keep(row0, col0, tq: int, tk: int):
     cols = col0 + lax.broadcasted_iota(jnp.int32, (tq, tk), 1)
     return cols <= rows
 
-
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, tk,
-                causal=False):
-    # Precision policy (shared with ops/attention.py::full_attention):
-    # matmul operands stay in the INPUT dtype — bf16 rides the MXU fast
-    # path — while scores/stats/accumulator are f32 via
-    # preferred_element_type; p is cast back to the operand dtype for the
-    # PV matmul (the flash-attention recipe). f32 inputs take the exact
-    # f32 path unchanged.
-    q = q_ref[0]                                        # [TQ, d]
-    mmdt = q.dtype
-    tq = q.shape[0]
-    dv = v_ref.shape[-1]
-    n_k = k_ref.shape[1] // tk
-    row0 = pl.program_id(1) * tq if causal else None
-
-    def body(j, carry, masked=False):
-        m, l, acc = carry
-        kb = k_ref[0, pl.ds(j * tk, tk), :]
-        vb = v_ref[0, pl.ds(j * tk, tk), :]
-        s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if masked:
-            s = jnp.where(_causal_keep(row0, j * tk, tq, tk), s, _NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m - m_new)
-        l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * corr + jnp.dot(p.astype(mmdt), vb,
-                                   preferred_element_type=jnp.float32)
-        return m_new, l, acc
-
-    m0 = jnp.full((tq, 1), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((tq, 1), jnp.float32)
-    acc0 = jnp.zeros((tq, dv), jnp.float32)
-    if causal:
-        # k-tiles wholly on or below the diagonal of this q-tile, then the
-        # ones the diagonal crosses; the rest are never touched. Key 0 is in
-        # the first tile and no query precedes it, so every row's running
-        # max is a real score from the first tile on.
-        n_full = (row0 + 1) // tk
-        n_here = (row0 + tq + tk - 1) // tk
-        carry = lax.fori_loop(0, n_full, body, (m0, l0, acc0))
-        m, l, acc = lax.fori_loop(
-            n_full, n_here, functools.partial(body, masked=True), carry)
-    else:
-        m, l, acc = lax.fori_loop(0, n_k, body, (m0, l0, acc0))
-    o_ref[0] = (acc / l).astype(o_ref.dtype)
-    # log-sum-exp per row — the single vector the backward needs to
-    # reconstruct p tiles without storing them. Kept [S, 1] (not [S]):
-    # Mosaic requires block last-two dims (8, 128)-divisible or full, which
-    # a trailing singleton satisfies and a flat [B, S] block cannot.
-    lse_ref[0] = m + jnp.log(l)
-
-
-def _fwd_impl(q, k, v, scale, causal=False):
-    B, S, dk = q.shape
-    dv = v.shape[-1]
-    tq, tk = _blocks(S)
-    kernel = functools.partial(_fwd_kernel, scale=scale, tk=tk)
-    if causal:
-        kernel = functools.partial(kernel, causal=True)
-    out, lse = pl.pallas_call(
-        kernel,
-        name="flash_fwd",
-        grid=(B, S // tq),
-        in_specs=[pl.BlockSpec((1, tq, dk), lambda b, i: (b, i, 0)),
-                  pl.BlockSpec((1, S, dk), lambda b, i: (b, 0, 0)),
-                  pl.BlockSpec((1, S, dv), lambda b, i: (b, 0, 0))],
-        out_specs=(pl.BlockSpec((1, tq, dv), lambda b, i: (b, i, 0)),
-                   pl.BlockSpec((1, tq, 1), lambda b, i: (b, i, 0))),
-        out_shape=(jax.ShapeDtypeStruct((B, S, dv), jnp.float32),
-                   jax.ShapeDtypeStruct((B, S, 1), jnp.float32)),
-        compiler_params=_compiler_params("parallel"),
-        interpret=_interpret(),
-    )(q, k, v)
-    return out, lse
-
-
-# ---------------------------------------------------------------------------
-# backward
-# ---------------------------------------------------------------------------
 
 def _dq_dkv_kernel(k_ref, v_ref, qT_ref, doT_ref, lse_ref, delta_ref,
                    dq_ref, dkT_ref, dvT_ref, dq_acc, *, scale, tq,
@@ -304,28 +375,37 @@ def _dq_dkv_kernel(k_ref, v_ref, qT_ref, doT_ref, lse_ref, delta_ref,
         dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
-def _bwd_stats(q, out, lse, g):
+def _bwd_inputs(qT, outT, lse, g):
     """The hop-invariant backward inputs, computed once per backward pass
     (the ring backward reuses them across every hop), all with the sequence
     on the LANE axis — the kernel holds them full-sequence, and a [S, d]
-    block lane-pads up to 128x (8 MiB at S=16384 where 64 KiB is the data):
+    block lane-pads up to 128x (8 MiB at S=16384 where 64 KiB is the data).
+    The forward left q^T [B, dk, S], o^T [B, dv, S] and lse [B, 1, S] that
+    way already; what is made here:
 
-    - qT [B, dk, S]: q transposed, so dK^T = q^T @ ds is a plain matmul.
-    - doT [B, dv, S]: the f32 cotangent cast to the matmul operand dtype
-      ONCE — under bf16 it halves its HBM traffic and VMEM residency — and
-      transposed like q.
-    - lse, and delta_i = rowsum(dO_i * O_i), the softmax-jacobian correction
-      term (one fused elementwise reduction, XLA handles it): [B, 1, S].
+    - doT [B, dv, S]: the f32 cotangent g [B, S, dv] transposed, and cast to
+      the matmul operand dtype ONCE — under bf16 it halves its HBM traffic
+      and VMEM residency.
+    - delta_i = sum_d(dO_i * O_i), the softmax-jacobian correction term (one
+      fused elementwise reduction over g^T and o^T, XLA handles it):
+      [B, 1, S].
     """
-    B, S, _ = q.shape
-    delta = jnp.sum(g.astype(jnp.float32) * out, axis=-1)
-    return (jnp.swapaxes(q, 1, 2), jnp.swapaxes(g.astype(q.dtype), 1, 2),
-            lse.reshape(B, 1, S), delta.reshape(B, 1, S))
+    gT = jnp.swapaxes(g, 1, 2).astype(jnp.float32)
+    delta = jnp.sum(gT * outT, axis=1, keepdims=True)
+    return qT, gT.astype(qT.dtype), lse, delta
+
+
+def _bwd_stats(q, out, lse, g):
+    """`_bwd_inputs` for a caller that holds `_fwd_impl`'s row-major q and
+    out (tests/test_flash_backward.py pins the ring's float32 contract
+    through the pair); the VJPs below keep q^T and o^T and skip the swaps."""
+    return _bwd_inputs(jnp.swapaxes(q, 1, 2), jnp.swapaxes(out, 1, 2), lse, g)
 
 
 def _bwd_impl(scale, causal, res, g):
-    q, k, v, out, lse = res
-    return _bwd_core(scale, k, v, *_bwd_stats(q, out, lse, g), causal=causal)
+    qT, k, v, outT, lse = res
+    return _bwd_core(scale, k, v, *_bwd_inputs(qT, outT, lse, g),
+                     causal=causal)
 
 
 def _bwd_core(scale, k, v, qT, doT, lse, delta, grad_dtype=None,
@@ -377,8 +457,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
 
 def _flash_vjp_fwd(q, k, v, scale, causal):
-    out, lse = _fwd_impl(q, k, v, scale, causal)
-    return out, (q, k, v, out, lse)
+    # the [B, S, d] <-> [B, d, S] swaps at the edges are XLA's; the
+    # residuals stay in the kernels' layout
+    qT = jnp.swapaxes(q, 1, 2)
+    outT, lse = _fwd_core(qT, k, jnp.swapaxes(v, 1, 2), scale, causal)
+    return jnp.swapaxes(outT, 1, 2), (qT, k, v, outT, lse)
 
 
 flash_attention.defvjp(_flash_vjp_fwd, _bwd_impl)
@@ -399,7 +482,7 @@ def ring_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
     Same contract as ops/attention.py::ring_attention (q/k/v [B, S_local, d]
     per device, n_shards-1 ppermute hops, f32 result), but the hop fold is
-    `_fwd_impl` — each block contributes a normalized partial (out_b, lse_b)
+    `_fwd_core` — each block contributes a normalized partial (out_b, lse_b)
     and partials merge associatively: lse = logaddexp(lse_a, lse_b),
     out = out_a*exp(lse_a-lse) + out_b*exp(lse_b-lse). The backward
     re-rotates (k, v) around the ring and reuses `_bwd_core` per hop with
@@ -414,43 +497,43 @@ def ring_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _ring_flash(q, k, v, scale, axis_name, n_shards):
-    out, _ = _ring_flash_fwd_pass(q, k, v, scale, axis_name, n_shards)
+    out, _ = _ring_flash_vjp_fwd(q, k, v, scale, axis_name, n_shards)
     return out
 
 
-def _ring_flash_fwd_pass(q, k, v, scale, axis_name, n_shards):
+def _ring_flash_vjp_fwd(q, k, v, scale, axis_name, n_shards):
     fwd = [(i, (i + 1) % n_shards) for i in range(n_shards)]
-    # resident block first (no hop result is discarded), then n-1 rotations
-    out, lse = _fwd_impl(q, k, v, scale)
+    # q^T, the rotating v^T block and the partials stay in the kernel's
+    # layout across the hops ([B, dv, S] partials merge with [B, 1, S]
+    # weights by plain broadcasting); one swap back at the end. Resident
+    # block first (no hop result is discarded), then n-1 rotations.
+    qT = jnp.swapaxes(q, 1, 2)
+    vT = jnp.swapaxes(v, 1, 2)
+    outT, lse = _fwd_core(qT, k, vT, scale)
 
     def hop(carry, _):
-        k_blk, v_blk, out, lse = carry
+        k_blk, vT_blk, outT, lse = carry
         k_blk = lax.ppermute(k_blk, axis_name, perm=fwd)
-        v_blk = lax.ppermute(v_blk, axis_name, perm=fwd)
-        out_b, lse_b = _fwd_impl(q, k_blk, v_blk, scale)
+        vT_blk = lax.ppermute(vT_blk, axis_name, perm=fwd)
+        outT_b, lse_b = _fwd_core(qT, k_blk, vT_blk, scale)
         lse_new = jnp.logaddexp(lse, lse_b)
-        out = (out * jnp.exp(lse - lse_new)
-               + out_b * jnp.exp(lse_b - lse_new))
-        return (k_blk, v_blk, out, lse_new), None
+        outT = (outT * jnp.exp(lse - lse_new)
+                + outT_b * jnp.exp(lse_b - lse_new))
+        return (k_blk, vT_blk, outT, lse_new), None
 
-    (_, _, out, lse), _ = lax.scan(
-        hop, (k, v, out, lse), None, length=n_shards - 1)
-    return out, lse
-
-
-def _ring_flash_vjp_fwd(q, k, v, scale, axis_name, n_shards):
-    out, lse = _ring_flash_fwd_pass(q, k, v, scale, axis_name, n_shards)
-    return out, (q, k, v, out, lse)
+    (_, _, outT, lse), _ = lax.scan(
+        hop, (k, vT, outT, lse), None, length=n_shards - 1)
+    return jnp.swapaxes(outT, 1, 2), (qT, k, v, outT, lse)
 
 
 def _ring_flash_vjp_bwd(scale, axis_name, n_shards, res, g):
-    q, k, v, out, lse = res
+    qT, k, v, outT, lse = res
     fwd = [(i, (i + 1) % n_shards) for i in range(n_shards)]
 
-    # hop-invariant backward inputs computed ONCE (q^T, the operand-dtype
-    # cotangent transposed, lse and delta lane-major) — only the backward
-    # kernel re-runs per hop
-    stats = _bwd_stats(q, out, lse, g)
+    # hop-invariant backward inputs computed ONCE (the operand-dtype
+    # cotangent transposed, delta) — only the backward kernel re-runs per
+    # hop
+    stats = _bwd_inputs(qT, outT, lse, g)
 
     def hop(carry, _):
         # (k, v) and their accumulated gradients travel TOGETHER: each
@@ -474,10 +557,10 @@ def _ring_flash_vjp_bwd(scale, axis_name, n_shards, res, g):
     zeros = (jnp.zeros(k.shape, jnp.float32),
              jnp.zeros(v.shape, jnp.float32))
     (_, _, dk_c, dv_c, dq), _ = lax.scan(
-        hop, (k, v) + zeros + (jnp.zeros(q.shape, jnp.float32),),
+        hop, (k, v) + zeros + (jnp.zeros(k.shape, jnp.float32),),
         None, length=n_shards)
     # after n rotations the blocks (and their grads) are home again
-    return (dq.astype(q.dtype), dk_c.astype(k.dtype),
+    return (dq.astype(qT.dtype), dk_c.astype(k.dtype),
             dv_c.astype(v.dtype))
 
 

@@ -155,6 +155,39 @@ def test_flash_attention_fwd_and_vjp(v5e, seq):
 
 
 @pytest.mark.usefixtures("compiled_kernels")
+@pytest.mark.parametrize("d, dv", [(8, 32), (64, 64)])
+def test_flash_attention_at_65536_tokens(v5e, d, dv):
+    """The longest sequence the narrow widths compile at (PERF.md section
+    7): every whole-sequence resident of the forward (v^T) and of the
+    backward (q^T, do^T, lse, delta) is lane-dense along S; the forward's
+    k [S, d] and the backward's dQ accumulator are the lane-padded ones."""
+    def loss(q, k, v):
+        return jnp.sum(pallas_attention.flash_attention(q, k, v, d ** -0.5))
+
+    qk = _sds((2, 65536, d), jnp.bfloat16, v5e)
+    text = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), qk, qk,
+                    _sds((2, 65536, dv), jnp.bfloat16, v5e))
+    assert "flash_fwd" in text and "flash_dq_dkv" in text
+
+
+@pytest.mark.usefixtures("compiled_kernels")
+@pytest.mark.parametrize("seq, causal", [(4096, False), (16384, False),
+                                         (8192, True)])
+def test_flash_forward_alone(v5e, seq, causal):
+    """The forward call by itself, as the sampler and the ring's hops make
+    it: q^T / v^T in, o^T float32 and lse [B, 1, S] out, causal or not, at
+    the shipped tiles (q-tile 2048 on the lane axis, 4 folds of 256 keys
+    per loop iteration)."""
+    text = _compile(
+        lambda qT, k, vT: pallas_attention._fwd_core(qT, k, vT, 8 ** -0.5,
+                                                     causal),
+        _sds((BATCH, 8, seq), jnp.bfloat16, v5e),
+        _sds((BATCH, seq, 8), jnp.bfloat16, v5e),
+        _sds((BATCH, 32, seq), jnp.bfloat16, v5e))
+    assert "flash_fwd" in text
+
+
+@pytest.mark.usefixtures("compiled_kernels")
 @pytest.mark.parametrize("heads, seq", [(32, 8192), (4, 16384)])
 def test_causal_flash_attention_at_latent_attention_widths(v5e, heads, seq):
     """The token trunk's attention (models/mla_moe.py): q/k 192 wide (more

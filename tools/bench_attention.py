@@ -12,6 +12,14 @@ sequence-parallel strategies. CPU runs are for smoke only.
     python tools/bench_attention.py --mesh 4 --heads 4   # + ring/ulysses
     JAX_PLATFORMS=cpu python tools/bench_attention.py --seq 256 --steps 2
     python tools/bench_attention.py --causal --heads 32 --d 192 --dv 128 --seq 8192
+    # the flash kernels alone, forward by itself and with the backward, over
+    # a grid of the forward's tiles and folds per loop iteration (the sweep
+    # behind FWD_BLOCK_Q / FWD_BLOCK_K / FWD_UNROLL of
+    # ops/pallas_attention.py):
+    python tools/bench_attention.py --forms flash --passes fwd fwd_bwd \
+        --batch 256 --d 8 --dv 32 --seq 4096
+    python tools/bench_attention.py --forms flash --passes fwd --batch 256 \
+        --d 8 --dv 32 --seq 4096 --tq 1024 2048 --tk 256 512 --unroll 4 8
 
 Prints one JSON line per (form, S): {"form", "seq", "ms", "heads", ...};
 forms that fail to compile/allocate report {"error": ...} instead of dying,
@@ -44,7 +52,26 @@ def main() -> None:
     p.add_argument("--mesh", type=int, default=0,
                    help=">1: also run ring/ulysses over this many devices "
                         "(sequence axis)")
-    p.add_argument("--forward_only", action="store_true")
+    p.add_argument("--forward_only", action="store_true",
+                   help="same as --passes fwd")
+    p.add_argument("--passes", nargs="+", choices=["fwd", "fwd_bwd"],
+                   default=["fwd_bwd"],
+                   help="time the forward alone, forward + backward, or "
+                        "both in turn")
+    p.add_argument("--forms", nargs="+", default=None,
+                   help="time only these forms (e.g. --forms flash: a "
+                        "kernel sweep has no use for the dense wall)")
+    p.add_argument("--unroll", type=int, nargs="+", default=[None],
+                   help="folds per loop iteration of the flash forward to "
+                        "sweep: sets pallas_attention.FWD_UNROLL for this "
+                        "process, to measure the constant itself; default: "
+                        "the module's")
+    p.add_argument("--tq", type=int, nargs="+", default=[None],
+                   help="q-tile targets to sweep (DCGAN_FLASH_TQ, read when "
+                        "each timed function is traced, by the forward AND "
+                        "the backward: sweep the forward's with --passes "
+                        "fwd); default: the module's constants")
+    p.add_argument("--tk", type=int, nargs="+", default=[None])
     p.add_argument("--causal", action="store_true",
                    help="causal attention: the flash kernels' lower-"
                         "triangle path against dense masked attention "
@@ -67,6 +94,7 @@ def main() -> None:
         ring_attention,
         ulysses_attention,
     )
+    from dcgan_tpu.ops import pallas_attention
     from dcgan_tpu.ops.pallas_attention import ATTN_GEN, flash_attention
 
     scale = args.d ** -0.5
@@ -141,46 +169,65 @@ def main() -> None:
                 return out
             forms["ulysses"] = uly
 
-    for S in args.seq:
-        q, k, v = make_qkv(S, jax.random.key(0))
-        for name, fn in forms.items():
-            if args.forward_only:
-                step = jax.jit(fn)
-            else:
-                # all three grads: argnums=0 alone would let XLA DCE the
-                # dk/dv matmuls out of the dense backward while the flash
-                # custom VJP always computes them — an unfair comparison
-                step = jax.jit(jax.grad(
-                    lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32)),
-                    argnums=(0, 1, 2)))
+    if args.forms:
+        unknown = [f for f in args.forms if f not in forms]
+        if unknown:
+            sys.exit(f"unknown --forms {unknown}; have {sorted(forms)}")
+        forms = {name: forms[name] for name in args.forms}
+    passes = ["fwd"] if args.forward_only else args.passes
 
-            def sync(out):
-                float(jnp.sum(jax.tree_util.tree_leaves(out)[0]
-                              .astype(jnp.float32)))
+    def sync(out):
+        float(jnp.sum(jax.tree_util.tree_leaves(out)[0].astype(jnp.float32)))
 
-            try:
-                sync(step(q, k, v))  # compile + warm
-                # best of 3 windows — same methodology as bench.py /
-                # bench_loader.py
-                dt = float("inf")
-                for _ in range(3):
-                    t0 = time.perf_counter()
-                    for _ in range(args.steps):
-                        out = step(q, k, v)
-                    sync(out)
-                    dt = min(dt, time.perf_counter() - t0)
-                ms = dt / args.steps * 1e3
-                print(json.dumps({"form": name, "seq": S,
-                                  "ms": round(ms, 2), "heads": h,
-                                  "batch": args.batch,
-                                  "backward": not args.forward_only,
-                                  "causal": args.causal,
-                                  "gen": ATTN_GEN}))
-            except Exception as e:  # the dense wall is the measurement
-                print(json.dumps({"form": name, "seq": S,
-                                  "error": f"{type(e).__name__}: "
-                                           f"{str(e)[:160]}",
-                                  "heads": h, "gen": ATTN_GEN}))
+    def timed(fn, which, q, k, v):
+        if which == "fwd":
+            # a new function object each time: jit's cache is keyed by it
+            step = jax.jit(lambda q, k, v: fn(q, k, v))
+        else:
+            # all three grads: argnums=0 alone would let XLA DCE the
+            # dk/dv matmuls out of the dense backward while the flash
+            # custom VJP always computes them — an unfair comparison
+            step = jax.jit(jax.grad(
+                lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32)),
+                argnums=(0, 1, 2)))
+        sync(step(q, k, v))  # compile + warm
+        # best of 3 windows — same methodology as bench.py /
+        # bench_loader.py
+        dt = float("inf")
+        for _ in range(3):
+            t0 = time.perf_counter()
+            for _ in range(args.steps):
+                out = step(q, k, v)
+            sync(out)
+            dt = min(dt, time.perf_counter() - t0)
+        return dt / args.steps * 1e3
+
+    # one timed function per grid point: a new jit traces the kernels anew,
+    # so the tile targets and the unroll are read as set here
+    one = [None]
+    grid = [(S, name, which, tq, tk, unroll)
+            for S in args.seq for name in forms for which in passes
+            for tq in (args.tq if name == "flash" else one)
+            for tk in (args.tk if name == "flash" else one)
+            for unroll in (args.unroll if name == "flash" else one)]
+    qkv, qkv_seq = None, None
+    for S, name, which, tq, tk, unroll in grid:
+        if S != qkv_seq:
+            qkv, qkv_seq = make_qkv(S, jax.random.key(0)), S
+        row = {"form": name, "seq": S, "heads": h, "batch": args.batch,
+               "d": args.d, "dv": args.dv, "backward": which == "fwd_bwd",
+               "causal": args.causal, "gen": ATTN_GEN}
+        for key, val in (("DCGAN_FLASH_TQ", tq), ("DCGAN_FLASH_TK", tk)):
+            if val is not None:
+                os.environ[key] = str(val)
+                row[key[-2:].lower()] = val
+        if unroll is not None:
+            pallas_attention.FWD_UNROLL = row["unroll"] = unroll
+        try:
+            row["ms"] = round(timed(forms[name], which, *qkv), 3)
+        except Exception as e:  # the dense wall is the measurement
+            row["error"] = f"{type(e).__name__}: {str(e)[:160]}"
+        print(json.dumps(row), flush=True)
 
 
 if __name__ == "__main__":
