@@ -21,8 +21,8 @@ the process that was started, so nothing else dials the chip first.
 Output contract (the driver parses the LAST stdout line): the headline
 row {"metric", "value", "unit", "vs_baseline"} is always the FINAL JSON
 line on stdout. Every A/B knob — PIPELINE_GD=1 (_bench_pipeline_ab),
-ZERO_STAGE={2,3} (_bench_zero_ab), PROGRESSIVE=1, PRECISION /
-PALLAS_FUSED (_bench_precision_ab), COMM_OVERLAP=1
+ZERO_STAGE={2,3} (_bench_zero_ab), PROGRESSIVE=1, PRECISION
+(_bench_precision_ab), COMM_OVERLAP=1
 (_bench_comm_overlap_ab) — prints its extra row(s) BEFORE the headline
 row, and all non-row context goes to stderr, so adding a knob can never
 break the last-line parse. tests/test_comm_overlap.py pins the row
@@ -302,20 +302,15 @@ def _bench_comm_overlap_ab(cfg, mesh, n_chips: int, images, base) -> None:
 
 
 def _bench_precision_ab(cfg, mesh, n_chips: int, images, base) -> None:
-    """PRECISION={bf16,fp8} / PALLAS_FUSED=1: the fused-kernel +
-    reduced-precision A/B row (ISSUE 17).
+    """PRECISION=bf16: the reduced-precision A/B row (ISSUE 17).
 
-    Measures the SAME workload per-step against an explicit f32-unfused
-    control arm (precision="f32" forces f32 params+compute even when the
-    headline config computes in bf16), plus one arm per armed knob —
-    @pallas_fused (fused conv⊕BN⊕act Pallas GEMM blocks), @<precision>
-    (the reduced-precision policy), and their composition when both are
-    set. Every arm reports ms_per_step + images_per_sec_chip +
-    peak_state_mib (bf16 params halve the resident param/nu bytes; mu
-    stays f32 master). The acceptance contract rides on
-    `ms_f32_over_best`: the best knobbed arm strictly faster than the
-    f32-unfused control at >=128px. Printed BEFORE the headline row so
-    the driver's last-line parse is unchanged.
+    Measures the SAME workload per-step against an explicit f32 control
+    arm (precision="f32" forces f32 params+compute even when the headline
+    config computes in bf16) and the @<precision> arm. Every arm reports
+    ms_per_step + images_per_sec_chip + peak_state_mib (bf16 params halve
+    the resident param/nu bytes; mu stays f32 master). `ms_f32_over_best`
+    is the control's ms_per_step over the policy arm's. Printed BEFORE the
+    headline row so the driver's last-line parse is unchanged.
     """
     import dataclasses
 
@@ -323,34 +318,14 @@ def _bench_precision_ab(cfg, mesh, n_chips: int, images, base) -> None:
 
     from dcgan_tpu.parallel import make_parallel_train
 
-    precision = os.environ.get("PRECISION", "")
-    fused = os.environ.get("PALLAS_FUSED") == "1"
-    if fused and (cfg.model.arch != "dcgan" or cfg.model.num_classes):
-        print("PALLAS_FUSED=1 skipped: fused blocks are plain-DCGAN "
-              "batch-norm only", file=sys.stderr)
-        fused = False
-    if not (precision or fused):
-        return
+    precision = os.environ["PRECISION"]
     steps = max(1, int(os.environ.get("BENCH_PRECISION_STEPS",
                                       min(STEPS_MEASURE, 60))))
     windows = int(os.environ.get("BENCH_WINDOWS", 3))
 
-    def _variant(prec, fuse):
-        m = cfg.model
-        if fuse:
-            m = dataclasses.replace(m, use_pallas=True, pallas_fused=True)
-        return dataclasses.replace(cfg, model=m, precision=prec)
-
-    arm_cfgs = [("f32", _variant("f32", False))]
-    if fused:
-        arm_cfgs.append(("pallas_fused", _variant("f32", True)))
-    if precision:
-        arm_cfgs.append((precision, _variant(precision, False)))
-    if precision and fused:
-        arm_cfgs.append((f"{precision}+fused", _variant(precision, True)))
-
     arms = {}
-    for tag, cfg_a in arm_cfgs:
+    for tag in ("f32", precision):
+        cfg_a = dataclasses.replace(cfg, precision=tag)
         pt_a = make_parallel_train(cfg_a, mesh)
         st = pt_a.init(jax.random.key(0))
         peak_state = _state_mib_per_chip(st)
@@ -372,20 +347,18 @@ def _bench_precision_ab(cfg, mesh, n_chips: int, images, base) -> None:
         del st  # free the arm's state before the next arm compiles
     arch = os.environ.get("BENCH_PRESET", "") or (
         f"DCGAN-{cfg.model.output_size}")
-    best_tag = min((t for t in arms if t != "f32"),
-                   key=lambda t: arms[t]["ms_per_step"])
-    f32, best = arms["f32"], arms[best_tag]
+    f32, best = arms["f32"], arms[precision]
     print(json.dumps({
-        "metric": f"{arch} precision/fusion A/B (batch {BATCH}/chip, "
+        "metric": f"{arch} precision A/B (batch {BATCH}/chip, "
                   "per-step dispatch)",
         "value": best["images_per_sec_chip"],
         "unit": "images/sec/chip",
         "vs_baseline": round(best["images_per_sec_chip"]
                              / V100_TF_BASELINE_IMG_PER_SEC, 3),
         **arms,
-        "best_arm": best_tag,
+        "best_arm": precision,
         # the headline speed claim as one unitless number: control
-        # ms_per_step over the best knobbed arm's (>1 = knobs won)
+        # ms_per_step over the policy arm's (>1 = the policy won)
         "ms_f32_over_best": round(
             f32["ms_per_step"] / best["ms_per_step"], 4)
         if best["ms_per_step"] else None,
@@ -534,7 +507,7 @@ def _bench_pipeline_ab(cfg, pt, n_chips: int, images, base) -> None:
     GDPipeline buffer manager, so the benched dataflow is the shipped
     one) — and prints one extra BENCH-style row with both arms'
     ms_per_step + devstep_ms. Per-step FLOPs are conservation-equal
-    across the arms (tools/step_profile.py PIPELINE_GD=1 proves it), so
+    across the arms, so
     this row is the regression guard that the stage split's extra
     dispatches stay in the noise, not a speedup claim. Printed BEFORE
     the headline row so the driver's last-line parse is unchanged.
@@ -669,9 +642,8 @@ def main() -> None:
             # the preset (and stamp the preset's rev onto it)
             backend=os.environ.get("BENCH_BACKEND", base.backend))
     else:
-        # the BENCH_* model knobs (shared with tools/step_profile.py so a
-        # profile always decomposes exactly a benched config):
-        # dcgan_tpu/utils/bench_env.py documents each
+        # the BENCH_* model knobs: dcgan_tpu/utils/bench_env.py
+        # documents each
         from dcgan_tpu.utils.bench_env import bench_model_config
 
         mcfg, _ = bench_model_config()
@@ -814,8 +786,8 @@ def main() -> None:
         # --zero_stage ladder moves; derived from the live shardings
         "peak_state_mib": _state_mib_per_chip(state),
     }
-    if os.environ.get("PRECISION") or os.environ.get("PALLAS_FUSED") == "1":
-        # the fused-kernel / precision-ladder A/B row (ISSUE 17) — printed
+    if os.environ.get("PRECISION"):
+        # the precision-ladder A/B row (ISSUE 17) — printed
         # before the headline row so the driver's last-line parse holds
         _bench_precision_ab(cfg, mesh, n_chips, images, base)
     if os.environ.get("COMM_OVERLAP") == "1":

@@ -16,11 +16,10 @@ z=100, batch 64, bf16 compute; weights random from a seed):
            it, from that checkpoint: bucket ladder warmed, a few dozen
            requests answered, one of them held against
            `dcgan_tpu.generate`, clean drain
-  kernels  one train step per opt-in kernel family (Pallas BN, the fused
-           conv+BN+act stages, flash attention), each proven COMPILED from
-           the program text (`tpu_custom_call`) and held at the loss level
-           against the same step on the XLA path; the bf16 XLA step itself
-           against the float32 one
+  kernels  one `sagan64` train step on the flash attention kernels, proven
+           COMPILED from the program text (`tpu_custom_call`) and held at
+           the loss level against the same step with dense attention on
+           XLA; the bf16 XLA `celeba64` step against the float32 one
 
 `--chips 4` runs instead ONLY the data-parallel path and what it is compared
 with: `celeba64` at global batch 256 on a (data=4, model=1) mesh, on both
@@ -413,14 +412,6 @@ def phase_kernels(info: dict, *, dcgan, sagan, expect_kernels: bool) -> None:
     f32, _, _ = run(dataclasses.replace(dcgan, precision="f32"),
                     kernels=False)
     info["celeba64_bf16_vs_f32"] = _close(xla, f32, BF16_LOSS_RTOL)
-    for tag, cfg in (
-            ("use_pallas", _with_model(dcgan, use_pallas=True)),
-            ("pallas_fused", _with_model(dcgan, use_pallas=True,
-                                         pallas_fused=True))):
-        got, n_calls, _ = run(cfg, kernels=True)
-        info[f"celeba64_{tag}"] = {
-            "tpu_custom_calls": n_calls,
-            "vs_xla": _close(got, xla, BF16_LOSS_RTOL)}
     dense, _, _ = run(_with_model(sagan, use_pallas=False), kernels=False)
     flash, n_calls, _ = run(sagan, kernels=True)
     info["sagan64_flash"] = {"tpu_custom_calls": n_calls,

@@ -151,26 +151,20 @@ def _require_platform() -> None:
 
 
 def small_config(backend: str = "gspmd", pipeline: bool = False,
-                 zero: int = 1, precision: str = "",
-                 pallas_fused: bool = False, overlap: str = "off"):
+                 zero: int = 1, precision: str = "", overlap: str = "off"):
     """The small CPU preset every program is lowered at: tiny dcgan16
     model, global batch 8 over the 2-way data mesh, every optional
     program's knob armed (sampler / probe / summarize / rollback with LR
     backoff) so the warmup plan enumerates the full dispatch surface.
     `zero` selects the ZeRO stage (ISSUE 13) — the 2-way data mesh is
-    exactly the canonical topology stages >= 2 need. `precision` /
-    `pallas_fused` select the reduced-precision policy and the fused
-    Pallas conv(+BN+act) blocks (ISSUE 17); the fused kernels lower in
-    interpreter mode on CPU so the fingerprints are device-independent.
-    `overlap` selects the collective overlap plane (ISSUE 20) for the
+    exactly the canonical topology stages >= 2 need. `precision` selects
+    the reduced-precision policy (ISSUE 17). `overlap` selects the collective overlap plane (ISSUE 20) for the
     `@overlap`/`@prefetch` variant rows."""
     from dcgan_tpu.config import MeshConfig, ModelConfig, TrainConfig
 
     return TrainConfig(
         model=ModelConfig(output_size=16, gf_dim=8, df_dim=8,
-                          compute_dtype="float32",
-                          use_pallas=pallas_fused,
-                          pallas_fused=pallas_fused),
+                          compute_dtype="float32"),
         mesh=MeshConfig(data=CANONICAL_DEVICES, zero_stage=zero),
         batch_size=8,
         backend=backend,
@@ -578,55 +572,38 @@ def enumerate_audits() -> Tuple[List[ProgramAudit], List[CoverageRow]]:
                         expect_donation=_base(n) in DONATED_PROGRAMS,
                         cadence=cadence))
 
-        # Fused-kernel / reduced-precision variants (ISSUE 17): the
-        # @pallas_fused rows swap every interior conv/BN/act stack for
-        # the fused Pallas GEMM programs — the census intentionally
-        # changes (the per-shard batch moments ride explicit `psum` rows
-        # in BOTH backends; the gspmd path routes the opaque pallas_call
-        # through an inner shard_map, so even the "0 explicit" backend
-        # gains them) — and the @bf16 rows lower the reduced-precision
-        # policy (bf16 params/compute, f32 master Adam mu). Only the
-        # step-family rows are traced (sampler/probe/summarize differ
-        # only by kernel routing / dtype, which the step rows already
-        # fingerprint). The donation audit must hold for both: note the
-        # bf16 lowering emits a conservative "donated buffers were not
-        # usable" warning for the small (C,)-shaped bf16 leaves, but the
-        # compiled alias map realizes every donation (unaliased=[]) —
-        # the structured audit below, not the warning, is the gate.
-        for vtag, vkw in (("pallas_fused", {"pallas_fused": True}),
-                          ("bf16", {"precision": "bf16"})):
-            cfg_v = small_config(backend, **vkw)
-            pt_v = make_parallel_train(cfg_v, mesh)
-            plan_v, _bkv = warmup.build_warmup_plan(
-                cfg_v, pt_v, warmup.state_example(pt_v), sample_z=z,
-                eval_z=z,
-                make_backoff_pt=lambda c, _m=mesh: make_parallel_train(
-                    c, _m))
-            coverage.append(CoverageRow(
-                variant=f"{backend}+{vtag}", path=path,
-                programs=frozenset(pt_v.programs),
-                plan=tuple(n for n, _, _ in plan_v),
-                must_cover=frozenset(
-                    {"train_step", f"multi_step@k{cfg_v.steps_per_call}",
-                     "sampler", "eval_losses", "summarize",
-                     "state_copy"})))
-            for n, f, a in plan_v:
-                if _base(n) not in step_bases:
-                    continue
-                cadence = ""
-                if n == "train_step":
-                    cadence = (
-                        "every step when `--pallas_fused` (interior "
-                        "conv⊕BN⊕act stages fused into Pallas GEMM "
-                        "kernels; per-shard moments psum explicitly)"
-                        if vtag == "pallas_fused" else
-                        "every step when `--precision bf16` (bf16 "
-                        "params+compute, f32 master Adam mu; fp8 adds "
-                        "operand fake-quant at >=128px stages only)")
-                audits.append(audit_callable(
-                    f"{backend}::{n}@{vtag}", f, a, path=path,
-                    expect_donation=_base(n) in DONATED_PROGRAMS,
-                    cadence=cadence))
+        # Reduced-precision variant (ISSUE 17): the @bf16 rows lower the
+        # reduced-precision policy (bf16 params/compute, f32 master Adam
+        # mu). Only the step-family rows are traced (sampler/probe/
+        # summarize differ only by dtype, which the step rows already
+        # fingerprint). The donation audit must hold: the bf16 lowering
+        # emits a conservative "donated buffers were not usable" warning
+        # for the small (C,)-shaped bf16 leaves, but the compiled alias
+        # map realizes every donation (unaliased=[]) — the structured
+        # audit below, not the warning, is the gate.
+        cfg_v = small_config(backend, precision="bf16")
+        pt_v = make_parallel_train(cfg_v, mesh)
+        plan_v, _bkv = warmup.build_warmup_plan(
+            cfg_v, pt_v, warmup.state_example(pt_v), sample_z=z, eval_z=z,
+            make_backoff_pt=lambda c, _m=mesh: make_parallel_train(c, _m))
+        coverage.append(CoverageRow(
+            variant=f"{backend}+bf16", path=path,
+            programs=frozenset(pt_v.programs),
+            plan=tuple(n for n, _, _ in plan_v),
+            must_cover=frozenset(
+                {"train_step", f"multi_step@k{cfg_v.steps_per_call}",
+                 "sampler", "eval_losses", "summarize", "state_copy"})))
+        for n, f, a in plan_v:
+            if _base(n) not in step_bases:
+                continue
+            cadence = ""
+            if n == "train_step":
+                cadence = ("every step when `--precision bf16` (bf16 "
+                           "params+compute, f32 master Adam mu)")
+            audits.append(audit_callable(
+                f"{backend}::{n}@bf16", f, a, path=path,
+                expect_donation=_base(n) in DONATED_PROGRAMS,
+                cadence=cadence))
 
         for n, f, a in rows:
             cadence = ""

@@ -51,9 +51,7 @@ class ModelConfig:
     conditional_bn: bool = False   # conditional models only: the generator's
                                    # BN affine becomes per-class [K, C] tables
                                    # (SAGAN/BigGAN cBN) instead of the z-concat
-                                   # conditioning alone; moments stay shared.
-                                   # cBN layers always take the jnp path (the
-                                   # fused Pallas kernels are per-channel)
+                                   # conditioning alone; moments stay shared
     base_size: int = 4             # spatial size of the first feature map
     bn_momentum: float = 0.9       # EMA decay (distriubted_model.py:18,23)
     bn_eps: float = 1e-5           # (distriubted_model.py:18)
@@ -61,39 +59,20 @@ class ModelConfig:
     kernel_size: int = 5           # conv / deconv kernel (distriubted_model.py:176,190)
     compute_dtype: str = "bfloat16"  # MXU-native compute precision
     param_dtype: str = "float32"     # parameter / BN-stat storage precision
-    use_pallas: bool = False       # Pallas kernels: flash attention when
-                                   # attn_res > 0 (a measured WIN at long
-                                   # sequences — DESIGN.md §8b) plus the
-                                   # fused BN+act kernels (capability only:
-                                   # ~20% SLOWER at flagship shapes; XLA's
-                                   # fusion already sits at the HBM roof)
-    bn_pallas: Optional[bool] = None  # override the BN half of use_pallas
-                                   # alone (None = follow use_pallas).
-                                   # Set False by the gspmd backend under a
-                                   # spatial mesh, where flash attention
-                                   # composes (it runs in its own
-                                   # shard_map, ring x flash) but the BN
-                                   # kernels' full-channel-vector contract
-                                   # does not survive height sharding
-    pallas_fused: bool = False     # fuse each interior G/D stage end-to-end
-                                   # (conv/deconv + bias + BN + act) into the
-                                   # im2col Pallas blocks of
-                                   # ops/pallas_fused.py instead of the
-                                   # XLA-conv + Pallas-BN split. Requires
-                                   # use_pallas (it widens the same routing);
-                                   # dcgan arch only, and cBN layers are
-                                   # excluded (same per-channel-vector
-                                   # contract as bn_pallas). Narrowed to
-                                   # False by the gspmd spatial mesh with
-                                   # bn_pallas (parallel/api.py)
-    quant: str = ""                # "" | "fp8": simulated-quantization
-                                   # (amax-scaled float8_e4m3fn round-trip)
-                                   # of conv/deconv GEMM operands at stages
-                                   # with feature maps >= 64px — the large
-                                   # progressive phases where the MXU fp8
-                                   # path would bite. Normally set via
-                                   # TrainConfig.precision="fp8", not
-                                   # directly
+    use_pallas: bool = False       # the ONE kernel decision of the image
+                                   # models: attention runs on the flash
+                                   # kernels (ops/pallas_attention.py) where
+                                   # the model has attention (attn_res > 0),
+                                   # and nothing else changes — convolution,
+                                   # BN, activations and Adam are XLA's
+                                   # (DESIGN.md §8b). A no-op at attn_res=0
+    # RETIRED names (PR 29): the Pallas BN kernels and the fused conv blocks
+    # lost to XLA on the chip and are gone. Read by nothing; accepted while
+    # falsy (configs saved beside old checkpoints and benchmark/configs/*.json
+    # carry them), normalized to these defaults, refused when truthy. They go
+    # when those files drop the keys (ROADMAP.md Queue 3 item 1)
+    bn_pallas: Optional[bool] = None
+    pallas_fused: bool = False
     attn_res: int = 0              # >0 inserts a SAGAN-style self-attention
                                    # block (ops/attention.py) into both stacks
                                    # at the stage whose feature maps are
@@ -121,44 +100,17 @@ class ModelConfig:
                                    # Power-iteration state is explicit, like
                                    # BN moments (ops/spectral.py)
 
-    @property
-    def bn_use_pallas(self) -> bool:
-        """Whether BatchNorm runs the fused Pallas kernels — use_pallas
-        unless bn_pallas overrides it (model BN call sites read this; the
-        attention sites read use_pallas directly)."""
-        return self.use_pallas if self.bn_pallas is None else self.bn_pallas
-
     def __post_init__(self):
         if self.arch not in ("dcgan", "resnet", "stylegan"):
             raise ValueError(
                 f"arch must be 'dcgan', 'resnet', or 'stylegan', got "
                 f"{self.arch!r}")
-        if self.bn_pallas and not self.use_pallas:
-            # the field only NARROWS use_pallas (the spatial-mesh fallback);
-            # letting it enable the BN kernels alone would route around the
-            # backend's multi-device composition guards (parallel/api.py)
+        if self.bn_pallas or self.pallas_fused:
             raise ValueError(
-                "bn_pallas=True requires use_pallas=True (bn_pallas only "
-                "narrows the flag; to run the fused BN kernels alone use "
-                "use_pallas=True with attn_res=0)")
-        if self.pallas_fused:
-            if not self.use_pallas:
-                raise ValueError(
-                    "pallas_fused=True requires use_pallas=True (the fused "
-                    "conv blocks ride the same Pallas routing and backend "
-                    "composition guards)")
-            if self.arch != "dcgan":
-                raise ValueError(
-                    "pallas_fused=True supports arch='dcgan' only (the "
-                    "resnet/stylegan stacks have no fused block wired)")
-            if self.conditional_bn:
-                raise ValueError(
-                    "pallas_fused=True is incompatible with conditional_bn "
-                    "(per-example affines break the fused blocks' "
-                    "per-channel-vector contract, same as bn_pallas)")
-        if self.quant not in ("", "fp8"):
-            raise ValueError(
-                f"model.quant must be '' or 'fp8', got {self.quant!r}")
+                "bn_pallas / pallas_fused were removed in PR 29: BN and "
+                "convolution run on XLA")
+        object.__setattr__(self, "bn_pallas", None)
+        object.__setattr__(self, "pallas_fused", False)
         if self.arch == "stylegan":
             if self.conditional_bn:
                 raise ValueError(
@@ -264,9 +216,8 @@ class TokenModelConfig:
                                    # masked attention (short sequences)
 
     # what the shared trainer and config code asks of any model: this
-    # family has no classes and no quantization policy
+    # family has no classes
     num_classes = property(lambda self: 0)
-    quant = property(lambda self: "")
 
     def __post_init__(self):
         if self.arch != TOKEN_ARCH:
@@ -790,13 +741,9 @@ class TrainConfig:
                                    # bf16's ~3 significant digits suffice —
                                    # and BN running stats follow param dtype
                                    # through batch_norm_init while the
-                                   # moment REDUCTIONS are always f32).
-                                   # "fp8": the bf16 policy plus simulated
-                                   # fp8 quantization of conv GEMM operands
-                                   # at >=64px stages (model.quant="fp8" —
-                                   # the large progressive phases). The
+                                   # moment REDUCTIONS are always f32). The
                                    # policy is applied by normalizing
-                                   # model.{compute,param}_dtype/quant in
+                                   # model.{compute,param}_dtype in
                                    # __post_init__, so every downstream
                                    # consumer (init, steps, serve, analysis)
                                    # sees ordinary model dtypes
@@ -869,9 +816,9 @@ class TrainConfig:
                 f"arch={self.model.arch!r}")
         if token:
             self._refuse_image_services()
-        if self.precision not in ("", "f32", "bf16", "fp8"):
+        if self.precision not in ("", "f32", "bf16"):
             raise ValueError(
-                f"precision must be one of '', 'f32', 'bf16', 'fp8', got "
+                f"precision must be one of '', 'f32', 'bf16', got "
                 f"{self.precision!r}")
         if self.precision:
             # Normalize the policy into the model dtypes up front (frozen
@@ -879,22 +826,12 @@ class TrainConfig:
             # and the rewrite is idempotent so config round-trips through
             # config_from_dict reproduce the same model). precision OVERRIDES
             # any explicit model dtype flags — one knob, one meaning.
-            _POLICY = {
-                "f32": ("float32", "float32", ""),
-                "bf16": ("bfloat16", "bfloat16", ""),
-                "fp8": ("bfloat16", "bfloat16", "fp8"),
-            }
-            cdt, pdt, quant = _POLICY[self.precision]
-            if (self.model.compute_dtype, self.model.param_dtype,
-                    self.model.quant) != (cdt, pdt, quant):
+            dt = {"f32": "float32", "bf16": "bfloat16"}[self.precision]
+            if (self.model.compute_dtype, self.model.param_dtype) != (dt, dt):
                 object.__setattr__(
                     self, "model",
-                    dataclasses.replace(self.model, compute_dtype=cdt,
-                                        param_dtype=pdt, quant=quant))
-        elif self.model.quant:
-            raise ValueError(
-                "model.quant is set by the precision policy — use "
-                "precision='fp8' rather than setting it directly")
+                    dataclasses.replace(self.model, compute_dtype=dt,
+                                        param_dtype=dt))
         if self.backend not in ("gspmd", "shard_map"):
             raise ValueError(f"unknown backend {self.backend!r}")
         if self.backend == "shard_map" and (self.mesh.model != 1

@@ -113,7 +113,7 @@ PARTITION_RULES: Tuple[Tuple[str, LogicalSpec], ...] = (
 def count_master_f32_leaves(state: Pytree) -> int:
     """Census of the reduced-precision ladder's f32 MASTER leaves: Adam
     first-moment (`.../mu/...`) leaves stored as float32 while their
-    mirrored param leaf is sub-f32 (precision='bf16'/'fp8' sets
+    mirrored param leaf is sub-f32 (precision='bf16' sets
     optax.adam(mu_dtype=f32) — train/steps.py::make_optimizer).
 
     Master-weight LAYOUT note for the rule table above: mu/nu mirror the

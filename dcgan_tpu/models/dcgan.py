@@ -100,20 +100,6 @@ def _sn_attn(params_attn: Pytree, state: Pytree, new_state: Pytree,
     return out
 
 
-_FP8_MIN_RES = 64
-
-
-def _stage_quant(cfg: ModelConfig, res: int) -> str:
-    """fp8 simulated-quantization gate (precision='fp8'): only interior
-    conv/deconv stages whose feature maps reach _FP8_MIN_RES quantize their
-    GEMM operands — a no-op for every stage of the 64px phase (interior
-    maps top out at 32px), biting exactly in the 128/256px progressive
-    phases where the arithmetic is. The image-boundary stages (G's final
-    deconv to c_dim, D's conv0) never quantize: quality-critical and
-    a rounding error of the FLOPs."""
-    return cfg.quant if res >= _FP8_MIN_RES else ""
-
-
 def _dtype(cfg: ModelConfig):
     return jnp.dtype(cfg.param_dtype)
 
@@ -234,13 +220,11 @@ def generator_apply(params: Pytree, state: Pytree, z: jax.Array, *,
         top_ch = cfg.gf_dim * (2 ** (k - 1))
         h = linear_apply(layer("proj"), z.astype(cdt), compute_dtype=cdt)
         h = h.reshape(-1, cfg.base_size, cfg.base_size, top_ch)
-        # BN + relu fused (one pass under use_pallas; XLA-fused otherwise)
         bn_labels = labels if cfg.conditional_bn else None
         h, new_state["bn0"] = batch_norm_apply(
             params["bn0"], state["bn0"], h, train=train,
             momentum=cfg.bn_momentum, eps=cfg.bn_eps, axis_name=axis_name,
-            act="relu", use_pallas=cfg.bn_use_pallas, labels=bn_labels,
-            pallas_mesh=pallas_mesh)
+            act="relu", labels=bn_labels)
     if cfg.attn_res == cfg.base_size:
         h = attn_apply(attn_params(), h, compute_dtype=cdt,
                        num_heads=cfg.attn_heads,
@@ -252,30 +236,12 @@ def generator_apply(params: Pytree, state: Pytree, z: jax.Array, *,
 
     for i in range(1, k + 1):
         with jax.named_scope(f"deconv{i}"):
-            if cfg.pallas_fused and i < k:
-                # the whole interior stage (deconv + bias + BN + relu) as the
-                # fused Pallas block — one HBM round-trip instead of three
-                from dcgan_tpu.ops.pallas_fused import fused_conv_bn_act
-
-                h, new_state[f"bn{i}"] = fused_conv_bn_act(
-                    layer(f"deconv{i}"), params[f"bn{i}"], state[f"bn{i}"], h,
-                    transpose=True, kernel=cfg.kernel_size, stride=2,
-                    train=train, momentum=cfg.bn_momentum, eps=cfg.bn_eps,
-                    act="relu", axis_name=axis_name, pallas_mesh=pallas_mesh,
-                    compute_dtype=cdt,
-                    quant=_stage_quant(cfg, cfg.base_size * (2 ** i)))
-            else:
-                h = deconv2d_apply(
-                    layer(f"deconv{i}"), h, compute_dtype=cdt,
-                    quant="" if i == k
-                    else _stage_quant(cfg, cfg.base_size * (2 ** i)))
-                if i < k:
-                    h, new_state[f"bn{i}"] = batch_norm_apply(
-                        params[f"bn{i}"], state[f"bn{i}"], h, train=train,
-                        momentum=cfg.bn_momentum, eps=cfg.bn_eps,
-                        axis_name=axis_name, act="relu",
-                        use_pallas=cfg.bn_use_pallas,
-                        labels=bn_labels, pallas_mesh=pallas_mesh)
+            h = deconv2d_apply(layer(f"deconv{i}"), h, compute_dtype=cdt)
+            if i < k:
+                h, new_state[f"bn{i}"] = batch_norm_apply(
+                    params[f"bn{i}"], state[f"bn{i}"], h, train=train,
+                    momentum=cfg.bn_momentum, eps=cfg.bn_eps,
+                    axis_name=axis_name, act="relu", labels=bn_labels)
         if i < k:
             if cfg.attn_res == cfg.base_size * (2 ** i):
                 h = attn_apply(attn_params(), h, compute_dtype=cdt,
@@ -394,29 +360,14 @@ def discriminator_apply(params: Pytree, state: Pytree, image: jax.Array, *,
 
     for i in range(k):
         with jax.named_scope(f"conv{i}"):
-            if cfg.pallas_fused and i > 0:
-                # fused conv + bias + BN + lrelu block (stage 0 keeps the
-                # reference's no-BN shape and stays on the unfused path)
-                from dcgan_tpu.ops.pallas_fused import fused_conv_bn_act
-
-                h, new_state[f"bn{i}"] = fused_conv_bn_act(
-                    layer(f"conv{i}"), params[f"bn{i}"], state[f"bn{i}"], h,
-                    transpose=False, kernel=cfg.kernel_size, stride=2,
-                    train=train, momentum=cfg.bn_momentum, eps=cfg.bn_eps,
-                    act="lrelu", leak=cfg.leak, axis_name=axis_name,
-                    pallas_mesh=pallas_mesh, compute_dtype=cdt,
-                    quant=_stage_quant(cfg, cfg.output_size >> i))
-            elif i > 0:
-                h = conv2d_apply(layer(f"conv{i}"), h, compute_dtype=cdt,
-                                 quant=_stage_quant(cfg, cfg.output_size >> i))
-                # BN + lrelu fused (stage 0 keeps the reference's no-BN shape)
+            h = conv2d_apply(layer(f"conv{i}"), h, compute_dtype=cdt)
+            if i > 0:
+                # BN + lrelu (stage 0 keeps the reference's no-BN shape)
                 h, new_state[f"bn{i}"] = batch_norm_apply(
                     params[f"bn{i}"], state[f"bn{i}"], h, train=train,
                     momentum=cfg.bn_momentum, eps=cfg.bn_eps,
-                    axis_name=axis_name, act="lrelu", leak=cfg.leak,
-                    use_pallas=cfg.bn_use_pallas, pallas_mesh=pallas_mesh)
+                    axis_name=axis_name, act="lrelu", leak=cfg.leak)
             else:
-                h = conv2d_apply(layer(f"conv{i}"), h, compute_dtype=cdt)
                 h = lrelu(h, cfg.leak)
         if cfg.attn_res and cfg.attn_res == cfg.output_size >> (i + 1):
             h = attn_apply(attn_params(), h, compute_dtype=cdt,

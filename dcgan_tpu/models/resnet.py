@@ -157,8 +157,7 @@ def generator_apply(params: Pytree, state: Pytree, z: jax.Array, *,
         y, new_state[name] = batch_norm_apply(
             params[name], state[name], x, train=train,
             momentum=cfg.bn_momentum, eps=cfg.bn_eps, axis_name=axis_name,
-            act=act, use_pallas=cfg.bn_use_pallas, labels=bn_labels,
-            pallas_mesh=pallas_mesh)
+            act=act, labels=bn_labels)
         return y
 
     if cfg.num_classes:

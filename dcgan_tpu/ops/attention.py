@@ -227,9 +227,8 @@ def attn_apply(params: Pytree, x: jax.Array, *, compute_dtype=None,
 
     pallas_mesh: a pure data-parallel Mesh the CALLER's jit partitions
     over. pallas_call is opaque to the GSPMD partitioner, so on such a
-    mesh the flash path runs per data-shard inside a nested shard_map
-    (the ops/norm.py::_pallas_shard_moments pattern) — attention is
-    batch-local, so the wrapper needs no collectives. Ignored unless
+    mesh the flash path runs per data-shard inside a nested shard_map —
+    attention is batch-local, so the wrapper needs no collectives. Ignored unless
     use_pallas is set and no sequence mesh applies.
 
     num_heads > 1 splits the existing query/key/value projections into heads
@@ -283,7 +282,7 @@ def attn_apply(params: Pytree, x: jax.Array, *, compute_dtype=None,
     if seq_parallel and seq_strategy == "ulysses":
         # heads stay unfolded: the all_to_all itself is the head split.
         # check_vma only without pallas: pallas_call outputs carry no vma
-        # annotations (same constraint as ops/norm.py / shard_map_backend)
+        # annotations (same constraint as shard_map_backend)
         f = shard_map(
             functools.partial(ulysses_attention, axis_name=seq_axis,
                               n_shards=n, num_heads=num_heads, scale=scale,
@@ -329,8 +328,7 @@ def attn_apply(params: Pytree, x: jax.Array, *, compute_dtype=None,
                 # [b, head] row is independent in flash_attention, so a
                 # split that lands mid-head-group is merely a layout, not
                 # a semantics, difference. check_vma off: pallas outputs
-                # carry no vma annotations (same constraint as
-                # ops/norm.py).
+                # carry no vma annotations.
                 spec = P(batch_axis, None, None)
                 out = shard_map(
                     # scale closed over: custom_vjp nondiff args must stay

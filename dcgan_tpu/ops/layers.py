@@ -20,18 +20,6 @@ from jax import lax
 Pytree = dict
 
 
-def _fake_quant_fp8(x: jax.Array) -> jax.Array:
-    """Simulated fp8 matmul/conv operand (TrainConfig.precision='fp8'):
-    round-trip through float8_e4m3fn with per-tensor amax scaling — the
-    e4m3 max normal is 448, so an unscaled cast overflows to NaN. Runs the
-    fp8 NUMERICS on any backend; real fp8 MXU dispatch is a lowering
-    concern this experiment deliberately leaves to XLA."""
-    xf = x.astype(jnp.float32)
-    scale = jnp.maximum(jnp.max(jnp.abs(xf)) / 448.0, 1e-12)
-    q = (xf / scale).astype(jnp.float8_e4m3fn).astype(jnp.float32)
-    return (q * scale).astype(x.dtype)
-
-
 def _stddev_init(key, shape, stddev, dtype, truncated=False):
     if truncated:
         # TF truncated_normal: resample outside 2 sigma; jax provides the same.
@@ -77,12 +65,10 @@ def conv2d_init(key, in_ch: int, out_ch: int, *, kernel: int = 5,
 
 
 def conv2d_apply(params: Pytree, x: jax.Array, *, stride: int = 2,
-                 compute_dtype=None, quant: str = "") -> jax.Array:
+                 compute_dtype=None) -> jax.Array:
     w, b = params["w"], params["b"]
     if compute_dtype is not None:
         x, w = x.astype(compute_dtype), w.astype(compute_dtype)
-    if quant == "fp8":
-        x, w = _fake_quant_fp8(x), _fake_quant_fp8(w)
     y = lax.conv_general_dilated(
         x, w, window_strides=(stride, stride), padding="SAME",
         dimension_numbers=_CONV_DIMS)
@@ -103,12 +89,10 @@ def deconv2d_init(key, in_ch: int, out_ch: int, *, kernel: int = 5,
 
 
 def deconv2d_apply(params: Pytree, x: jax.Array, *, stride: int = 2,
-                   compute_dtype=None, quant: str = "") -> jax.Array:
+                   compute_dtype=None) -> jax.Array:
     w, b = params["w"], params["b"]
     if compute_dtype is not None:
         x, w = x.astype(compute_dtype), w.astype(compute_dtype)
-    if quant == "fp8":
-        x, w = _fake_quant_fp8(x), _fake_quant_fp8(w)
     y = lax.conv_transpose(
         x, w, strides=(stride, stride), padding="SAME",
         dimension_numbers=_CONV_DIMS)

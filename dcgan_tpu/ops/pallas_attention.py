@@ -76,8 +76,9 @@ Layout notes (TPU):
   exactness against ops/attention.py::full_attention, gradients included).
 
 Composition: `ops/attention.py::attn_apply(use_pallas=True)` routes its dense
-path here (single chip, or per-shard under the shard_map backend — pallas_call
-is opaque to the GSPMD partitioner, same constraint as ops/pallas_kernels.py).
+path here (single chip, or per-shard under the shard_map backend and under the
+gspmd backend's nested shard_map — pallas_call is opaque to the GSPMD
+partitioner).
 Under a spatial mesh the same flag routes the ring strategy through
 `ring_flash_attention` (bottom of this module): ring hops bound the
 per-device sequence, flash tiles bound the per-hop fold, so neither level

@@ -190,40 +190,23 @@ def make_parallel_train(cfg: TrainConfig,
     if cfg.model.arch == TOKEN_ARCH:
         return make_lm_parallel_train(cfg, mesh)
     pallas_mesh = None
-    if cfg.model.use_pallas and mesh.size > 1:
+    if cfg.model.use_pallas and cfg.model.attn_res and mesh.size > 1 \
+            and not cfg.mesh.spatial:
         # pallas_call is opaque to GSPMD: left alone, the partitioner would
-        # replicate activations around every BN — silent collapse of data
-        # parallelism. On a pure-DP mesh the fused BN kernels instead run
-        # per data-shard inside a shard_map nested in this jit (the ring-
-        # attention pattern; ops/norm.py::_pallas_shard_moments) — VERDICT
-        # r1 #5. Model/spatial sharding (channel- or height-sharded
-        # activations break the kernels' full-channel-vector contract)
-        # stays rejected — EXCEPT the spatial + attention case, where the
-        # attention already runs in its own explicit shard_map and the
-        # flash kernels compose as ring x flash
-        # (ops/pallas_attention.py::ring_flash_attention): there, only the
-        # BN half of the flag falls back to the jnp path.
-        if mesh.shape["model"] > 1 or cfg.mesh.spatial:
-            if cfg.mesh.spatial and cfg.model.attn_res:
-                # pallas_fused narrows with bn_pallas: the fused conv blocks
-                # share the BN kernels' full-channel-vector contract, which
-                # height sharding breaks the same way
-                cfg = dataclasses.replace(cfg, model=dataclasses.replace(
-                    cfg.model, bn_pallas=False, pallas_fused=False))
-            else:
-                raise ValueError(
-                    "use_pallas under the gspmd backend composes with data-"
-                    f"parallel meshes only, got mesh={dict(mesh.shape)} "
-                    f"(spatial={cfg.mesh.spatial}); the fused kernels need "
-                    "full channel vectors per shard")
-        else:
-            # Pure-DP mesh: BOTH kernel families run per data-shard in
-            # nested shard_maps — the fused BN moments via ops/norm.py and
-            # (since r5) flash attention via ops/attention.py::attn_apply's
-            # pallas_mesh route, so the rev-2 attention presets (flash +
-            # XLA BN) scale over data-parallel meshes under the default
-            # backend too.
-            pallas_mesh = mesh
+        # gather the batch around every flash kernel. On a data-parallel
+        # mesh the kernels run per batch shard in a shard_map nested in this
+        # jit (ops/attention.py::attn_apply's pallas_mesh route). Under a
+        # spatial mesh attention already runs in its own shard_map and
+        # composes as ring x flash (attn_mesh below). Everything but
+        # attention is XLA's and partitions as it always did.
+        if mesh.shape["model"] > 1:
+            raise ValueError(
+                "use_pallas with attention under the gspmd backend needs a "
+                "data-parallel or spatial mesh, got "
+                f"mesh={dict(mesh.shape)} without spatial: the flash "
+                "kernels' per-shard route splits the batch over 'data' "
+                "alone and would run replicated over 'model'")
+        pallas_mesh = mesh
     spatial = cfg.mesh.spatial
     img_sh = batch_sharding(mesh, 4, spatial=spatial)
     constrain_fake = None

@@ -12,11 +12,10 @@ changes is who writes them.
 Two reasons this backend exists beyond idiom parity:
 
 1. **Per-shard Pallas kernels.** `pallas_call` is opaque to the GSPMD
-   partitioner, so the fused BN kernels (ops/pallas_kernels.py) are rejected
-   under the default backend on multi-device meshes. Inside shard_map there is
-   no partitioner — each device runs the kernel on its local shard and the
-   moments are pmean'd explicitly — so `ModelConfig.use_pallas` composes with
-   data parallelism here.
+   partitioner. Inside shard_map there is no partitioner: each device runs
+   the flash attention kernels (`ModelConfig.use_pallas` with `attn_res`) on
+   its local batch shard, at any device count. (The default backend reaches
+   the same by nesting a shard_map around attention, parallel/api.py.)
 2. **A second, independently-testable implementation** of the communication
    pattern that replaced the reference's gRPC parameter-server traffic
    (image_train.py:55-67): tests assert the two backends agree, which checks
@@ -173,15 +172,16 @@ def make_shard_map_train(cfg: TrainConfig,
                           zero_hooks=zero_hooks)
     conditional = cfg.model.num_classes > 0
     # The varying-manner checker needs `vma` annotations on every
-    # ShapeDtypeStruct a pallas_call emits, which the kernels (written to be
-    # backend-agnostic) don't carry — turn static checking off for the fused
-    # path; the collective placement is the same either way and is covered by
-    # the equivalence tests. ZeRO >= 2 likewise runs unchecked: this
-    # container's check_rep tracker has no rule marking tiled
+    # ShapeDtypeStruct a pallas_call emits, which the flash kernels (written
+    # to be backend-agnostic) don't carry — turn static checking off where
+    # they run; the collective placement is the same either way and is
+    # covered by the equivalence tests. ZeRO >= 2 likewise runs unchecked:
+    # this container's check_rep tracker has no rule marking tiled
     # psum_scatter/all_gather chains replication-consistent with the
     # sharded out_specs below, and the placement is pinned by the stage
     # 1/2/3 loss-parity tests instead.
-    vma = not cfg.model.use_pallas and zero < 2
+    flash = cfg.model.use_pallas and cfg.model.attn_res
+    vma = not flash and zero < 2
 
     def smap(f, in_specs, out_specs):
         from dcgan_tpu.utils.backend import shard_map

@@ -115,15 +115,12 @@ def sagan64(**overrides) -> TrainConfig:
     """
     cfg = _build(ModelConfig(output_size=64, attn_res=32,
                              spectral_norm="gd",
-                             # the execution split DESIGN.md §8b argues
-                             # for (restructure, don't re-fuse): attention
-                             # on the flash kernels, BN on XLA. Its timing
-                             # is not measured on the current machine.
-                             # Composes with every mesh: per-shard nested
-                             # shard_map on DP gspmd (attn_apply's
+                             # attention on the flash kernels (DESIGN.md
+                             # §8b). Composes with every mesh: per-shard
+                             # nested shard_map on DP gspmd (attn_apply's
                              # pallas_mesh route), ring x flash under
                              # --mesh_spatial, per-shard under shard_map
-                             use_pallas=True, bn_pallas=False),
+                             use_pallas=True),
                  MeshConfig(),
                  batch_size=64, loss="hinge", beta1=0.0,
                  d_learning_rate=4e-4, g_learning_rate=1e-4,
@@ -138,10 +135,7 @@ def sagan128(**overrides) -> TrainConfig:
     --mesh_spatial) and the flash kernels (--use_pallas) earn their keep.
     Same recipe as sagan64 otherwise (hinge, SN both nets, TTUR, EMA)."""
     cfg = _build(ModelConfig(output_size=128, attn_res=64,
-                             spectral_norm="gd",
-                             # same split as sagan64: flash attention +
-                             # XLA BN (DESIGN.md §8)
-                             use_pallas=True, bn_pallas=False),
+                             spectral_norm="gd", use_pallas=True),
                  MeshConfig(),
                  batch_size=64, loss="hinge", beta1=0.0,
                  d_learning_rate=4e-4, g_learning_rate=1e-4,
@@ -159,17 +153,14 @@ def sagan256_lc(**overrides) -> TrainConfig:
     is D-only here — G's 2048-channel early stages make G-side power
     iteration the dominant non-attention cost at this depth."""
     cfg = _build(ModelConfig(output_size=256, attn_res=128,
-                             spectral_norm="d", use_pallas=True,
-                             # r5: BN back on XLA — use_pallas exists here
-                             # for the flash ATTENTION path; the fused-BN
-                             # half re-fuses what XLA fuses (DESIGN.md §8b)
-                             bn_pallas=False),
+                             spectral_norm="d", use_pallas=True),
                  MeshConfig(),
-                 # shard_map backend: use_pallas + attn_res composes with
-                 # data-parallel meshes at ANY device count there (each
-                 # shard runs the kernels locally; the gspmd partitioner
-                 # would reject the combination on a multi-device mesh —
-                 # parallel/api.py)
+                 # shard_map backend: each shard runs the flash kernels on
+                 # its own batch rows. gspmd composes the same way since its
+                 # nested shard_map (parallel/api.py; the benchmark's
+                 # four-chip cell runs it), so the pin is a choice between
+                 # two working backends that no cell has decided yet
+                 # (ROADMAP Queue 3 item 2)
                  backend="shard_map",
                  batch_size=64, loss="hinge", beta1=0.0,
                  d_learning_rate=4e-4, g_learning_rate=1e-4,
@@ -274,9 +265,8 @@ PRESETS: Dict[str, Callable[..., TrainConfig]] = {
 # only, so a row's spread never mixes configs that no longer exist —
 # the same contract ops/pallas_attention.py::ATTN_GEN gives kernel
 # changes. Unlisted presets are revision 1.
-# rev 2 (r5): sagan64/sagan128 adopt flash attention + XLA BN
-# (chip-measured +46% on the sagan64-shape step); sagan256-lc splits
-# bn_pallas off its use_pallas flag.
+# rev 2 (r5): sagan64/sagan128/sagan256-lc run attention on the flash
+# kernels and BN on XLA (chip-measured +46% on the sagan64-shape step).
 PRESET_REVS: Dict[str, int] = {
     "sagan64": 2,
     "sagan128": 2,

@@ -92,17 +92,13 @@ def build_parser() -> argparse.ArgumentParser:
                    help="conditional models: per-class BN affine in G "
                         "(SAGAN/BigGAN cBN)")
     p.add_argument("--use_pallas", action="store_true",
-                   help="fused Pallas BN+activation kernels (single-chip)")
-    p.add_argument("--pallas_fused", action="store_true",
-                   help="fuse each interior G/D stage (conv/deconv + bias + "
-                        "BN + act) into one Pallas block (ops/pallas_fused); "
-                        "requires --use_pallas, dcgan arch only")
-    p.add_argument("--precision", choices=["", "f32", "bf16", "fp8"],
+                   help="run attention (--attn_res) on the flash Pallas "
+                        "kernels; everything else stays on XLA")
+    p.add_argument("--precision", choices=["", "f32", "bf16"],
                    default="",
                    help="reduced-precision ladder: f32 (reference arm), "
-                        "bf16 (bf16 params+compute, f32 master Adam mu), "
-                        "fp8 (bf16 + simulated-fp8 conv operands at >=64px "
-                        "stages); default '' leaves model dtypes alone")
+                        "bf16 (bf16 params+compute, f32 master Adam mu); "
+                        "default '' leaves model dtypes alone")
     p.add_argument("--attn_res", type=int, default=0,
                    help=">0 inserts SAGAN self-attention into both stacks at "
                         "this feature-map resolution (ring attention under "
@@ -438,7 +434,6 @@ _FLAG_FIELDS = {
     "z_dim": ("model", "z_dim"), "gf_dim": ("model", "gf_dim"),
     "df_dim": ("model", "df_dim"), "num_classes": ("model", "num_classes"),
     "use_pallas": ("model", "use_pallas"),
-    "pallas_fused": ("model", "pallas_fused"),
     "precision": ("", "precision"),
     "conditional_bn": ("model", "conditional_bn"),
     "attn_res": ("model", "attn_res"),

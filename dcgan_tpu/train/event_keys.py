@@ -115,7 +115,7 @@ EVENT_KEYS: Dict[str, str] = {
     "fleet/phase": "fleet_health_steps",
 
     # -- reduced-precision ladder (ISSUE 17): one startup row naming the
-    #    active policy (numeric code: 0=f32, 1=bf16, 2=fp8) and the f32
+    #    active policy (numeric code: 0=f32, 1=bf16) and the f32
     #    master-moment census from elastic/rules.py. Gated on the knob —
     #    precision="" (the default) emits neither, so default streams stay
     #    byte-identical (parity A/B-pinned); the policy STRING rides the

@@ -105,7 +105,7 @@ def make_optimizer(cfg: TrainConfig, lr: Optional[float] = None, *,
     overrides the base rate (TTUR per-net rates); the schedule applies on
     top of whichever base is used."""
     base_lr = cfg.learning_rate if lr is None else lr
-    # Reduced-precision ladder (ISSUE 17): under bf16/fp8 params the Adam
+    # Reduced-precision ladder (ISSUE 17): under bf16 params the Adam
     # FIRST moment is kept as an f32 master copy (mu_dtype) — it is a small
     # signed running mean whose bf16 rounding visibly biases updates. nu
     # (second moment) follows the param dtype: it is a variance consumed
@@ -114,7 +114,7 @@ def make_optimizer(cfg: TrainConfig, lr: Optional[float] = None, *,
     # checkpoint-structure contract below survives the ladder, and the
     # rule-engine specs (elastic/rules.py) shard mu like any same-shaped
     # param leaf.
-    mu_dtype = jnp.float32 if cfg.precision in ("bf16", "fp8") else None
+    mu_dtype = jnp.float32 if cfg.precision == "bf16" else None
     adam = optax.adam(make_lr_schedule(cfg, base_lr,
                                        updates_per_step=updates_per_step),
                       b1=cfg.beta1, b2=0.999, eps=1e-8, mu_dtype=mu_dtype)

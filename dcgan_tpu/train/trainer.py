@@ -378,7 +378,7 @@ def _flight_context(cfg: TrainConfig, startup: StartupProfile,
     out = {"process": jax.process_index()}
     if cfg.precision:
         # name the active precision policy in every crash dump (ISSUE 17):
-        # a NaN abort under bf16/fp8 must be attributable to the ladder at
+        # a NaN abort under bf16 must be attributable to the ladder at
         # a glance. Crash-path-only IO — absent under the default policy,
         # so this never touches the parity-pinned event stream.
         out["precision"] = cfg.precision
@@ -1149,7 +1149,7 @@ def _train_run(cfg: TrainConfig, *, synthetic_data: bool,
                 # the policy STRING rides the flight-recorder header)
                 prow = {
                     "perf/precision/policy": float(
-                        {"f32": 0, "bf16": 1, "fp8": 2}[cfg.precision]),
+                        {"f32": 0, "bf16": 1}[cfg.precision]),
                     "perf/precision/master_f32_leaves": float(master_f32),
                 }
                 svc.submit(lambda s=step, r=prow:

@@ -1,10 +1,5 @@
-"""The BENCH_* env knobs -> config, shared by every measurement entrypoint.
-
-bench.py (the driver's bench contract) and tools/step_profile.py (the
-roofline profiler) must build IDENTICAL configs from the same env — a
-profile row is only meaningful as the decomposition of a captured bench
-row. Round 4 kept two hand-copies of the parsing and they drifted
-(step_profile missed BENCH_ATTN_RES); this module is the single copy.
+"""The BENCH_* env knobs -> config, for bench.py (the driver's bench
+contract) and the capture matrix that labels its rows.
 
 Knobs handled here (model-shape only — batch/steps/scan/backends stay with
 their owners, they don't change WHAT is measured, only how long):
@@ -13,10 +8,8 @@ their owners, they don't change WHAT is measured, only how long):
   BENCH_SIZE       output resolution (default 64)
   BENCH_ATTN=1     self-attention at 32x32 (the sagan64-attn shape)
   BENCH_SN=1       spectral norm on both nets
-  BENCH_PALLAS=1   use_pallas (flash attention; BN too unless split below)
-  BENCH_BN_PALLAS=0  keep BN on XLA while BENCH_PALLAS routes attention
-                   through the flash kernels — the measured-best split
-                   (DESIGN.md §8b)
+  BENCH_PALLAS=1   use_pallas: attention on the flash kernels (a no-op
+                   without attention — DESIGN.md §8b)
   BENCH_ATTN_RES=R attention at feature-map resolution R on top of
                    whatever config the knobs above built (the long-context
                    knob: R=128 at BENCH_SIZE=256 is S=16384)
@@ -37,7 +30,6 @@ def bench_model_config(env=None) -> Tuple[ModelConfig, str]:
     mcfg = ModelConfig(
         output_size=int(env.get("BENCH_SIZE", 64)),
         use_pallas=env.get("BENCH_PALLAS", "") == "1",
-        bn_pallas=(False if env.get("BENCH_BN_PALLAS") == "0" else None),
         attn_res=32 if env.get("BENCH_ATTN", "") == "1" else 0,
         spectral_norm="gd" if env.get("BENCH_SN", "") == "1" else "none")
     # the label must be injective over the knobs above — capture renders
@@ -50,28 +42,15 @@ def bench_model_config(env=None) -> Tuple[ModelConfig, str]:
         label = "headline" if size == 64 else f"dcgan{size}"
     # BENCH_ATTN_RES is applied to the CONFIG later (apply_attn_res_override
     # runs on the full TrainConfig), but the label must reflect it NOW
-    # (ADVICE r5 #2): the flash/pallas suffix below keys off whether
-    # attention actually runs, and computing it pre-override mislabeled
-    # e.g. BENCH_ATTN_RES=128 + BENCH_PALLAS=1 + BENCH_BN_PALLAS=0 as
-    # '-pallas-xlabn' (declared "no Pallas kernel runs") though it runs
-    # flash attention. The bench matrix's long-context rows name these
+    # (ADVICE r5 #2): the flash suffix below keys off whether attention
+    # actually runs. The bench matrix's long-context rows name these
     # '<family>-attn<R>-{flash,dense}' (tools/capture_all.py) — match that.
     attn_res_knob = int(env.get("BENCH_ATTN_RES", "0") or 0)
     if attn_res_knob:
         label += f"-attn{attn_res_knob}"
     effective_attn = mcfg.attn_res or attn_res_knob
-    if mcfg.use_pallas:
-        # "-flash" = flash attention with BN split back to XLA (the
-        # measured-best form); "-pallas" = both kernel families engaged;
-        # "-pallas-xlabn" = the degenerate no-attention + BN-split combo
-        # (no Pallas kernel actually runs — kept distinct so it can never
-        # merge with the fused-BN row)
-        if effective_attn and mcfg.bn_pallas is False:
-            label += "-flash"
-        elif mcfg.bn_pallas is False:
-            label += "-pallas-xlabn"
-        else:
-            label += "-pallas"
+    if mcfg.use_pallas and effective_attn:
+        label += "-flash"
     elif attn_res_knob:
         label += "-dense"  # the bench matrix's explicit dense rows
     if mcfg.spectral_norm != "none":

@@ -16,7 +16,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-pytestmark = pytest.mark.slow  # see pytest.ini: excluded from the smoke tier
+# tier-1 but for the tests marked slow one by one: each of those pays a
+# multi-device or whole-model compile of 10-30 s on a CPU
+
 from jax.sharding import Mesh, PartitionSpec as P
 
 from dcgan_tpu.config import MeshConfig, ModelConfig, TrainConfig
@@ -187,6 +189,7 @@ class TestUlysses:
         np.testing.assert_allclose(np.asarray(uly), np.asarray(ring),
                                    atol=1e-5)
 
+    @pytest.mark.slow
     def test_gradients_match_dense(self):
         params = attn_init(jax.random.key(0), 32)
         params = dict(params, gamma=jnp.asarray(0.8))
@@ -219,6 +222,7 @@ class TestUlysses:
             attn_apply(params, x, seq_mesh=ring_mesh(2),
                        seq_strategy="megatron")
 
+    @pytest.mark.slow
     def test_sharded_train_step_ulysses(self):
         """Full train step under dp4 x sp2 with Ulysses attention matches the
         single-device step (same envelope as the ring test)."""
@@ -254,6 +258,7 @@ class TestModelWiring:
             ModelConfig(output_size=64, attn_res=64)  # only intermediate maps
         ModelConfig(output_size=64, attn_res=4)       # base_size site is legal
 
+    @pytest.mark.slow
     @pytest.mark.parametrize("attn_res", [4, 8])
     def test_generator_and_discriminator_run(self, attn_res):
         cfg = dataclasses.replace(ATTN_TINY, attn_res=attn_res)
@@ -268,6 +273,7 @@ class TestModelWiring:
                                           cfg=cfg, train=True)
         assert logit.shape == (4, 1)
 
+    @pytest.mark.slow
     def test_no_attn_params_without_attn_res(self):
         params, _ = gan_init(jax.random.key(0),
                              dataclasses.replace(ATTN_TINY, attn_res=0))
@@ -290,6 +296,7 @@ class TestModelWiring:
 
 
 class TestShardedAttentionStep:
+    @pytest.mark.slow
     def test_spatial_ring_step_matches_single_device(self):
         """dp4 x spatial2 with ring attention == the unsharded step (losses
         tight; params within the ±2·lr first-Adam-step sign-flip envelope —

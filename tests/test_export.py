@@ -114,7 +114,7 @@ class TestExportSampler:
         are execution-form-agnostic, so the flash-trained weights serve
         through the dense sampler unchanged."""
         ckpt = _train_ckpt(tmp_path_factory.mktemp("export_attn"),
-                           attn_res=8, use_pallas=True, bn_pallas=False)
+                           attn_res=8, use_pallas=True)
         out = str(tmp_path / "attn.jaxexport")
         meta = export_sampler(
             ckpt, out, overrides={"output_size": 16, "gf_dim": 8,
@@ -142,7 +142,6 @@ class TestExportSampler:
         cfg = TrainConfig(model=ModelConfig(output_size=16, gf_dim=8,
                                             df_dim=8, attn_res=8,
                                             use_pallas=True,
-                                            bn_pallas=False,
                                             compute_dtype="float32"),
                           batch_size=8, checkpoint_dir=ckpt)
         pt = make_parallel_train(cfg, make_mesh(cfg.mesh))

@@ -146,3 +146,80 @@ class TestBatchNorm:
         for i in range(n):
             np.testing.assert_allclose(np.asarray(s_sync["mean"][i]),
                                        np.asarray(expect), rtol=1e-4)
+
+
+def _bn_formula(p, s, x, *, train, act, labels, momentum=0.9, eps=1e-5,
+                leak=0.2):
+    """BatchNorm + activation written out in float32 `jax.numpy`: the plain
+    formula `batch_norm_apply` is held to."""
+    xf = x.astype(jnp.float32)
+    if train:
+        axes = tuple(range(x.ndim - 1))
+        mean = jnp.mean(xf, axes)
+        var = jnp.mean((xf - mean) ** 2, axes)
+        state = {"mean": momentum * s["mean"] + (1 - momentum) * mean,
+                 "var": momentum * s["var"] + (1 - momentum) * var}
+    else:
+        mean, var, state = s["mean"], s["var"], s
+    scale, bias = p["scale"], p["bias"]
+    if labels is not None:
+        scale = scale[labels][:, None, None, :]
+        bias = bias[labels][:, None, None, :]
+    y = (xf - mean) / jnp.sqrt(var + eps) * scale + bias
+    y = {"none": lambda u: u, "relu": jax.nn.relu, "tanh": jnp.tanh,
+         "lrelu": lambda u: jnp.where(u > 0, u, leak * u)}[act](y)
+    return y, state
+
+
+@pytest.mark.parametrize("conditional", [False, True],
+                         ids=["plain", "conditional"])
+@pytest.mark.parametrize("train", [True, False], ids=["train", "eval"])
+@pytest.mark.parametrize("act", ["none", "relu", "lrelu", "tanh"])
+def test_batch_norm_apply_is_the_plain_formula(act, train, conditional):
+    """The one BN path (XLA's since PR 29) against the formula: output,
+    new state, and the gradients to input, scale and bias, in float32; then
+    a bfloat16 input, whose moments must still be taken in float32."""
+    k = jax.random.split(jax.random.key(7), 5)
+    classes = 3 if conditional else 0
+    p, s = batch_norm_init(k[0], 8, num_classes=classes)
+    p = {"scale": p["scale"], "bias": 0.1 * jax.random.normal(
+        k[1], p["bias"].shape)}
+    s = {"mean": 0.3 * jax.random.normal(k[2], (8,)),
+         "var": 1.0 + 0.5 * jax.random.uniform(k[3], (8,))}
+    x = 2.0 * jax.random.normal(k[4], (6, 4, 4, 8)) + 0.5
+    labels = jnp.arange(6) % 3 if conditional else None
+
+    def loss(fn):
+        def f(p, x):
+            y, state = fn(p, s, x, train=train, act=act, labels=labels)
+            w = jnp.cos(jnp.arange(y.size, dtype=jnp.float32)).reshape(
+                y.shape)
+            return jnp.sum(y.astype(jnp.float32) * w), (y, state)
+        return jax.value_and_grad(f, argnums=(0, 1), has_aux=True)
+
+    (_, (y, state)), (gp, gx) = loss(batch_norm_apply)(p, x)
+    (_, (y_ref, state_ref)), (gp_ref, gx_ref) = loss(_bn_formula)(p, x)
+    assert y.dtype == jnp.float32
+    np.testing.assert_allclose(y, y_ref, rtol=1e-5, atol=1e-5)
+    for name in ("mean", "var"):
+        np.testing.assert_allclose(state[name], state_ref[name],
+                                   rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(gx, gx_ref, rtol=1e-4, atol=1e-5)
+    for name in ("scale", "bias"):
+        np.testing.assert_allclose(gp[name], gp_ref[name], rtol=1e-4,
+                                   atol=1e-4)
+
+    xb = x.astype(jnp.bfloat16)
+    yb, state_b = batch_norm_apply(p, s, xb, train=train, act=act,
+                                   labels=labels)
+    yb_ref, state_b_ref = _bn_formula(p, s, xb, train=train, act=act,
+                                      labels=labels)
+    assert yb.dtype == jnp.bfloat16
+    assert state_b["mean"].dtype == jnp.float32
+    # the moments see the bfloat16 input's values and float32 arithmetic:
+    # they match the formula far inside bfloat16's 3 digits
+    for name in ("mean", "var"):
+        np.testing.assert_allclose(state_b[name], state_b_ref[name],
+                                   rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(yb.astype(jnp.float32), yb_ref,
+                               rtol=0.05, atol=0.08)

@@ -1,7 +1,8 @@
 """Flash-attention Pallas kernels (ops/pallas_attention.py): exactness vs the
 dense reference, forward and backward, plus the attn_apply(use_pallas=True)
-routing and a full train step on the fused path. Off-TPU the kernels run in
-interpret mode — the same code path the chip compiles."""
+routing, a full train step on the flash path, and the meshes `use_pallas`
+composes with. Off-TPU the kernels run in interpret mode — the same code
+path the chip compiles."""
 
 import dataclasses
 
@@ -9,8 +10,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-pytestmark = pytest.mark.slow  # see pytest.ini: excluded from the smoke tier
 
 from dcgan_tpu.config import MeshConfig, ModelConfig, TrainConfig
 from dcgan_tpu.utils.backend import shard_map
@@ -233,3 +232,53 @@ class TestFusedAttnApply:
         for k in m_ref:
             np.testing.assert_allclose(float(m_fused[k]), float(m_ref[k]),
                                        rtol=1e-4, err_msg=k)
+
+
+def _lowered_step(mesh_cfg, debug_info=False, **model_kw):
+    """The gspmd train step of a 16 px model on `mesh_cfg` over the 8
+    virtual devices, lowered (nothing compiles or runs)."""
+    from dcgan_tpu.parallel import make_parallel_train
+
+    cfg = TrainConfig(
+        model=ModelConfig(output_size=16, gf_dim=8, df_dim=8,
+                          compute_dtype="float32", **model_kw),
+        batch_size=16, mesh=mesh_cfg)
+    pt = make_parallel_train(cfg)
+    state = jax.eval_shape(lambda k: pt.init(k), jax.random.key(0))
+    images = jax.ShapeDtypeStruct((16, 16, 16, 3), jnp.float32)
+    return pt.programs["train_step"].lower(
+        state, images, jax.random.key(1)).as_text(debug_info=debug_info)
+
+
+class TestGspmdMeshes:
+    """What parallel/api.py's guard admits: `use_pallas` selects the flash
+    kernels where the model has attention and nothing else, so only
+    attention constrains the mesh."""
+
+    @pytest.mark.parametrize("mesh_cfg", [
+        pytest.param(MeshConfig(), id="data"),
+        pytest.param(MeshConfig(model=2, spatial=True), id="data-x-spatial"),
+    ])
+    def test_attention_runs_the_flash_kernels_per_shard(self, mesh_cfg):
+        import re
+
+        text = _lowered_step(mesh_cfg, debug_info=True, use_pallas=True,
+                             attn_res=8)
+        kernels = {loc.split("/")[-2]
+                   for loc in re.findall(r'loc\("([^"]+)"', text)
+                   if loc.endswith("/pallas_call")}
+        assert kernels == {"flash_fwd", "flash_dq_dkv"}
+
+    def test_model_axis_with_attention_is_refused(self):
+        with pytest.raises(ValueError,
+                           match="data-parallel or spatial mesh"):
+            _lowered_step(MeshConfig(model=2), use_pallas=True, attn_res=8)
+
+    @pytest.mark.parametrize("mesh_cfg", [
+        pytest.param(MeshConfig(), id="data"),
+        pytest.param(MeshConfig(model=2), id="data-x-model"),
+        pytest.param(MeshConfig(model=2, spatial=True), id="data-x-spatial"),
+    ])
+    def test_without_attention_the_flag_is_a_no_op(self, mesh_cfg):
+        assert _lowered_step(mesh_cfg, use_pallas=True) \
+            == _lowered_step(mesh_cfg)

@@ -87,17 +87,15 @@ def test_spatial_sharding_rules():
                   marks=pytest.mark.slow),
      pytest.param(MeshConfig(model=2, spatial=True), TINY, False,
                   id="dp4xsp2", marks=pytest.mark.slow),
-     # spatial + attention + use_pallas: the gspmd gate now admits this
-     # cell by dropping only the BN half of the flag (bn_pallas=False) and
-     # routing the attention through ring x flash
+     # spatial + attention + use_pallas: attention runs as ring x flash
      # (ops/pallas_attention.py::ring_flash_attention); the single-device
-     # reference runs flash + fused-BN — both exact, so they must agree
+     # reference runs plain flash — both exact, so they must agree
      pytest.param(MeshConfig(model=2, spatial=True), "ring-flash", False,
                   id="dp4xsp2-ringflash", marks=pytest.mark.slow),
-     # pure-DP gspmd + flash attention + XLA BN (r5): the flash kernels
-     # run per data-shard through attn_apply's pallas_mesh nested
-     # shard_map — the rev-2 attention presets' execution form; must
-     # match the single-device step exactly like every other partitioning
+     # pure-DP gspmd + flash attention: the kernels run per data-shard
+     # through attn_apply's pallas_mesh nested shard_map — the attention
+     # presets' execution form; must match the single-device step exactly
+     # like every other partitioning
      pytest.param(MeshConfig(), "dp-flash", False, id="dp8-flash",
                   marks=pytest.mark.slow),
      pytest.param(MeshConfig(shard_opt=True), TINY, False, id="dp8-zero1",
@@ -115,11 +113,8 @@ def test_sharded_step_matches_single_device(mesh_cfg, model, conditional):
 
     if model == "cbn":
         model = dataclasses.replace(TINY, num_classes=4, conditional_bn=True)
-    elif model == "ring-flash":
+    elif model in ("ring-flash", "dp-flash"):
         model = dataclasses.replace(TINY, attn_res=8, use_pallas=True)
-    elif model == "dp-flash":
-        model = dataclasses.replace(TINY, attn_res=8, use_pallas=True,
-                                    bn_pallas=False)
     cfg = TrainConfig(model=model, batch_size=16, mesh=mesh_cfg)
     xs, key = real_batch(), jax.random.key(3)
     labels = (jnp.asarray(np.arange(16) % model.num_classes),) \

@@ -1,7 +1,7 @@
-"""Reduced-precision ladder (ISSUE 17): the `--precision {f32,bf16,fp8}`
+"""Reduced-precision ladder (ISSUE 17): the `--precision {f32,bf16}`
 policy knob and everything downstream of it — config normalization, f32
-master-moment layout, simulated-fp8 numerics, the int8 post-training-
-quantization serving rung, telemetry surfacing, and the bf16 FID-parity
+master-moment layout, the refusal of the retired fp8 rung and kernel
+fields (PR 29), the int8 post-training-quantization serving rung, telemetry surfacing, and the bf16 FID-parity
 gate. The structural parity gate runs in the smoke tier (the ISSUE's
 acceptance requires it in tier-1); the full FID run rides the slow tier."""
 
@@ -15,33 +15,24 @@ import pytest
 
 from dcgan_tpu.config import ModelConfig, TrainConfig, config_from_dict, \
     config_to_dict
-from dcgan_tpu.ops.pallas_fused import fake_quant_fp8
 
 
-def _cfg(precision="", pallas_fused=False, **kw):
+def _cfg(precision="", **kw):
     return TrainConfig(
         model=ModelConfig(output_size=16, base_size=4, gf_dim=8, df_dim=8,
-                          z_dim=8, use_pallas=pallas_fused,
-                          pallas_fused=pallas_fused),
+                          z_dim=8),
         batch_size=8, precision=precision, max_steps=100, **kw)
 
 
 class TestPolicyConfig:
-    """precision is ONE knob normalized into the model dtype/quant fields
-    at construction, so checkpoints and config_from_dict reproduce the
-    same model; setting the model fields by hand is rejected."""
+    """precision is ONE knob normalized into the model dtype fields at
+    construction, so checkpoints and config_from_dict reproduce the same
+    model."""
 
     def test_bf16_policy(self):
         cfg = _cfg("bf16")
         assert cfg.model.compute_dtype == "bfloat16"
         assert cfg.model.param_dtype == "bfloat16"
-        assert cfg.model.quant == ""
-
-    def test_fp8_policy_adds_quant(self):
-        cfg = _cfg("fp8")
-        assert cfg.model.compute_dtype == "bfloat16"
-        assert cfg.model.param_dtype == "bfloat16"
-        assert cfg.model.quant == "fp8"
 
     def test_f32_policy_overrides_model_default(self):
         # the model's default compute dtype is bfloat16 — precision="f32"
@@ -55,7 +46,7 @@ class TestPolicyConfig:
         assert cfg.model.compute_dtype == "bfloat16"
         assert cfg.model.param_dtype == "float32"
 
-    @pytest.mark.parametrize("precision", ["f32", "bf16", "fp8"])
+    @pytest.mark.parametrize("precision", ["f32", "bf16"])
     def test_dict_roundtrip_idempotent(self, precision):
         cfg = _cfg(precision)
         cfg2 = config_from_dict(config_to_dict(cfg))
@@ -66,20 +57,72 @@ class TestPolicyConfig:
         with pytest.raises(ValueError, match="precision"):
             _cfg("fp16")
 
-    def test_manual_model_quant_raises(self):
-        with pytest.raises(ValueError, match="precision"):
-            TrainConfig(model=ModelConfig(quant="fp8"), batch_size=8)
+
+
+class TestRetiredNames:
+    """PR 29 deleted the Pallas BN kernels, the fused conv blocks and the
+    fp8 rung. Asking for any of them is refused, never read as something
+    else: the two ModelConfig fields that old files still carry refuse a
+    truthy value, and the names that are gone are gone everywhere."""
+
+    @pytest.mark.parametrize("build,exc,match", [
+        (lambda: ModelConfig(use_pallas=True, bn_pallas=True),
+         ValueError, "removed in PR 29"),
+        (lambda: ModelConfig(use_pallas=True, pallas_fused=True),
+         ValueError, "removed in PR 29"),
+        (lambda: _cfg("fp8"), ValueError, "precision must be one of"),
+        (lambda: ModelConfig(quant="fp8"), TypeError, "quant"),
+    ], ids=["bn_pallas", "pallas_fused", "precision_fp8", "model_quant"])
+    def test_dataclass_refuses(self, build, exc, match):
+        with pytest.raises(exc, match=match):
+            build()
+
+    @pytest.mark.parametrize("argv", [["--precision", "fp8"],
+                                      ["--pallas_fused"]],
+                             ids=["precision_fp8", "pallas_fused"])
+    def test_cli_refuses(self, argv, capsys):
+        from dcgan_tpu.train.cli import build_parser
+
+        with pytest.raises(SystemExit):
+            build_parser().parse_args(argv)
+        assert argv[-1].lstrip("-") in capsys.readouterr().err
+
+    def test_falsy_values_normalize_to_the_defaults(self):
+        # the spellings benchmark/configs/*.json carry
+        m = ModelConfig(use_pallas=True, bn_pallas=False, pallas_fused=False)
+        assert m == ModelConfig(use_pallas=True)
+        assert (m.bn_pallas, m.pallas_fused) == (None, False)
+
+    def test_nothing_reads_the_retired_fields(self):
+        """The two fields are names only: no module of the program mentions
+        them outside config.py."""
+        import os
+
+        import dcgan_tpu
+
+        root = os.path.dirname(dcgan_tpu.__file__)
+        hits = []
+        for d, _, files in os.walk(root):
+            for f in files:
+                path = os.path.join(d, f)
+                if f.endswith(".py") and path != os.path.join(root,
+                                                              "config.py"):
+                    text = open(path).read()
+                    hits += [(os.path.relpath(path, root), w)
+                             for w in ("bn_pallas", "pallas_fused", "fp8",
+                                       "float8") if w in text]
+        assert not hits
 
 
 class TestMasterWeights:
-    """bf16/fp8 keep an f32 master copy of the Adam FIRST moment
+    """bf16 keeps an f32 master copy of the Adam FIRST moment
     (mu_dtype=f32); params and the sqrt-bound second moment stay in the
     param dtype. Verified structurally (eval_shape — no compute)."""
 
-    def _state_shapes(self, precision, pallas_fused=False):
+    def _state_shapes(self, precision):
         from dcgan_tpu.train.steps import make_train_step
 
-        cfg = _cfg(precision, pallas_fused)
+        cfg = _cfg(precision)
         fns = make_train_step(cfg)
         return cfg, fns, jax.eval_shape(fns.init, jax.random.key(0))
 
@@ -114,14 +157,13 @@ class TestMasterWeights:
         _, _, state_d = self._state_shapes("")
         assert count_master_f32_leaves(state_d) == 0
 
-    @pytest.mark.parametrize("precision,fused", [
-        ("", False), ("bf16", False), ("bf16", True), ("fp8", True)])
-    def test_train_step_dtype_invariance(self, precision, fused):
+    @pytest.mark.parametrize("precision", ["", "f32", "bf16"])
+    def test_train_step_dtype_invariance(self, precision):
         # regression for the f32-cotangent bug: a single leaf changing
         # dtype across the step breaks lax.scan carries and donation
         # aliasing. The step must be a dtype-preserving state map under
-        # EVERY policy x fusion combination.
-        cfg, fns, state = self._state_shapes(precision, fused)
+        # EVERY policy.
+        cfg, fns, state = self._state_shapes(precision)
         img = jax.ShapeDtypeStruct((8, 16, 16, 3), jnp.float32)
         out, _ = jax.eval_shape(fns.train_step, state, img,
                                 jax.random.key(1))
@@ -130,43 +172,6 @@ class TestMasterWeights:
         bad = [jtu.keystr(p) for p, l in jtu.tree_flatten_with_path(out)[0]
                if ins[jtu.keystr(p)].dtype != l.dtype]
         assert not bad, f"dtype drift across train_step: {bad}"
-
-
-class TestFp8Numerics:
-    def test_large_amax_stays_finite(self):
-        # e4m3's max normal is 448 — an unscaled cast of 500 overflows to
-        # NaN; the amax scaling must keep the round-trip finite
-        x = jnp.array([500.0, -3.0, 0.25, 0.0])
-        q = fake_quant_fp8(x)
-        assert bool(jnp.all(jnp.isfinite(q)))
-        np.testing.assert_allclose(q[0], 500.0, rtol=0.08)
-
-    def test_relative_error_bound(self):
-        x = jax.random.normal(jax.random.key(0), (512,))
-        q = fake_quant_fp8(x)
-        # 3 mantissa bits: worst-case relative rounding error 2^-4
-        err = jnp.abs(q - x) / jnp.maximum(jnp.abs(x), 1e-3)
-        assert float(jnp.max(err)) < 0.0726
-
-    def test_preserves_dtype_shape_and_zero(self):
-        x = jax.random.normal(jax.random.key(1), (4, 6), jnp.bfloat16)
-        q = fake_quant_fp8(x)
-        assert q.dtype == jnp.bfloat16 and q.shape == x.shape
-        z = fake_quant_fp8(jnp.zeros((8,)))
-        np.testing.assert_array_equal(z, jnp.zeros((8,)))
-
-    def test_stage_gating_by_resolution(self):
-        # fp8 operand quantization is scoped to stages whose feature maps
-        # reach 64px — the boundary stages and every stage of small models
-        # run clean bf16
-        from dcgan_tpu.models.dcgan import _FP8_MIN_RES, _stage_quant
-
-        cfg = ModelConfig(output_size=128, quant="fp8")
-        assert _FP8_MIN_RES == 64
-        assert _stage_quant(cfg, 32) == ""
-        assert _stage_quant(cfg, 64) == "fp8"
-        assert _stage_quant(cfg, 128) == "fp8"
-        assert _stage_quant(ModelConfig(output_size=128), 128) == ""
 
 
 class TestInt8Serving:

@@ -23,8 +23,6 @@ TRAIN_SPANS = ("train/next", "train/dispatch", "train/consume",
                "train/services")
 PALLAS_NAMES = {
     "ops/pallas_attention.py": ["flash_fwd", "flash_dq_dkv"],
-    "ops/pallas_kernels.py": ["bn_moments", "bn_apply", "bn_bwd"],
-    "ops/pallas_fused.py": ["fused_conv_stats", "fused_conv_apply"],
 }
 
 
@@ -249,15 +247,15 @@ class TestTrainerSpans:
                 1e3 * sum(host) / len(host), rel=1e-9), p
 
 
-@pytest.fixture(scope="module")
-def lowered_sagan16():
-    """The sagan128 family's step at 16 px, lowered with its locations."""
+def lowered_at_16px(preset):
+    """A preset's train step cut to 16 px (attention at 8 x 8) and batch 4,
+    lowered with its locations."""
     import dataclasses
 
     from dcgan_tpu.presets import get_preset
     from dcgan_tpu.train import make_train_step
 
-    cfg = get_preset("sagan128")
+    cfg = get_preset(preset)
     cfg = dataclasses.replace(
         cfg, batch_size=4, model=dataclasses.replace(
             cfg.model, output_size=16, gf_dim=8, df_dim=8, attn_res=8))
@@ -268,6 +266,30 @@ def lowered_sagan16():
     text = jax.jit(fns.train_step).lower(state, images, key).as_text(
         debug_info=True)
     return set(re.findall(r'loc\("([^"]+)"', text))
+
+
+@pytest.fixture(scope="module")
+def lowered_sagan16():
+    return lowered_at_16px("sagan128")
+
+
+class TestKernelPolicy:
+    """The one kernel decision of the image models (PR 29): `use_pallas`
+    puts attention on the flash kernels and changes nothing else, so every
+    Pallas call of a step is one of the two names `flash_attn_roofline`,
+    `flash_fwd_ms` and `flash_bwd_ms` read."""
+
+    @pytest.mark.parametrize("preset", ["sagan64", "sagan128",
+                                        "sagan256-lc"])
+    def test_a_flash_preset_runs_the_flash_kernels_and_no_other(self,
+                                                                preset):
+        from dcgan_tpu.presets import get_preset
+
+        assert get_preset(preset).model.use_pallas
+        locs = lowered_at_16px(preset)
+        kernels = {loc.split("/")[-2] for loc in locs
+                   if loc.endswith("/pallas_call")}
+        assert kernels == set(PALLAS_NAMES["ops/pallas_attention.py"])
 
 
 class TestNamesInTheLoweredStep:

@@ -2,8 +2,8 @@
 
 What must hold: the spread/aggregation math the docs tables are rendered
 from (tools/capture_all.py), the trainer-log parsing bench_trainer_loop's
-throughput derivation rests on, and a CPU execution of the matmul-rate and
-step-profile tools end to end (tiny shapes — the contract is "runs and
+throughput derivation rests on, and a CPU execution of the matmul-rate
+tool end to end (tiny shapes — the contract is "runs and
 prints well-formed JSON", the numbers only mean anything on a chip).
 """
 
@@ -106,13 +106,6 @@ class TestSpread:
                   "ms_per_matmul": 0.5},
                  {"form": "matmul", "m": 8, "k": 8, "n": 8, "tflops": 2.0,
                   "ms_per_matmul": 0.25}]},  # best per shape wins
-            {"section": "roofline", "label": "step-profile", "rc": 0,
-             "date": "d1", "parsed": [
-                 {"label": "step-profile", "batch": 64, "scan": 50,
-                  "step_ms": 3.0, "fwd_ms": 2.0, "bwd_opt_ms_derived": 1.0,
-                  "g_forward_ms": 1.5, "adam_ms": 1.2,
-                  "flops_per_step": 192e9, "bytes_accessed": 2.3e9,
-                  "tflops_effective": 64.0, "hbm_gbps_effective": 766.0}]},
             {"section": "roofline", "label": "trainer-loop", "rc": 0,
              "date": "d1", "parsed": [
                  {"label": "trainer-loop", "images_per_sec_chip": 19000.0,
@@ -124,7 +117,6 @@ class TestSpread:
         ]
         text = "\n".join(_render_roofline(rows))
         assert "| 8×8×8 | 2.0 | 0.25 |" in text   # best-per-shape
-        assert "192.0 GFLOP" in text
         assert "19000 img/s/chip" in text
         assert "9000000000" not in text
 
@@ -144,30 +136,6 @@ class TestSpread:
         assert _mpx_cell("dcgan256-attn128-flash", 48.9) == "3.2"
         assert _mpx_cell("dcgan64-headline", 20000.0) == "81.9"
         assert _mpx_cell("unknowable", 100.0) == "—"
-
-    def test_per_family_scan_annotation(self):
-        """VERDICT Weak #6: a scanning family's roofline row must either
-        carry the trip-exact stamp (new captures) or flag the counted-once
-        undercount (pre-fix captures) — never republish the bad FLOP count
-        bare."""
-        def profile_row(**kw):
-            base = {"label": "step-profile", "preset": "wgan-gp",
-                    "batch": 64, "scan": 50, "step_ms": 2.85,
-                    "fwd_ms": 1.36, "bwd_opt_ms_derived": 1.49,
-                    "g_forward_ms": 1.0, "adam_ms": 1.0,
-                    "flops_per_step": 279.6e9, "bytes_accessed": 2.85e9,
-                    "tflops_effective": 20.6, "hbm_gbps_effective": 225.0}
-            base.update(kw)
-            return {"section": "roofline", "label": "step-profile",
-                    "rc": 0, "date": "d1", "parsed": [base]}
-
-        old = "\n".join(_render_roofline([profile_row()]))
-        assert "wgan-gp (scanned ×5)\\*" in old
-        assert "count the ×5 scan body once" in old
-        new = "\n".join(_render_roofline(
-            [profile_row(scan_trips={"n_critic": 5})]))
-        assert "scanned ×5, trip-exact" in new
-        assert "body once" not in new
 
     def test_render_docs_end_to_end(self, tmp_path, monkeypatch):
         """render_docs over a synthetic captures log into temp docs: every
@@ -979,31 +947,11 @@ class TestToolsRunOnCpu:
         if d256 is not None and d512 is not None and d512 > 0:
             assert d512 >= d256
 
-    def test_step_profile_cpu(self):
-        env = dict(os.environ, BENCH_PLATFORM="cpu", JAX_PLATFORMS="cpu",
-                   BENCH_BATCH="8", BENCH_SCAN="2", BENCH_WINDOWS="1",
-                   # keep CALLS (= BENCH_STEPS//BENCH_SCAN) at 2 — the
-                   # sync-amortization default of 400 steps/window is a
-                   # chip policy, ~200x the acceptable CPU smoke work
-                   BENCH_STEPS="4")
-        res = subprocess.run(
-            [sys.executable, "tools/step_profile.py"], cwd=REPO, env=env,
-            capture_output=True, text=True, timeout=600)
-        assert res.returncode == 0, res.stderr[-500:]
-        lines = [json.loads(l) for l in res.stdout.splitlines()
-                 if l.startswith("{")]
-        comps = {p["component"] for p in lines if "component" in p}
-        assert comps == {"train_step", "fwd_losses", "g_forward",
-                         "adam_applies", "resident/train_step"}
-        summ = lines[-1]
-        assert summ["label"] == "step-profile"
-        assert summ["step_ms"] > 0 and summ["fwd_ms"] > 0
-
 
 class TestBenchEnvLabels:
-    """bench_model_config's label is the join key between capture rows and
-    step_profile rooflines; it must reflect the attention that actually
-    runs AFTER the BENCH_ATTN_RES override (ADVICE r5 #2)."""
+    """bench_model_config's label keys the capture rows; it must reflect
+    the attention that actually runs AFTER the BENCH_ATTN_RES override
+    (ADVICE r5 #2)."""
 
     def _label(self, **env):
         from dcgan_tpu.utils.bench_env import bench_model_config
@@ -1013,21 +961,20 @@ class TestBenchEnvLabels:
         assert self._label() == "headline"
         assert self._label(BENCH_SIZE="128") == "dcgan128"
         assert self._label(BENCH_ATTN="1") == "sagan64-attn"
-        assert self._label(BENCH_ATTN="1", BENCH_PALLAS="1",
-                           BENCH_BN_PALLAS="0") == "sagan64-attn-flash"
-        assert self._label(BENCH_PALLAS="1") == "headline-pallas"
-        assert self._label(BENCH_PALLAS="1", BENCH_BN_PALLAS="0") \
-            == "headline-pallas-xlabn"
+        assert self._label(BENCH_ATTN="1", BENCH_PALLAS="1") \
+            == "sagan64-attn-flash"
+        # without attention the flag selects nothing: the same program, the
+        # same row
+        assert self._label(BENCH_PALLAS="1") == "headline"
         assert self._label(BENCH_ATTN="1", BENCH_SN="1") \
             == "sagan64-attn-sn"
 
     def test_attn_res_override_labels_match_bench_matrix(self):
         """The ADVICE r5 #2 scenario: a BENCH_ATTN_RES config running flash
-        attention must not be labeled '-pallas-xlabn' (declared
-        no-Pallas-kernel-runs); long-context labels match capture_all's
-        '<family>-attn<R>-{flash,dense}' naming."""
+        attention is labeled by the attention that runs; long-context
+        labels match capture_all's '<family>-attn<R>-{flash,dense}'
+        naming."""
         assert self._label(BENCH_SIZE="256", BENCH_ATTN_RES="128",
-                           BENCH_PALLAS="1", BENCH_BN_PALLAS="0") \
-            == "dcgan256-attn128-flash"
+                           BENCH_PALLAS="1") == "dcgan256-attn128-flash"
         assert self._label(BENCH_SIZE="256", BENCH_ATTN_RES="128") \
             == "dcgan256-attn128-dense"

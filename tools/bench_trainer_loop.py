@@ -221,8 +221,7 @@ def _pipeline_mode() -> None:
     perf/device/{step_ms,idle_gap_ms,compute_ms,span_ms} digest next to
     the host-side occupancy numbers — the same measurement path the
     fleet runs, not a bench-only harness. Per-step FLOPs are
-    conservation-equal across the arms (tools/step_profile.py
-    PIPELINE_GD=1 proves it), so the A/B is a regression guard: the
+    conservation-equal across the arms, so the A/B is a regression guard: the
     device idle share of the window must not grow and devstep_ms must be
     no worse. NOTE: on CPU test hosts the capture falls back to the
     op-level executor thread-group track (utils/trace.py), so the device

@@ -69,17 +69,16 @@ STEPS = [
     # the attention block, XLA for BN; these rows keep that comparison
     # against the dense rows above live in the matrix (the sagan presets
     # default to this split since rev 2)
-    _bench("sagan64-attn-flash", BENCH_ATTN="1", BENCH_PALLAS="1",
-           BENCH_BN_PALLAS="0"),
+    _bench("sagan64-attn-flash", BENCH_ATTN="1", BENCH_PALLAS="1"),
     _bench("sagan64-attn-sn-flash", BENCH_ATTN="1", BENCH_SN="1",
-           BENCH_PALLAS="1", BENCH_BN_PALLAS="0"),
+           BENCH_PALLAS="1"),
     # the attention family's batch-scaling points: does the flash form keep
     # the headline's rising-throughput curve (DESIGN.md §1b) once the
     # score-matrix traffic is gone?
     _bench("sagan64-attn-flash-b256", BENCH_ATTN="1", BENCH_PALLAS="1",
-           BENCH_BN_PALLAS="0", BENCH_BATCH="256"),
+           BENCH_BATCH="256"),
     _bench("sagan64-attn-flash-b512", BENCH_ATTN="1", BENCH_PALLAS="1",
-           BENCH_BN_PALLAS="0", BENCH_BATCH="512"),
+           BENCH_BATCH="512"),
     # the full sagan64 preset (hinge + SN both nets + TTUR + EMA on the
     # rev-2 flash/XLA-BN split) — the recipe row, vs the knob rows above
     _bench("sagan64", BENCH_PRESET="sagan64"),
@@ -90,8 +89,7 @@ STEPS = [
     # inference (sampler) rows for the attention family — the serve path
     # with the flash kernels in the generator
     _bench("sagan64-attn-flash-sample", BENCH_MODE="sample",
-           BENCH_ATTN="1", BENCH_PALLAS="1", BENCH_BN_PALLAS="0"),
-    _bench("dcgan64-pallas", BENCH_PALLAS="1"),
+           BENCH_ATTN="1", BENCH_PALLAS="1"),
     _bench("dcgan64-shard_map", BENCH_BACKEND="shard_map"),
     _bench("dcgan64-sample", BENCH_MODE="sample"),
     _bench("dcgan128-sample", BENCH_MODE="sample", BENCH_PRESET="dcgan128"),
@@ -140,32 +138,8 @@ STEPS = [
      {}, 900),
     ("roofline", "matmul-rate", [sys.executable, "tools/matmul_rate.py"],
      {}, 600),
-    ("roofline", "step-profile", [sys.executable, "tools/step_profile.py"],
-     {}, 600),
-    # per-family profiles for the configs below the 4x north star
-    # (VERDICT r4 #5): same tool, same knobs as their bench rows — the
-    # numerator/denominator behind each family's binding-roof reading
-    # (DESIGN.md §1c)
-    ("roofline", "step-profile-dcgan128",
-     [sys.executable, "tools/step_profile.py"],
-     {"BENCH_PRESET": "dcgan128"}, 600),
-    ("roofline", "step-profile-wgan-gp",
-     [sys.executable, "tools/step_profile.py"],
-     {"BENCH_PRESET": "wgan-gp"}, 600),
-    ("roofline", "step-profile-sagan64-attn",
-     [sys.executable, "tools/step_profile.py"],
-     {"BENCH_ATTN": "1"}, 600),
-    ("roofline", "step-profile-sagan64-attn-flash",
-     [sys.executable, "tools/step_profile.py"],
-     {"BENCH_ATTN": "1", "BENCH_PALLAS": "1", "BENCH_BN_PALLAS": "0"},
-     600),
-    ("roofline", "step-profile-stylegan64",
-     [sys.executable, "tools/step_profile.py"],
-     {"BENCH_PRESET": "stylegan64"}, 600),
     ("roofline", "trainer-loop",
      [sys.executable, "tools/bench_trainer_loop.py"], {}, 900),
-    ("roofline", "pallas-op",
-     [sys.executable, "tools/bench_pallas_op.py"], {}, 600),
     ("fid", "fid-trajectory-chip",
      [sys.executable, "tools/fid_trajectory.py", "--preset", "cifar10-cond",
       "--snapshots", "0,500,2000,5000", "--num_samples", "10000", "--kid"],
@@ -396,13 +370,10 @@ def _mpx_cell(label, img_per_sec):
 
 
 def _render_roofline(rows):
-    """Roofline group: matmul sweep (best per shape), step profile (best
-    window = min step_ms), trainer hot loop (best + spread)."""
+    """Roofline group: matmul sweep (best per shape), trainer hot loop
+    (best + spread)."""
     shapes = {}      # (m, n) -> best tflops row (+date)
-    profiles = []
     trainer = []
-    bn_ops = {}      # shape -> LATEST jnp-vs-pallas row (a ratio has no
-    #                  meaningful best-of; rows in one run share a window)
     for r in rows:
         if r["section"] != "roofline" or r["rc"] != 0:
             continue
@@ -412,10 +383,6 @@ def _render_roofline(rows):
                 key = (p["m"], p.get("k", p["n"]), p["n"])
                 if key not in shapes or p["tflops"] > shapes[key]["tflops"]:
                     shapes[key] = dict(p, date=r["date"])
-            elif p.get("form") == "bn_op":
-                bn_ops[tuple(p["shape"])] = dict(p, date=r["date"])
-            elif p.get("label") == "step-profile":
-                profiles.append(dict(p, date=r["date"]))
             elif p.get("label") == "trainer-loop" and \
                     p.get("images_per_sec_chip"):
                 trainer.append(dict(p, date=r["date"]))
@@ -430,98 +397,6 @@ def _render_roofline(rows):
             p = shapes[(m, k, n)]
             out.append(f"| {m}×{k}×{n} | {p['tflops']} | "
                        f"{p['ms_per_matmul']} | {p['date']} |")
-    by_preset = {}
-    for p in profiles:
-        by_preset.setdefault(p.get("preset", "headline"), []).append(p)
-    head = by_preset.pop("headline", None)
-    if head:
-        best = min(head, key=lambda p: p["step_ms"])
-        out += ["", f"Headline step profile (tools/step_profile.py, best "
-                f"window of n={len(head)} capture(s), {best['date']}; "
-                "scanned dispatch, batch "
-                f"{best['batch']}): step {best['step_ms']} ms = forward "
-                f"{best['fwd_ms']} ms + backward+opt "
-                f"{best['bwd_opt_ms_derived']} ms (derived); G forward "
-                f"alone {best['g_forward_ms']} ms, both Adam chains alone "
-                f"{best['adam_ms']} ms."]
-        if best.get("flops_per_step"):
-            gflop = best["flops_per_step"] / 1e9
-            out += [f"XLA cost model: {gflop:.1f} GFLOP and "
-                    f"{best.get('bytes_accessed', 0) / 2**30:.2f} GiB "
-                    "accessed per step "
-                    f"(arithmetic intensity "
-                    f"{best['flops_per_step'] / best['bytes_accessed']:.0f} "
-                    "FLOP/byte) -> effective "
-                    f"{best.get('tflops_effective', 0):.1f} TFLOP/s and "
-                    f"{best.get('hbm_gbps_effective', 0):.0f} GB/s at the "
-                    "best-window step time. See DESIGN.md \"Roofline\" for "
-                    "the reading."]
-    if by_preset:
-        def _scan_tag(name, row):
-            """In-step lax.scan annotation (VERDICT Weak #6). New captures
-            carry a scan_trips stamp — step_profile now counts those
-            programs through a fully-unrolled lowering, so their FLOP/bytes
-            are trip-exact. Pre-stamp captures of scanning configs (the
-            trip counts come from the preset registry) counted the scan
-            body ONCE: flag them as undercounting instead of republishing
-            the bad number as truth."""
-            trips = row.get("scan_trips")
-            if trips:
-                mult = " ".join(f"×{v}" for v in trips.values())
-                return f" (scanned {mult}, trip-exact)", None
-            try:
-                from dcgan_tpu.presets import get_preset
-
-                cfg = get_preset(name)
-                k = max(cfg.n_critic, cfg.grad_accum)
-            except Exception:
-                return "", None
-            if k <= 1:
-                return "", None
-            return (f" (scanned ×{k})",
-                    f"\\* {name}: this capture predates the scan-aware "
-                    f"count — its GFLOP/GiB columns count the ×{k} scan "
-                    f"body once (undercounted roughly ×{k}); re-harvest "
-                    f"tools/step_profile.py for trip-exact numbers.")
-        notes = []
-        out += ["", "Per-family step profiles (same tool and knobs as each "
-                "family's bench row; best window per family) — the measured "
-                "numerator/denominator behind the binding-roof reading in "
-                "DESIGN.md §1c:", "",
-                "| family | step ms | fwd ms | GFLOP/step | GiB/step | "
-                "eff TFLOP/s | eff GB/s | captured |",
-                "|---|---|---|---|---|---|---|---|"]
-        for name in sorted(by_preset):
-            b = min(by_preset[name], key=lambda p: p["step_ms"])
-            fl = b.get("flops_per_step")
-            ba = b.get("bytes_accessed")
-            tag, note = _scan_tag(name, b)
-            if note:
-                tag += "\\*"
-                notes.append(note)
-            out.append(
-                f"| {name}{tag} (b{b['batch']}) | {b['step_ms']} | "
-                f"{b['fwd_ms']} "
-                + (f"| {fl / 1e9:.1f} | " if fl else "| — | "))
-            out[-1] += (f"{ba / 2**30:.2f} | " if ba else "— | ")
-            out[-1] += (f"{b.get('tflops_effective', 0):.1f} | "
-                        f"{b.get('hbm_gbps_effective', 0):.0f} | "
-                        f"{b['date']} |")
-        for note in notes:
-            out += ["", note]
-    if bn_ops:
-        date = max(p["date"] for p in bn_ops.values())
-        out += ["", f"Op-level fused-BN+act, Pallas vs XLA (tools/"
-                f"bench_pallas_op.py, fwd+bwd, latest run {date}) — the "
-                "measurement behind use_pallas being a capability flag, "
-                "not a perf flag (DESIGN.md §8b):", "",
-                "| activation shape | XLA ms | Pallas ms | XLA/Pallas |",
-                "|---|---|---|---|"]
-        for shape in sorted(bn_ops):
-            p = bn_ops[shape]
-            out.append(f"| {list(shape)} | {p['jnp_ms']} | "
-                       f"{p['pallas_ms']} | "
-                       f"{p['ratio_jnp_over_pallas']}× |")
     if trainer:
         best = max(trainer, key=lambda p: p["images_per_sec_chip"])
         sp = _spread([p["images_per_sec_chip"] for p in trainer])
@@ -771,8 +646,8 @@ def render_docs() -> None:
                     f"({b64:,.0f} img/s best) {n64} core(s) suffice at the "
                     "best-capture loader rate.")
 
-    # roofline section (VERDICT r3 #1/#4): sustained matmul rate, step
-    # cost/profile, and the real trainer loop measured as one group
+    # roofline section (VERDICT r3 #1/#4): sustained matmul rate and the
+    # real trainer loop measured as one group
     roof_lines = _render_roofline(rows)
     if roof_lines:
         lines += [""] + roof_lines

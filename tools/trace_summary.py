@@ -6,8 +6,8 @@ on-demand --profile_trigger file) captures a Chrome-trace timeline of the
 training loop. This tool reads the `*.trace.json.gz` it writes and reports,
 for each device-track program, the execution count and per-execution
 duration — the device's OWN measurement of step time, independent of every
-host-side wall-clock harness (bench.py, StepTimer, tools/step_profile.py
-all sync through the transport; the trace does not).
+host-side wall-clock harness (bench.py and StepTimer
+sync through the transport; the trace does not).
 
     python -m dcgan_tpu.train --synthetic --profile_dir /tmp/tr ...
     python tools/trace_summary.py /tmp/tr
@@ -26,9 +26,8 @@ The committed artifact docs/assets/trace_train_step_v5e.json.gz is a real
 v5e capture of 5 per-step train_step dispatches: 2.8441-2.8458 ms each
 (±0.06%), the cleanest confirmation of the headline step time
 (DESIGN.md §1b). Note: that capture, taken on the previous machine, holds
-PROGRAM-level device events only — no per-XLA-op rows — which is why the
-§1b component split uses tools/step_profile.py's compiled sub-programs
-instead.
+PROGRAM-level device events only — no per-XLA-op rows; the per-operation
+breakdown is `benchmark/run.py --trace 1`'s (PERF.md section 5).
 
 Prints one JSON line per device program.
 """
