@@ -19,8 +19,16 @@ DeepSeek-V3 (arXiv:2412.19437), pure init/apply like the image families:
   and that partial result goes on. No token is dropped at any imbalance:
   the (token, expert) pairs are sorted by expert and the grouped matmuls
   (megablox `gmm`, a Pallas kernel whose grid follows the pairs that are
-  really here) run over a buffer sized for the worst case but visit only
-  the tiles that hold pairs. On one chip the layer runs without an
+  really here) visit only the tiles that hold pairs. The buffer they run
+  over is sized from the share of the experts held (`moe_buffer_rows`:
+  MOE_BUFFER_FACTOR times the pairs expected, 16,384 rows for 65,536
+  pairs at 16 of 256): rows are gathered straight from the tokens and
+  their results added into their tokens' rows, so what is moved follows
+  the pairs that are here. A step in which more pairs arrive than the
+  buffer has rows takes the worst-case buffer of every pair instead (a
+  `lax.cond` on the count; the counter `compact` says which ran). Where
+  the sized buffer would be the worst case (every expert held, the tiny
+  preset) there is no branch. On one chip the layer runs without an
   exchange; nothing stands in for the absent chips;
 - a multi-token module after the last trunk layer: `eh_proj([RMSNorm(Emb(
   t_{i+1})) ; RMSNorm(h_i)])`, one expert block, its own final norm, the
@@ -60,6 +68,10 @@ _megablox = importlib.import_module(
 #: rows of one grouped-matmul tile: 128 keeps the rows the kernel computes
 #: under twice the pairs routed when 16 experts see ~256 pairs each
 GMM_TILE_M = 128
+#: the grouped buffer of an expert layer holds this many times the pairs
+#: expected at the share of the experts held (`moe_buffer_rows`); a layer
+#: where more arrive takes the worst-case buffer for that step
+MOE_BUFFER_FACTOR = 4
 #: tokens of one chunk of the head + loss (logits of one chunk live at a time)
 LOSS_CHUNK = 2048
 
@@ -327,48 +339,141 @@ def _gmm_bwd(out_dtype, res, grad):
 _gmm.defvjp(_gmm_fwd, _gmm_bwd)
 
 
+def moe_buffer_rows(m: int, cfg: TokenModelConfig) -> int:
+    """Rows of the grouped buffer for `m` pairs routed: MOE_BUFFER_FACTOR
+    times the pairs expected at the share of the experts held here, in whole
+    tiles, and never more than the worst case `m`."""
+    want = -(-MOE_BUFFER_FACTOR * m * cfg.experts_held // cfg.n_routed_experts)
+    return min(m, -(-want // GMM_TILE_M) * GMM_TILE_M)
+
+
+def _tile_rows(sizes, tm: int):
+    """Rows the grouped kernels compute at tiles of `tm` rows: every tile a
+    non-empty group touches."""
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    tiles = jnp.where(sizes > 0, (ends - 1) // tm - starts // tm + 1, 0)
+    return jnp.sum(tiles) * tm
+
+
+def _held_experts(xs, e, sizes, filled, cd):
+    """The held experts' SwiGLU over sorted rows xs [R, H] (`e` in the
+    compute dtype); rows past the pairs that are here come out zero."""
+    g = _gmm(xs, e["gate"], sizes, cd)
+    u = _gmm(xs, e["up"], sizes, cd)
+    act = jnp.where(filled, jax.nn.silu(g.astype(jnp.float32))
+                    * u.astype(jnp.float32), 0).astype(cd)
+    return jnp.where(filled, _gmm(act, e["down"], sizes, cd), 0)
+
+
+def _routed_whole(cd, x, w_here, e, shared, order, sizes):
+    """`shared` plus the weighted held experts over a buffer of ALL m pairs,
+    the absent ones sorted behind the pairs that are here: right at any
+    imbalance, and m rows moved whatever arrives."""
+    (t, h), k = x.shape, w_here.shape[1]
+    m = t * k
+    with jax.named_scope("dispatch"):
+        inv = jnp.zeros((m,), jnp.int32).at[order].set(
+            jnp.arange(m, dtype=jnp.int32), unique_indices=True)
+        filled = (jnp.arange(m) < jnp.sum(sizes))[:, None]
+        xs = _permute(jnp.repeat(x.astype(cd), k, axis=0), order, inv)
+        xs = jnp.where(filled, xs, 0)
+    with jax.named_scope("experts"):
+        ys = _held_experts(xs, e, sizes, filled, cd)
+    with jax.named_scope("combine"):
+        back = _permute(ys, inv, order).reshape(t, k, h)
+        return jnp.einsum("tk,tkh->th", w_here,
+                          back.astype(jnp.float32)) + shared
+
+
+def _routed_sized(c, cd, x, w_here, e, shared, order, sizes):
+    """The same sum over a buffer of the first `c` sorted pairs, for a layer
+    with at most `c` pairs here: rows gathered straight from the tokens,
+    their results added into their tokens' rows."""
+    (t, h), k = x.shape, w_here.shape[1]
+    with jax.named_scope("dispatch"):
+        rows = order[:c]
+        tok = rows // k
+        filled = (jnp.arange(c) < jnp.sum(sizes))[:, None]
+        xs = jnp.where(filled, x.astype(cd)[tok], 0)
+    with jax.named_scope("experts"):
+        ys = _held_experts(xs, e, sizes, filled, cd)
+    with jax.named_scope("combine"):
+        w_c = w_here.reshape(t * k).at[rows].get(unique_indices=True)
+        return shared.at[tok].add(w_c[:, None] * ys.astype(jnp.float32))
+
+
+def _routed_branches(c, cd):
+    """(whole, sized): `lax.cond` takes the second where its predicate
+    holds."""
+    return (functools.partial(_routed_whole, cd),
+            functools.partial(_routed_sized, c, cd))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _routed(c, cd, fits, x, w_here, e, shared, order, sizes):
+    """`_routed_sized` where the pairs here fit `c` rows (`fits`), else
+    `_routed_whole`. Its own VJP, so that each branch keeps its
+    intermediates to itself: differentiating the `cond` would have the
+    branch taken write zeros for everything the other would have kept."""
+    whole, sized = _routed_branches(c, cd)
+    return jax.lax.cond(fits, sized, whole,
+                        x, w_here, e, shared, order, sizes)
+
+
+def _routed_fwd(c, cd, fits, x, w_here, e, shared, order, sizes):
+    # `shared` is only added to: its cotangent is the result's, so neither
+    # it nor that cotangent has to pass through the backward's `cond`
+    return (_routed(c, cd, fits, x, w_here, e, shared, order, sizes),
+            (fits, x, w_here, e, order, sizes))
+
+
+def _routed_bwd(c, cd, res, g):
+    fits, *diff, order, sizes = res
+
+    def back(branch):
+        return lambda: jax.vjp(lambda *d: branch(
+            *d, jnp.zeros_like(g), order, sizes), *diff)[1](g)
+    whole, sized = _routed_branches(c, cd)
+    grads = jax.lax.cond(fits, back(sized), back(whole))
+    return (None, *grads, g, None, None)
+
+
+_routed.defvjp(_routed_fwd, _routed_bwd)
+
+
 def moe_apply(p: Pytree, bias, x, cfg: TokenModelConfig):
     """The expert layer's share of this chip over tokens x [T, H] (normed,
     float32): (y [T, H] float32, counters). `y` holds the selected experts
     that are held here, weighted, plus the shared expert once."""
     cd, _ = _dtypes(cfg)
-    t, h = x.shape
+    t, _ = x.shape
     k, held = cfg.num_experts_per_tok, cfg.experts_held
     m = t * k
+    c = moe_buffer_rows(m, cfg)
     with jax.named_scope("route"):
         idx, w = route(p["router"]["w"], bias, x, cfg)
         local = idx - cfg.first_expert
         here = (local >= 0) & (local < held)
         local = jnp.where(here, local, held).reshape(m)   # absent -> last
+        w_here = jnp.where(here, w, 0.0)
     with jax.named_scope("dispatch"):
         order = jnp.argsort(local, stable=True)
-        inv = jnp.zeros((m,), jnp.int32).at[order].set(
-            jnp.arange(m, dtype=jnp.int32), unique_indices=True)
         sizes = jnp.bincount(local, length=held + 1)[:held].astype(jnp.int32)
-        n_here = jnp.sum(sizes)
-        filled = (jnp.arange(m) < n_here)[:, None]
-        xs = _permute(jnp.repeat(x.astype(cd), k, axis=0), order, inv)
-        xs = jnp.where(filled, xs, 0)
     with jax.named_scope("experts"):
-        e = p["experts"]
-        g = _gmm(xs, e["gate"].astype(cd), sizes, cd)
-        u = _gmm(xs, e["up"].astype(cd), sizes, cd)
-        act = jnp.where(filled, jax.nn.silu(g.astype(jnp.float32))
-                        * u.astype(jnp.float32), 0).astype(cd)
-        ys = _gmm(act, e["down"].astype(cd), sizes, cd)
-        ys = jnp.where(filled, ys, 0)
+        e = {n: a.astype(cd) for n, a in p["experts"].items()}
     with jax.named_scope("shared"):
         shared = swiglu_apply(p["shared"], x, cd)
-    with jax.named_scope("combine"):
-        back = _permute(ys, inv, order).reshape(t, k, h)
-        y = jnp.einsum("tk,tkh->th", jnp.where(here, w, 0.0),
-                       back.astype(jnp.float32)) + shared
-    # rows the kernel computes: every tile a non-empty group touches
-    ends = jnp.cumsum(sizes)
-    starts = ends - sizes
-    tm = _gmm_tile_m(m)
-    tiles = jnp.where(sizes > 0, (ends - 1) // tm - starts // tm + 1, 0)
-    return y, {"counts": sizes, "rows": jnp.sum(tiles) * tm}
+    rows = _tile_rows(sizes, _gmm_tile_m(m))
+    if c == m:
+        y = _routed_whole(cd, x, w_here, e, shared, order, sizes)
+        compact = jnp.zeros((), jnp.float32)
+    else:
+        fits = jnp.sum(sizes) <= c
+        y = _routed(c, cd, fits, x, w_here, e, shared, order, sizes)
+        compact = fits.astype(jnp.float32)
+        rows = jnp.where(fits, _tile_rows(sizes, _gmm_tile_m(c)), rows)
+    return y, {"counts": sizes, "rows": rows, "compact": compact}
 
 
 def block_apply(p: Pytree, bias, x, cfg: TokenModelConfig, rope):
@@ -423,7 +528,8 @@ def token_loss(params: Pytree, bias: Pytree, ids, cfg: TokenModelConfig
     cross-entropy of the trunk over positions 0..S-2, plus
     `mtp_loss_weight` times that of the multi-token module over 0..S-3.
     Returns (loss, {"loss", "loss_mtp", "counts": {layer: [held]},
-    "rows": rows the grouped kernels computed})."""
+    "rows": rows the grouped kernels computed, "compact": the share of the
+    expert layers that ran over the sized buffer})."""
     b, s = ids.shape
     rope = rotary_tables(s, cfg.qk_rope_head_dim, cfg.rope_theta)
     # every block is recomputed in the backward pass: its input is all that
@@ -469,4 +575,6 @@ def token_loss(params: Pytree, bias: Pytree, ids, cfg: TokenModelConfig
     return total, {
         "loss": loss, "loss_mtp": loss_mtp,
         "counts": {n: c["counts"] for n, c in counters.items()},
-        "rows": sum(c["rows"] for c in counters.values())}
+        "rows": sum(c["rows"] for c in counters.values()),
+        "compact": sum(c["compact"] for c in counters.values())
+        / max(len(counters), 1)}

@@ -1091,8 +1091,8 @@ def make_lm_train_step(cfg: TrainConfig, *, mesh=None) -> TrainStepFns:
         (_, aux), grads = jax.value_and_grad(
             lambda p: token_loss(p, bias, ids, mcfg), has_aux=True)(params)
         if n_data > 1:
-            grads, aux["loss"], aux["loss_mtp"] = lax.pmean(
-                (grads, aux["loss"], aux["loss_mtp"]), "data")
+            grads, aux["loss"], aux["loss_mtp"], aux["compact"] = lax.pmean(
+                (grads, aux["loss"], aux["loss_mtp"], aux["compact"]), "data")
             aux["counts"], aux["rows"] = lax.psum(
                 (aux["counts"], aux["rows"]), "data")
         return grads, aux
@@ -1122,6 +1122,8 @@ def make_lm_train_step(cfg: TrainConfig, *, mesh=None) -> TrainStepFns:
             / jnp.maximum(pairs, 1).astype(jnp.float32),
             # rows the grouped kernels computed (whole tiles)
             "moe_rows_computed": aux["rows"].astype(jnp.float32),
+            # share of the expert layers whose pairs fit the sized buffer
+            "moe_compact_share": aux["compact"],
         }
         state = {**state, "params": params, "opt": opt_state,
                  "moe_counts": {n: state["moe_counts"][n] + c

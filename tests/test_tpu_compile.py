@@ -139,11 +139,14 @@ def test_causal_flash_attention_at_latent_attention_widths(v5e, heads, seq):
     assert "flash_fwd" in text and "flash_dq_dkv" in text
 
 
-def test_grouped_expert_matmuls_compile_at_published_widths(v5e, monkeypatch):
+@pytest.mark.parametrize("rows", [65536, 16384])
+def test_grouped_expert_matmuls_compile_at_published_widths(v5e, monkeypatch,
+                                                            rows):
     """The expert layer's grouped products (megablox `gmm` / `tgmm` through
     models/mla_moe.py::_gmm): 65,536 sorted rows (8,192 tokens x 8, the
-    worst case) against 16 experts of 2048 x 768 and back, forward and
-    gradients, with this module's own tile sizes."""
+    worst case) or the 16,384 of the buffer sized for 16 of 256 experts,
+    against 16 experts of 2048 x 768 and back, forward and gradients, with
+    this module's own tile sizes."""
     from dcgan_tpu.models import mla_moe
 
     monkeypatch.setattr(mla_moe.jax, "default_backend", lambda: "tpu")
@@ -154,7 +157,7 @@ def test_grouped_expert_matmuls_compile_at_published_widths(v5e, monkeypatch):
                        .astype(jnp.float32))
 
     text = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)),
-                    _sds((65536, 2048), jnp.bfloat16, v5e),
+                    _sds((rows, 2048), jnp.bfloat16, v5e),
                     _sds((16, 2048, 768), jnp.bfloat16, v5e),
                     _sds((16, 768, 2048), jnp.bfloat16, v5e),
                     _sds((16,), jnp.int32, v5e))
