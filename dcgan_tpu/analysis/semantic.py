@@ -699,33 +699,37 @@ def enumerate_audits() -> Tuple[List[ProgramAudit], List[CoverageRow]]:
                         f"returns to the plain rows)"))
 
         if backend == "gspmd":
-            # The one-network token family (models/mla_moe.py): its
-            # likelihood step at the tiny preset over the same 2-way data
-            # mesh (loss and gradient per shard inside a shard_map, the
-            # kernels in interpret mode), named @lm. The family's surface
-            # is "init" and "train_step" alone, and the warmup plan covers
-            # it from the same `_program_args` the trainer warms.
+            # The one-network token archs (models/mla_moe.py, named @lm;
+            # models/loop_lm.py, named @loop_lm): the likelihood step at
+            # each tiny preset over the same 2-way data mesh (loss and
+            # gradient per shard inside a shard_map, the looped arch's
+            # kernels in interpret mode). A token arch's surface is "init"
+            # and "train_step" alone, and the warmup plan covers it from
+            # the same `_program_args` the trainer warms.
             from dcgan_tpu.presets import get_preset
 
-            cfg_lm = get_preset("mla_moe_tiny", mesh=cfg.mesh)
-            pt_lm = make_parallel_train(cfg_lm, mesh)
-            plan_lm, _bk_lm = warmup.build_warmup_plan(
-                cfg_lm, pt_lm, warmup.state_example(pt_lm),
-                sample_z=None, eval_z=None,
-                make_backoff_pt=lambda c, _m=mesh: make_parallel_train(
-                    c, _m))
-            coverage.append(CoverageRow(
-                variant="gspmd+lm", path=path,
-                programs=frozenset(pt_lm.programs),
-                plan=tuple(n for n, _, _ in plan_lm),
-                must_cover=frozenset({"train_step", "state_copy"})))
-            for n, f, a in plan_lm:
-                if _base(n) == "train_step":
-                    audits.append(audit_callable(
-                        f"gspmd::{n}@lm", f, a, path=path,
-                        expect_donation=True,
-                        cadence="every step of a token-family run "
-                                "(`arch=mla_moe`, `loss=lm`)"))
+            for preset, tag in (("mla_moe_tiny", "lm"),
+                                ("loop_lm_tiny", "loop_lm")):
+                cfg_lm = get_preset(preset, mesh=cfg.mesh)
+                pt_lm = make_parallel_train(cfg_lm, mesh)
+                plan_lm, _bk_lm = warmup.build_warmup_plan(
+                    cfg_lm, pt_lm, warmup.state_example(pt_lm),
+                    sample_z=None, eval_z=None,
+                    make_backoff_pt=lambda c, _m=mesh: make_parallel_train(
+                        c, _m))
+                coverage.append(CoverageRow(
+                    variant=f"gspmd+{tag}", path=path,
+                    programs=frozenset(pt_lm.programs),
+                    plan=tuple(n for n, _, _ in plan_lm),
+                    must_cover=frozenset({"train_step", "state_copy"})))
+                for n, f, a in plan_lm:
+                    if _base(n) == "train_step":
+                        audits.append(audit_callable(
+                            f"gspmd::{n}@{tag}", f, a, path=path,
+                            expect_donation=True,
+                            cadence="every step of a token-family run "
+                                    f"(`arch={cfg_lm.model.arch}`, "
+                                    "`loss=lm`)"))
 
             # the serving plane's rungs: the checkpoint-source sampler at
             # every bucket of the default doubling ladder (granule = the

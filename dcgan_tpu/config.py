@@ -166,6 +166,10 @@ class ModelConfig:
 #: language model with latent attention, routed experts and a multi-token
 #: head, trained by a likelihood step (TrainConfig.loss == LM_LOSS)
 TOKEN_ARCH = "mla_moe"
+#: the looped token family's `arch` (models/loop_lm.py): one stack of
+#: layers run `total_ut_steps` times on shared weights, an exit gate and
+#: an expected loss over the exits; the same likelihood step
+LOOP_ARCH = "loop_lm"
 LM_LOSS = "lm"
 
 
@@ -248,6 +252,79 @@ class TokenModelConfig:
     @property
     def qk_head_dim(self) -> int:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+
+@dataclasses.dataclass(frozen=True)
+class LoopModelConfig:
+    """A looped causal language model (Ouro, arXiv:2510.25741): ONE stack
+    of `num_hidden_layers` layers applied `total_ut_steps` times on shared
+    weights, the final norm and an exit gate after each pass, one head for
+    every exit, trained on the expected loss over the exits less `loss_beta`
+    times the entropy of the exit distribution. Each layer: multi-head
+    attention with rotary over the whole head and a sandwich RMSNorm (a
+    norm before AND after each branch), then SwiGLU likewise. Fields carry
+    the names of the model's public `config.json`; the defaults are a
+    small model, the presets hold the published one.
+    """
+
+    arch: str = LOOP_ARCH
+    vocab_size: int = 256
+    hidden_size: int = 64
+    num_hidden_layers: int = 2     # layers of the one stack
+    intermediate_size: int = 128   # SwiGLU width
+    num_attention_heads: int = 2
+    num_key_value_heads: int = 2   # plain multi-head: equal to the heads
+    head_dim: int = 32
+    rope_theta: float = 1000000.0
+    rms_norm_eps: float = 1e-6
+    total_ut_steps: int = 4        # passes over the stack = exits
+    early_exit_threshold: float = 1.0  # inference's; 1 (never exit early)
+                                       # is all the training step can mean
+    seq_len: int = 32              # tokens of one row of the batch
+    loss_beta: float = 0.1         # weight of the exit distribution's entropy
+    compute_dtype: str = "bfloat16"
+    param_dtype: str = "float32"
+    use_pallas: bool = True        # causal flash kernels; False = dense
+                                   # masked attention (short sequences)
+
+    num_classes = property(lambda self: 0)
+
+    def __post_init__(self):
+        if self.arch != LOOP_ARCH:
+            raise ValueError(
+                f"LoopModelConfig.arch must be {LOOP_ARCH!r}, got "
+                f"{self.arch!r}")
+        if self.num_key_value_heads != self.num_attention_heads:
+            raise ValueError(
+                "arch 'loop_lm' is plain multi-head attention: "
+                "num_key_value_heads must equal num_attention_heads")
+        if self.head_dim % 2:
+            raise ValueError("head_dim must be even (rotary pairs)")
+        if self.total_ut_steps < 1 or self.num_hidden_layers < 1:
+            raise ValueError(
+                "total_ut_steps and num_hidden_layers must be >= 1")
+        if self.early_exit_threshold != 1:
+            raise ValueError(
+                "arch 'loop_lm' trains every pass and has no inference "
+                "path: early_exit_threshold must be 1 (never exit early), "
+                f"got {self.early_exit_threshold}")
+        if self.seq_len < 2:
+            raise ValueError("seq_len must be >= 2 (a next token to score)")
+
+
+#: the archs that train one network on id batches by `LM_LOSS`, each with
+#: the dataclass that holds its model config; an arch is the name of its
+#: module under models/ (train/steps.py takes init, loss and counters from
+#: it)
+TOKEN_MODEL_CONFIGS = {TOKEN_ARCH: TokenModelConfig,
+                       LOOP_ARCH: LoopModelConfig}
+TOKEN_ARCHS = tuple(TOKEN_MODEL_CONFIGS)
+
+
+def is_token_arch(arch: str) -> bool:
+    """The one test of "a one-network token family": no sampler, no
+    critic, int32 id batches, the likelihood step."""
+    return arch in TOKEN_MODEL_CONFIGS
 
 
 @dataclasses.dataclass(frozen=True)
@@ -791,29 +868,30 @@ class TrainConfig:
             "precision": "the precision ladder rewrites the image "
                          "families' dtypes",
         }
+        arch = self.model.arch
         for name, why in image_only.items():
             if getattr(self, name):
                 raise ValueError(
                     f"{name}={getattr(self, name)!r} is an image-family "
-                    f"service ({why}); arch={TOKEN_ARCH!r} refuses it")
+                    f"service ({why}); arch={arch!r} refuses it")
         if self.steps_per_call != 1 or self.grad_accum != 1 \
                 or self.n_critic != 1:
             raise ValueError(
-                f"arch={TOKEN_ARCH!r} runs one likelihood step per call: "
+                f"arch={arch!r} runs one likelihood step per call: "
                 "steps_per_call, grad_accum and n_critic must be 1")
         if self.backend != "gspmd" or self.mesh.zero_stage != 1 \
                 or self.mesh.spatial or self.mesh.model != 1:
             raise ValueError(
-                f"arch={TOKEN_ARCH!r} runs on the gspmd backend over a "
+                f"arch={arch!r} runs on the gspmd backend over a "
                 "data-parallel mesh (no expert axis, no exchange yet)")
 
     def __post_init__(self):
-        token = self.model.arch == TOKEN_ARCH
+        token = is_token_arch(self.model.arch)
         if token != (self.loss == LM_LOSS):
             raise ValueError(
-                f"loss={LM_LOSS!r} (next-token likelihood) and model.arch="
-                f"{TOKEN_ARCH!r} go together: got loss={self.loss!r} with "
-                f"arch={self.model.arch!r}")
+                f"loss={LM_LOSS!r} (next-token likelihood) and a token arch "
+                f"({', '.join(TOKEN_ARCHS)}) go together: got "
+                f"loss={self.loss!r} with arch={self.model.arch!r}")
         if token:
             self._refuse_image_services()
         if self.precision not in ("", "f32", "bf16"):
@@ -1121,7 +1199,7 @@ def _known_fields(cls, d: Dict[str, Any], *, context: str) -> Dict[str, Any]:
 def config_from_dict(d: Dict[str, Any]) -> TrainConfig:
     d = dict(d)
     saved = dict(d.pop("model", {}))
-    cls = TokenModelConfig if saved.get("arch") == TOKEN_ARCH else ModelConfig
+    cls = TOKEN_MODEL_CONFIGS.get(saved.get("arch"), ModelConfig)
     model = cls(**_known_fields(cls, saved, context="model"))
     mesh = MeshConfig(**_known_fields(MeshConfig, dict(d.pop("mesh", {})),
                                       context="mesh"))
@@ -1252,5 +1330,10 @@ def resolve_model_config(checkpoint_dir: str, *, preset: Optional[str] = None,
                       f"r{base.output_size}); building the r{res} model",
                       file=sys.stderr)
                 base = dataclasses.replace(base, output_size=res)
+    if is_token_arch(base.arch):
+        raise ValueError(
+            f"arch={base.arch!r} is a one-network token family: its "
+            "checkpoint holds no sampler, so generate, evals, export and "
+            "serve have nothing to run from it")
     given = {k: v for k, v in (overrides or {}).items() if v is not None}
     return dataclasses.replace(base, **given)

@@ -103,6 +103,11 @@ PARTITION_RULES: Tuple[Tuple[str, LogicalSpec], ...] = (
     (r"(^|/)(attn_norm|ffn_norm|q_norm|kv_norm|final_norm|enorm|hnorm)"
      r"/scale$", REPLICATED),
     (r"^moe_(bias|counts)/\w+$", REPLICATED),
+    # -- the looped token family (models/loop_lm.py): the leaves the rows
+    # above do not name; the per-exit mass the step accumulates
+    (r"(^|/)(q_proj|k_proj|v_proj|exit_gate)/w$", REPLICATED),
+    (r"(^|/)(attn_out_norm|ffn_out_norm)/scale$", REPLICATED),
+    (r"^exit_mass$", REPLICATED),
     # Adam step counts (optax ScaleByAdamState / schedule counts)
     (r"(^|/)count$", REPLICATED),
     # the trainer's global step

@@ -36,7 +36,9 @@ DeepSeek-V3 (arXiv:2412.19437), pure init/apply like the image families:
 
 Precision policy (ops/layers.py's): float32 parameters, matmul operands in
 `compute_dtype` with float32 accumulation; the router, softmax, the norms'
-statistics and the loss in float32; the residual stream in float32.
+statistics and the loss in float32; the residual stream in float32. The
+pieces every token arch uses (the matmul, RMSNorm, rotary, SwiGLU, the
+chunked head + loss) are models/token_ops.py's.
 
 Scopes (`jax.named_scope`, PERF.md section 3): `embed`, `block<i>`, `mla`
 (`q_proj`, `kv_proj`, `rope`, `attn`, `o_proj`), `dense_ffn`, `moe`
@@ -56,6 +58,10 @@ import jax
 import jax.numpy as jnp
 
 from dcgan_tpu.config import TokenModelConfig
+from dcgan_tpu.models.token_ops import (apply_rotary, dense_causal_attention,
+                                        dtypes, head_loss, mm, normal,
+                                        rms_norm, rotary_tables, swiglu_apply,
+                                        swiglu_init)
 from dcgan_tpu.ops.pallas_attention import flash_attention
 
 Pytree = Any
@@ -72,54 +78,36 @@ GMM_TILE_M = 128
 #: expected at the share of the experts held (`moe_buffer_rows`); a layer
 #: where more arrive takes the worst-case buffer for that step
 MOE_BUFFER_FACTOR = 4
-#: tokens of one chunk of the head + loss (logits of one chunk live at a time)
-LOSS_CHUNK = 2048
-
-
-def _dtypes(cfg: TokenModelConfig):
-    return jnp.dtype(cfg.compute_dtype), jnp.dtype(cfg.param_dtype)
-
 
 # --- init ---------------------------------------------------------------------
-
-def _normal(key, shape, dtype, std=0.02):
-    return std * jax.random.normal(key, shape, dtype)
-
 
 def _mla_init(key, cfg: TokenModelConfig, dt) -> Pytree:
     h, nh = cfg.hidden_size, cfg.num_attention_heads
     ks = jax.random.split(key, 5)
     return {
-        "q_a": {"w": _normal(ks[0], (h, cfg.q_lora_rank), dt)},
+        "q_a": {"w": normal(ks[0], (h, cfg.q_lora_rank), dt)},
         "q_norm": {"scale": jnp.ones((cfg.q_lora_rank,), dt)},
-        "q_b": {"w": _normal(ks[1], (cfg.q_lora_rank, nh * cfg.qk_head_dim),
+        "q_b": {"w": normal(ks[1], (cfg.q_lora_rank, nh * cfg.qk_head_dim),
                              dt)},
-        "kv_a": {"w": _normal(
+        "kv_a": {"w": normal(
             ks[2], (h, cfg.kv_lora_rank + cfg.qk_rope_head_dim), dt)},
         "kv_norm": {"scale": jnp.ones((cfg.kv_lora_rank,), dt)},
-        "kv_b": {"w": _normal(
+        "kv_b": {"w": normal(
             ks[3], (cfg.kv_lora_rank,
                     nh * (cfg.qk_nope_head_dim + cfg.v_head_dim)), dt)},
-        "o_proj": {"w": _normal(ks[4], (nh * cfg.v_head_dim, h), dt)},
+        "o_proj": {"w": normal(ks[4], (nh * cfg.v_head_dim, h), dt)},
     }
-
-
-def _swiglu_init(key, h: int, width: int, dt) -> Pytree:
-    ks = jax.random.split(key, 3)
-    return {"gate": {"w": _normal(ks[0], (h, width), dt)},
-            "up": {"w": _normal(ks[1], (h, width), dt)},
-            "down": {"w": _normal(ks[2], (width, h), dt)}}
 
 
 def _moe_init(key, cfg: TokenModelConfig, dt) -> Pytree:
     h, f, held = cfg.hidden_size, cfg.moe_intermediate_size, cfg.experts_held
     ks = jax.random.split(key, 5)
     return {
-        "router": {"w": _normal(ks[0], (h, cfg.n_routed_experts), dt)},
-        "experts": {"gate": _normal(ks[1], (held, h, f), dt),
-                    "up": _normal(ks[2], (held, h, f), dt),
-                    "down": _normal(ks[3], (held, f, h), dt)},
-        "shared": _swiglu_init(ks[4], h, f * cfg.n_shared_experts, dt),
+        "router": {"w": normal(ks[0], (h, cfg.n_routed_experts), dt)},
+        "experts": {"gate": normal(ks[1], (held, h, f), dt),
+                    "up": normal(ks[2], (held, h, f), dt),
+                    "down": normal(ks[3], (held, f, h), dt)},
+        "shared": swiglu_init(ks[4], h, f * cfg.n_shared_experts, dt),
     }
 
 
@@ -130,7 +118,7 @@ def _block_init(key, cfg: TokenModelConfig, dt, dense: bool) -> Pytree:
              "mla": _mla_init(k_attn, cfg, dt),
              "ffn_norm": {"scale": jnp.ones((h,), dt)}}
     if dense:
-        block["dense_ffn"] = _swiglu_init(k_ffn, h, cfg.intermediate_size, dt)
+        block["dense_ffn"] = swiglu_init(k_ffn, h, cfg.intermediate_size, dt)
     else:
         block["moe"] = _moe_init(k_ffn, cfg, dt)
     return block
@@ -147,21 +135,21 @@ def token_init(key, cfg: TokenModelConfig) -> Tuple[Pytree, Pytree]:
     """(params, router biases). The bias `b` of `topk(score + b)` takes no
     gradient and no optimizer state, so it lives beside the parameters, one
     [n_routed_experts] vector per expert layer."""
-    _, dt = _dtypes(cfg)
+    _, dt = dtypes(cfg)
     n = cfg.num_hidden_layers
     ks = jax.random.split(key, n + 4)
     h = cfg.hidden_size
-    params = {"embed": {"table": _normal(ks[0], (cfg.vocab_size, h), dt)}}
+    params = {"embed": {"table": normal(ks[0], (cfg.vocab_size, h), dt)}}
     for i in range(n):
         params[f"block{i}"] = _block_init(
             ks[1 + i], cfg, dt, dense=i < cfg.first_k_dense_replace)
     params["final_norm"] = {"scale": jnp.ones((h,), dt)}
-    params["lm_head"] = {"w": _normal(ks[n + 1], (h, cfg.vocab_size), dt)}
+    params["lm_head"] = {"w": normal(ks[n + 1], (h, cfg.vocab_size), dt)}
     if cfg.num_nextn_predict_layers:
         params["mtp"] = {
             "enorm": {"scale": jnp.ones((h,), dt)},
             "hnorm": {"scale": jnp.ones((h,), dt)},
-            "eh_proj": {"w": _normal(ks[n + 2], (2 * h, h), dt)},
+            "eh_proj": {"w": normal(ks[n + 2], (2 * h, h), dt)},
             "block": _block_init(ks[n + 3], cfg, dt, dense=False),
             "final_norm": {"scale": jnp.ones((h,), dt)},
         }
@@ -172,64 +160,22 @@ def token_init(key, cfg: TokenModelConfig) -> Tuple[Pytree, Pytree]:
 
 # --- pieces ---------------------------------------------------------------------
 
-def _mm(x, w, cd, out=jnp.float32):
-    """x @ w with operands in the compute dtype and float32 accumulation."""
-    return jnp.dot(x.astype(cd), w.astype(cd),
-                   preferred_element_type=jnp.float32).astype(out)
-
-
-def rms_norm(x, scale, eps: float):
-    x = x.astype(jnp.float32)
-    var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-    return x * jax.lax.rsqrt(var + eps) * scale.astype(jnp.float32)
-
-
-def rotary_tables(seq_len: int, dim: int, theta: float):
-    """cos, sin [S, dim] (float32) for the half-split rotation."""
-    inv = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
-    ang = jnp.arange(seq_len, dtype=jnp.float32)[:, None] * inv[None, :]
-    ang = jnp.concatenate([ang, ang], axis=-1)
-    return jnp.cos(ang), jnp.sin(ang)
-
-
-def apply_rotary(x, cos, sin, interleave: bool):
-    """Rotary embedding over the last axis of x [..., S, d] (float32). With
-    `interleave` the stored dimensions are pairs (2i, 2i+1), de-interleaved
-    to halves before the rotation."""
-    x = x.astype(jnp.float32)
-    if interleave:
-        x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
-    half = x.shape[-1] // 2
-    rot = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
-    return x * cos + rot * sin
-
-
-def _dense_causal_attention(q, k, v, scale: float):
-    s = jnp.einsum("bqd,bkd->bqk", q, k,
-                   preferred_element_type=jnp.float32) * scale
-    n = q.shape[1]
-    keep = jnp.tril(jnp.ones((n, n), bool))
-    p = jax.nn.softmax(jnp.where(keep, s, -1e30), axis=-1)
-    return jnp.einsum("bqk,bkd->bqd", p.astype(v.dtype), v,
-                      preferred_element_type=jnp.float32)
-
-
 def mla_apply(p: Pytree, x, cfg: TokenModelConfig, rope):
     """Latent attention over x [B, S, H] (already normed, float32)."""
-    cd, _ = _dtypes(cfg)
+    cd, _ = dtypes(cfg)
     b, s, _ = x.shape
     nh, dn, dr, dv = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
                       cfg.qk_rope_head_dim, cfg.v_head_dim)
     with jax.named_scope("q_proj"):
-        cq = rms_norm(_mm(x, p["q_a"]["w"], cd), p["q_norm"]["scale"],
+        cq = rms_norm(mm(x, p["q_a"]["w"], cd), p["q_norm"]["scale"],
                       cfg.rms_norm_eps)
-        q = _mm(cq, p["q_b"]["w"], cd).reshape(b, s, nh, dn + dr)
+        q = mm(cq, p["q_b"]["w"], cd).reshape(b, s, nh, dn + dr)
     with jax.named_scope("kv_proj"):
-        ckv = _mm(x, p["kv_a"]["w"], cd)
+        ckv = mm(x, p["kv_a"]["w"], cd)
         k_rope = ckv[..., cfg.kv_lora_rank:]                    # [B, S, dr]
         ckv = rms_norm(ckv[..., :cfg.kv_lora_rank], p["kv_norm"]["scale"],
                        cfg.rms_norm_eps)
-        kv = _mm(ckv, p["kv_b"]["w"], cd).reshape(b, s, nh, dn + dv)
+        kv = mm(ckv, p["kv_b"]["w"], cd).reshape(b, s, nh, dn + dv)
     with jax.named_scope("rope"):
         cos, sin = rope
         q = jnp.swapaxes(q, 1, 2)                               # [B, nh, S, .]
@@ -248,16 +194,10 @@ def mla_apply(p: Pytree, x, cfg: TokenModelConfig, rope):
         if cfg.use_pallas:
             o = flash_attention(fold(q), fold(k), fold(v), scale, True)
         else:
-            o = _dense_causal_attention(fold(q), fold(k), fold(v), scale)
+            o = dense_causal_attention(fold(q), fold(k), fold(v), scale)
         o = jnp.swapaxes(o.reshape(b, nh, s, dv), 1, 2).reshape(b, s, nh * dv)
     with jax.named_scope("o_proj"):
-        return _mm(o, p["o_proj"]["w"], cd)
-
-
-def swiglu_apply(p: Pytree, x, cd):
-    g = _mm(x, p["gate"]["w"], cd)
-    u = _mm(x, p["up"]["w"], cd)
-    return _mm(jax.nn.silu(g) * u, p["down"]["w"], cd)
+        return mm(o, p["o_proj"]["w"], cd)
 
 
 @jax.custom_vjp
@@ -446,7 +386,7 @@ def moe_apply(p: Pytree, bias, x, cfg: TokenModelConfig):
     """The expert layer's share of this chip over tokens x [T, H] (normed,
     float32): (y [T, H] float32, counters). `y` holds the selected experts
     that are held here, weighted, plus the shared expert once."""
-    cd, _ = _dtypes(cfg)
+    cd, _ = dtypes(cfg)
     t, _ = x.shape
     k, held = cfg.num_experts_per_tok, cfg.experts_held
     m = t * k
@@ -479,7 +419,7 @@ def moe_apply(p: Pytree, bias, x, cfg: TokenModelConfig):
 def block_apply(p: Pytree, bias, x, cfg: TokenModelConfig, rope):
     """One pre-norm residual block over x [B, S, H] (float32):
     (x, counters or None)."""
-    cd, _ = _dtypes(cfg)
+    cd, _ = dtypes(cfg)
     b, s, h = x.shape
     with jax.named_scope("mla"):
         x = x + mla_apply(p["mla"],
@@ -492,34 +432,6 @@ def block_apply(p: Pytree, bias, x, cfg: TokenModelConfig, rope):
     with jax.named_scope("moe"):
         y, counters = moe_apply(p["moe"], bias, xn.reshape(b * s, h), cfg)
     return x + y.reshape(b, s, h), counters
-
-
-def head_loss(h, norm_scale, head_w, targets, weights, cfg: TokenModelConfig):
-    """Sum over positions of weight x cross-entropy of
-    `RMSNorm(h) @ head_w` against `targets`, over [B, S, H] / [B, S], in
-    chunks of LOSS_CHUNK tokens so that one chunk's logits live at a time
-    (each chunk recomputed in the backward pass)."""
-    cd, _ = _dtypes(cfg)
-    n = targets.size
-    chunk = LOSS_CHUNK if n % LOSS_CHUNK == 0 else n
-    h = h.reshape(n // chunk, chunk, h.shape[-1])
-    targets = targets.reshape(n // chunk, chunk)
-    weights = weights.reshape(n // chunk, chunk)
-
-    @jax.checkpoint
-    def one(hc, tc, wc):
-        logits = _mm(rms_norm(hc, norm_scale, cfg.rms_norm_eps), head_w, cd)
-        with jax.named_scope("loss"):
-            lse = jax.nn.logsumexp(logits, axis=-1)
-            hit = jnp.take_along_axis(logits, tc[:, None], axis=-1)[:, 0]
-            return jnp.sum(wc * (lse - hit))
-
-    with jax.named_scope("head"):
-        def body(total, xs):
-            return total + one(*xs), None
-        total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32),
-                                (h, targets, weights))
-    return total
 
 
 def token_loss(params: Pytree, bias: Pytree, ids, cfg: TokenModelConfig
@@ -552,7 +464,7 @@ def token_loss(params: Pytree, bias: Pytree, ids, cfg: TokenModelConfig
                      ) / (b * (s - 1))
     loss_mtp = jnp.zeros((), jnp.float32)
     if cfg.num_nextn_predict_layers:
-        cd, _ = _dtypes(cfg)
+        cd, _ = dtypes(cfg)
         p = params["mtp"]
         with jax.named_scope("mtp"):
             # position i sees h_i and the embedding of token i+1, and is
@@ -564,7 +476,7 @@ def token_loss(params: Pytree, bias: Pytree, ids, cfg: TokenModelConfig
             merged = jnp.concatenate(
                 [rms_norm(e, p["enorm"]["scale"], cfg.rms_norm_eps),
                  rms_norm(x, p["hnorm"]["scale"], cfg.rms_norm_eps)], axis=-1)
-            hm = _mm(merged, p["eh_proj"]["w"], cd)
+            hm = mm(merged, p["eh_proj"]["w"], cd)
             hm, c = block(p["block"], bias["mtp"], hm)
             counters["mtp"] = c
             loss_mtp = head_loss(
@@ -578,3 +490,51 @@ def token_loss(params: Pytree, bias: Pytree, ids, cfg: TokenModelConfig
         "rows": sum(c["rows"] for c in counters.values()),
         "compact": sum(c["compact"] for c in counters.values())
         / max(len(counters), 1)}
+
+
+# --- what the likelihood step asks of a token arch (train/steps.py) ------------
+
+#: state entries the loss reads beside the parameters; aux entries
+#: averaged / summed over the data shards
+LM_READS = ("moe_bias",)
+LM_MEAN = ("loss", "loss_mtp", "compact")
+LM_SUM = ("counts", "rows")
+
+
+def lm_init(key, cfg: TokenModelConfig) -> Pytree:
+    """The state beside optimizer and step: the parameters, the routers'
+    selection biases (no gradient, no optimizer state; the step leaves them
+    as they are) and the per-expert pair counts the step accumulates."""
+    params, bias = token_init(key, cfg)
+    return {
+        "params": params,
+        "moe_bias": bias,
+        "moe_counts": {n: jnp.zeros((cfg.experts_held,), jnp.int32)
+                       for n in bias},
+    }
+
+
+def lm_loss(params: Pytree, state: Pytree, ids, cfg: TokenModelConfig):
+    return token_loss(params, state["moe_bias"], ids, cfg)
+
+
+def lm_metrics(aux: Dict[str, Any]) -> Dict[str, jax.Array]:
+    per_expert = jnp.concatenate(list(aux["counts"].values()))
+    pairs = jnp.sum(per_expert)
+    return {
+        "loss": aux["loss"], "loss_mtp": aux["loss_mtp"],
+        # (token, expert) pairs routed to experts held here, all layers
+        "moe_pairs_here": pairs.astype(jnp.float32),
+        # the fullest held expert's pairs over the mean
+        "moe_load_max": jnp.max(per_expert) * per_expert.size
+        / jnp.maximum(pairs, 1).astype(jnp.float32),
+        # rows the grouped kernels computed (whole tiles)
+        "moe_rows_computed": aux["rows"].astype(jnp.float32),
+        # share of the expert layers whose pairs fit the sized buffer
+        "moe_compact_share": aux["compact"],
+    }
+
+
+def lm_accumulate(state: Pytree, aux: Dict[str, Any]) -> Pytree:
+    return {"moe_counts": {n: state["moe_counts"][n] + c
+                           for n, c in aux["counts"].items()}}

@@ -63,8 +63,11 @@ Layout notes (TPU):
   d_v = C/2; they ride the lane axis zero-padded, which wastes lanes but not
   HBM), 64/64 (tools/bench_attention.py), and since PR 27 q/k 192 with v 128
   at S = 8192, causal, heads folded into the batch axis (the latent-attention
-  trunk of models/mla_moe.py; PERF.md section 6 has the times). Other widths
-  compile from the same code and have not been timed.
+  trunk of models/mla_moe.py; PERF.md section 6 has the times), and since
+  PR 31 q/k/v 128/128/128 at 16 heads, S = 4096, causal (the looped trunk of
+  models/loop_lm.py): 0.946 ms the forward alone, 2.389 ms forward +
+  backward (tools/bench_attention.py, one TPU v5 lite; PERF.md section 6).
+  Other widths compile from the same code and have not been timed.
 - `causal=True` (a static argument) computes the lower triangle only: the
   forward's k-loop ends at the q-tile's diagonal, the backward's q-loop
   starts at the k-tile's, and only the tiles the diagonal crosses build a

@@ -16,7 +16,7 @@ from typing import Any, Callable, Dict, Optional
 import jax
 from jax.sharding import Mesh
 
-from dcgan_tpu.config import TOKEN_ARCH, TrainConfig
+from dcgan_tpu.config import TrainConfig, is_token_arch
 from dcgan_tpu.parallel.mesh import make_mesh
 from dcgan_tpu.parallel.sharding import (
     batch_sharding,
@@ -47,7 +47,7 @@ class ParallelTrain:
     step(state, images, key, labels)  (conditional models)
     sample(state, z[, labels]) -> images (replicated output for host saving)
 
-    A one-network likelihood family (arch "mla_moe", loss "lm") has `init`
+    A one-network likelihood family (a token arch, loss "lm") has `init`
     and `step(state, ids [B, S] int32, key) -> (state, scalar metrics)`
     and nothing else: `programs` holds "init" and "train_step" alone, and
     every other surface (`sample`, `summarize`, `eval_losses`, `multi_step`,
@@ -132,7 +132,7 @@ def _refusal(name: str, cfg: TrainConfig) -> Callable:
 
 
 def make_lm_parallel_train(cfg: TrainConfig, mesh: Mesh) -> ParallelTrain:
-    """The likelihood step of the token family over a data-parallel mesh:
+    """The likelihood step of a token arch over a data-parallel mesh:
     state replicated (the rule table; the mesh has no expert axis), id
     batches [B, S] sharded over "data", the state donated."""
     from dcgan_tpu.train.steps import make_lm_train_step
@@ -187,7 +187,7 @@ def make_parallel_train(cfg: TrainConfig,
 
         return make_shard_map_train(cfg, mesh)
     mesh = mesh or make_mesh(cfg.mesh)
-    if cfg.model.arch == TOKEN_ARCH:
+    if is_token_arch(cfg.model.arch):
         return make_lm_parallel_train(cfg, mesh)
     pallas_mesh = None
     if cfg.model.use_pallas and cfg.model.attn_res and mesh.size > 1 \

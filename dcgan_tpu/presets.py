@@ -18,8 +18,8 @@ tests/bench code) can materialize them without repeating knob soup:
 - ``wgan-gp``     — WGAN-GP loss variant: Wasserstein critic + gradient
   penalty (grad-of-grad), canonical lr 1e-4 / β1 0 hyperparameters.
 
-Plus seven beyond-BASELINE presets across four further model/recipe
-families (twelve registered configs total — keep this count in sync with
+Plus nine beyond-BASELINE presets across five further model/recipe
+families (fourteen registered configs total — keep this count in sync with
 ``PRESETS`` below):
 
 - ``sagan64``     — self-attention GAN (hinge + TTUR + EMA, attention at
@@ -34,6 +34,10 @@ families (twelve registered configs total — keep this count in sync with
 - ``joyai_llm_flash`` / ``mla_moe_tiny`` — the one-network token family
   (latent attention, routed experts, a multi-token head; likelihood step):
   a published 48B model as published, and the size the tests train.
+- ``ouro_2_6b`` / ``loop_lm_tiny`` — the looped token family (one stack of
+  layers run four times on shared weights, an exit gate, an expected loss
+  over the exits; the same likelihood step): a published 2.6B model as
+  published, and the size the tests train.
 
 Every preset factory takes overrides as keyword arguments forwarded to
 `dataclasses.replace`-style reconstruction, so the CLI's explicit flags win
@@ -47,6 +51,7 @@ from typing import Callable, Dict
 
 from dcgan_tpu.config import (
     LM_LOSS,
+    LoopModelConfig,
     MeshConfig,
     ModelConfig,
     TokenModelConfig,
@@ -199,7 +204,7 @@ def stylegan64(**overrides) -> TrainConfig:
     return dataclasses.replace(cfg, **overrides)
 
 
-def _lm(model: TokenModelConfig, **train_kw) -> TrainConfig:
+def _lm(model, **train_kw) -> TrainConfig:
     """The token family's run knobs: the likelihood loss, the program's
     Adam at beta1 0.9, no decay, no clipping, and every image-only service
     (sample grids, activation summaries) off."""
@@ -243,6 +248,36 @@ def mla_moe_tiny(**overrides) -> TrainConfig:
     return dataclasses.replace(_lm(model, batch_size=8), **overrides)
 
 
+def ouro_2_6b(**overrides) -> TrainConfig:
+    """Ouro-2.6B AS PUBLISHED (ByteDance, config.json on
+    huggingface.co/ByteDance/Ouro-2.6B; "Scaling Latent Reasoning via Looped
+    Language Models", arXiv:2510.25741): ONE stack of 48 layers run 4 times
+    on shared weights (`total_ut_steps`), hidden 2048, 16 heads of 128
+    (plain multi-head), SwiGLU 5632 wide, sandwich RMSNorm, rotary theta
+    1e6 over the whole head, vocabulary 49,152 with embedding and head
+    untied, an exit gate after every pass; 4,096-token rows (the
+    pre-training length). 2,667,974,657 parameters are 42.7 GB of training
+    state, so no chip holds it: a configuration that runs cuts the depth to
+    one pipeline stage's layers (benchmark/configs/ouro-2.6b.json)."""
+    model = LoopModelConfig(
+        vocab_size=49152, hidden_size=2048, num_hidden_layers=48,
+        intermediate_size=5632, num_attention_heads=16,
+        num_key_value_heads=16, head_dim=128, rope_theta=1000000.0,
+        rms_norm_eps=1e-6, total_ut_steps=4, early_exit_threshold=1.0,
+        seq_len=4096, loss_beta=0.1)
+    return dataclasses.replace(
+        _lm(model, batch_size=1, learning_rate=3e-4), **overrides)
+
+
+def loop_lm_tiny(**overrides) -> TrainConfig:
+    """The looped family at a size the CPU tests and a smoke run train:
+    hidden 64, 2 layers run 4 times, 2 heads of 32, vocabulary 256, rows of
+    32 tokens, float32, the causal kernels in interpret mode."""
+    model = LoopModelConfig(compute_dtype="float32")
+    return dataclasses.replace(
+        _lm(model, batch_size=8, learning_rate=3e-4), **overrides)
+
+
 PRESETS: Dict[str, Callable[..., TrainConfig]] = {
     "celeba64": celeba64,
     "lsun64-dp8": lsun64_dp8,
@@ -256,6 +291,8 @@ PRESETS: Dict[str, Callable[..., TrainConfig]] = {
     "stylegan64": stylegan64,
     "joyai_llm_flash": joyai_llm_flash,
     "mla_moe_tiny": mla_moe_tiny,
+    "ouro_2_6b": ouro_2_6b,
+    "loop_lm_tiny": loop_lm_tiny,
 }
 
 # Preset revisions: bump when a preset's PERF-RELEVANT config changes

@@ -50,6 +50,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import importlib
 from typing import Any, Callable, Optional, Tuple
 
 import jax
@@ -57,7 +58,7 @@ import jax.numpy as jnp
 import optax
 from jax import lax
 
-from dcgan_tpu.config import TrainConfig
+from dcgan_tpu.config import TrainConfig, is_token_arch
 from dcgan_tpu.models.dcgan import (
     discriminator_apply,
     gan_init,
@@ -1052,49 +1053,58 @@ def make_train_step(cfg: TrainConfig, *, axis_name: Optional[str] = None,
 # the one-network likelihood step (TrainConfig.loss == "lm")
 # ---------------------------------------------------------------------------
 
-def init_lm_state(key, cfg: TrainConfig) -> Pytree:
-    """State of the token family: parameters with the optimizer state
-    beside them, the routers' selection biases (no gradient, no optimizer
-    state; the step leaves them as they are) and the per-expert pair counts
-    the step accumulates."""
-    from dcgan_tpu.models.mla_moe import token_init
+def _lm_arch(cfg: TrainConfig):
+    """The module of the token arch (models/<arch>.py). It gives the step
+    `lm_init(key, model cfg)` (the state beside optimizer and step, with
+    `params` in it), `lm_loss(params, state, ids, model cfg) -> (loss,
+    aux)` with `state` the entries `LM_READS` names, `LM_MEAN` / `LM_SUM`
+    (the aux entries averaged / summed over the data shards),
+    `lm_metrics(aux)` (the step's counters, all scalars) and
+    `lm_accumulate(state, aux)` (the state entries the step adds to): what
+    an arch counts stays with the arch."""
+    if not is_token_arch(cfg.model.arch):
+        raise ValueError(f"arch={cfg.model.arch!r} has no likelihood step")
+    return importlib.import_module(f"dcgan_tpu.models.{cfg.model.arch}")
 
-    params, bias = token_init(key, cfg.model)
-    held = cfg.model.experts_held
+
+def init_lm_state(key, cfg: TrainConfig) -> Pytree:
+    """State of a token arch: what the arch keeps (parameters, and for the
+    routed arch the selection biases and per-expert pair counts, for the
+    looped arch the per-exit mass), the optimizer state and the step."""
+    state = _lm_arch(cfg).lm_init(key, cfg.model)
     return {
-        "params": params,
-        "moe_bias": bias,
-        "moe_counts": {n: jnp.zeros((held,), jnp.int32) for n in bias},
-        "opt": make_optimizer(cfg).init(params),
+        **state,
+        "opt": make_optimizer(cfg).init(state["params"]),
         "step": jnp.zeros((), jnp.int32),
     }
 
 
 def make_lm_train_step(cfg: TrainConfig, *, mesh=None) -> TrainStepFns:
-    """Loss, gradient and the program's Adam for the token family, as the
+    """Loss, gradient and the program's Adam for a token arch, as the
     same `TrainStepFns` the two-player step returns. `train_step(state, ids
     [B, S] int32, key)`: the key is unused (nothing is drawn). Under a mesh
     with a data axis > 1 loss and gradient run per data shard inside a
-    `shard_map` (each shard's rows through attention, router and the
-    experts held, as every chip of a deployment runs its own tokens) and
-    are averaged across it; the kernels are opaque to the partitioner."""
+    `shard_map` (each shard's rows through the whole network, as every chip
+    of a deployment runs its own tokens) and are averaged across it; the
+    kernels are opaque to the partitioner."""
     from jax.sharding import PartitionSpec as P
 
-    from dcgan_tpu.models.mla_moe import token_loss
     from dcgan_tpu.utils.backend import shard_map
 
+    arch = _lm_arch(cfg)
     mcfg = cfg.model
     opt = make_optimizer(cfg)
     n_data = 1 if mesh is None else mesh.shape["data"]
 
-    def loss_grad(params, bias, ids):
+    def loss_grad(params, rest, ids):
         (_, aux), grads = jax.value_and_grad(
-            lambda p: token_loss(p, bias, ids, mcfg), has_aux=True)(params)
+            lambda p: arch.lm_loss(p, rest, ids, mcfg), has_aux=True)(params)
         if n_data > 1:
-            grads, aux["loss"], aux["loss_mtp"], aux["compact"] = lax.pmean(
-                (grads, aux["loss"], aux["loss_mtp"], aux["compact"]), "data")
-            aux["counts"], aux["rows"] = lax.psum(
-                (aux["counts"], aux["rows"]), "data")
+            grads, *mean = lax.pmean(
+                (grads, *(aux[n] for n in arch.LM_MEAN)), "data")
+            aux.update(zip(arch.LM_MEAN, mean))
+            aux.update(zip(arch.LM_SUM, lax.psum(
+                tuple(aux[n] for n in arch.LM_SUM), "data")))
         return grads, aux
 
     if n_data > 1:
@@ -1105,29 +1115,15 @@ def make_lm_train_step(cfg: TrainConfig, *, mesh=None) -> TrainStepFns:
     def train_step(state: Pytree, ids: jax.Array, key: jax.Array
                    ) -> Tuple[Pytree, dict]:
         del key
-        grads, aux = loss_grad(state["params"], state["moe_bias"], ids)
+        rest = {n: state[n] for n in arch.LM_READS}
+        grads, aux = loss_grad(state["params"], rest, ids)
         with jax.named_scope("adam"):
             updates, opt_state = opt.update(grads, state["opt"],
                                             state["params"])
             params = optax.apply_updates(state["params"], updates)
-        counts = aux["counts"]
-        per_expert = jnp.concatenate(list(counts.values()))
-        pairs = jnp.sum(per_expert)
-        metrics = {
-            "loss": aux["loss"], "loss_mtp": aux["loss_mtp"],
-            # (token, expert) pairs routed to experts held here, all layers
-            "moe_pairs_here": pairs.astype(jnp.float32),
-            # the fullest held expert's pairs over the mean
-            "moe_load_max": jnp.max(per_expert) * per_expert.size
-            / jnp.maximum(pairs, 1).astype(jnp.float32),
-            # rows the grouped kernels computed (whole tiles)
-            "moe_rows_computed": aux["rows"].astype(jnp.float32),
-            # share of the expert layers whose pairs fit the sized buffer
-            "moe_compact_share": aux["compact"],
-        }
+        metrics = arch.lm_metrics(aux)
         state = {**state, "params": params, "opt": opt_state,
-                 "moe_counts": {n: state["moe_counts"][n] + c
-                                for n, c in counts.items()},
+                 **arch.lm_accumulate(state, aux),
                  "step": state["step"] + 1}
         return state, metrics
 

@@ -57,7 +57,8 @@ import jax
 import numpy as np
 
 from dcgan_tpu.analysis import tripwire
-from dcgan_tpu.config import TOKEN_ARCH, TrainConfig, load_config, save_config
+from dcgan_tpu.config import (TrainConfig, is_token_arch, load_config,
+                              save_config)
 from dcgan_tpu.data import (
     DataConfig,
     make_dataset,
@@ -113,7 +114,7 @@ def _data_iterator(cfg: TrainConfig, mesh, *, synthetic: bool,
     generator (cheap — no slicing, no upload); real-data loaders discard
     yielded batches (best-effort: a threaded shuffle stream has no exact
     position to restore anyway)."""
-    if cfg.model.arch == TOKEN_ARCH:
+    if is_token_arch(cfg.model.arch):
         return _token_data_iterator(cfg, mesh, synthetic=synthetic,
                                     seed_offset=seed_offset,
                                     skip_batches=skip_batches)
@@ -264,7 +265,8 @@ def _token_data_iterator(cfg: TrainConfig, mesh, *, synthetic: bool,
     batches. Synthetic ids only: token records have no loader yet."""
     if not synthetic:
         raise ValueError(
-            f"arch={TOKEN_ARCH!r} trains on synthetic ids only (--synthetic): "
+            f"arch={cfg.model.arch!r} trains on synthetic ids only "
+            "(--synthetic): "
             "token records have no reader in data/ yet")
     from dcgan_tpu.data.pipeline import DevicePrefetcher, process_local_box
     from dcgan_tpu.data.synthetic import synthetic_id_batches
@@ -609,7 +611,7 @@ def _train_run(cfg: TrainConfig, *, synthetic_data: bool,
     n_samples = -(-n_samples // data_axis) * data_axis  # data-axis multiple
     # a one-network token family has no sampler: no z, and the config
     # already refused every service that would ask for one
-    sample_z = None if cfg.model.arch == TOKEN_ARCH else jax.random.uniform(
+    sample_z = None if is_token_arch(cfg.model.arch) else jax.random.uniform(
         jax.random.key(cfg.seed + 1), (n_samples, cfg.model.z_dim),
         minval=-1.0, maxval=1.0)
     sample_labels = None
@@ -1248,10 +1250,13 @@ def _train_run(cfg: TrainConfig, *, synthetic_data: bool,
         if chief and cfg.log_every_steps and s % cfg.log_every_steps == 0:
             m = _host_vals(p)
             epoch = s * pcfg.batch_size // epoch_size
-            # the two players' losses, or the token family's two heads'
-            losses = " ".join(f"{k} {m[k]:.4f}" for k in
-                              ("d_loss", "g_loss", "loss", "loss_mtp")
-                              if k in m)
+            # the two players' losses, or a token arch's: its objective
+            # and each head's or exit's loss, and the looped arch's mean
+            # exit step
+            names = ["d_loss", "g_loss",
+                     *sorted(k for k in m if k.startswith("loss")),
+                     "exit_mean_step"]
+            losses = " ".join(f"{k} {m[k]:.4f}" for k in names if k in m)
             print(f"[dcgan_tpu] epoch {epoch} step {s} "
                   f"time {time.time() - t_start:.1f}s {losses}")
         # record AFTER the step log so the ring rides the materialization

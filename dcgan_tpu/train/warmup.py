@@ -57,7 +57,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 
-from dcgan_tpu.config import TOKEN_ARCH
+from dcgan_tpu.config import is_token_arch
 
 #: jax's own variable: where it is set the cache is kept there, and the
 #: only thing that overrides it is an explicit --compile_cache_dir
@@ -255,8 +255,8 @@ def _program_args(cfg, pt, state, *, sample_z=None, sample_labels=None,
     from dcgan_tpu.parallel import batch_sharding
 
     mesh = pt.mesh
-    if cfg.model.arch == TOKEN_ARCH:
-        # the token family's batch: int32 ids [batch, seq_len]
+    if is_token_arch(cfg.model.arch):
+        # a token arch's batch: int32 ids [batch, seq_len]
         img = jax.ShapeDtypeStruct(
             (cfg.batch_size, cfg.model.seq_len), jnp.int32,
             sharding=batch_sharding(mesh, 2))

@@ -139,6 +139,21 @@ def test_causal_flash_attention_at_latent_attention_widths(v5e, heads, seq):
     assert "flash_fwd" in text and "flash_dq_dkv" in text
 
 
+@pytest.mark.usefixtures("compiled_kernels")
+def test_causal_flash_attention_at_the_looped_model_widths(v5e):
+    """The looped trunk's attention (models/loop_lm.py): 16 heads of 128
+    for q, k and v alike, causal, 4,096 tokens, heads folded into the batch
+    axis, forward and backward."""
+    def loss(q, k, v):
+        return jnp.sum(pallas_attention.flash_attention(q, k, v, 128 ** -0.5,
+                                                        True))
+
+    qkv = _sds((16, 4096, 128), jnp.bfloat16, v5e)
+    text = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), qkv, qkv,
+                    qkv)
+    assert "flash_fwd" in text and "flash_dq_dkv" in text
+
+
 @pytest.mark.parametrize("rows", [65536, 16384])
 def test_grouped_expert_matmuls_compile_at_published_widths(v5e, monkeypatch,
                                                             rows):
