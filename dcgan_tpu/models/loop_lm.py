@@ -28,8 +28,9 @@ Every weight of the stack is used T times in one step: the passes are ONE
 rolled `lax.scan` over `t` with the stack's parameters closed over it, so
 the program holds one copy of the stack and the scan's backward accumulates
 each leaf's gradient over its T uses. Each block is recomputed in the
-backward pass (`jax.checkpoint`): T x layers block inputs are kept, not
-layers.
+backward pass (`token_ops.recomputed`): T x layers block inputs are kept,
+not layers, and with each the flash forward's outputs (float32 o^T and
+log-sum-exp), so the recomputation runs no attention kernel.
 
 Not here: the second training stage that fits the gate to the measured gain
 of each pass, and inference with early exit (`early_exit_threshold`) and a
@@ -56,8 +57,8 @@ import jax.numpy as jnp
 from dcgan_tpu.config import LoopModelConfig
 from dcgan_tpu.models.token_ops import (apply_rotary, dense_causal_attention,
                                         dtypes, head_loss, mm, normal,
-                                        rms_norm, rotary_tables, swiglu_apply,
-                                        swiglu_init)
+                                        recomputed, rms_norm, rotary_tables,
+                                        swiglu_apply, swiglu_init)
 from dcgan_tpu.ops.pallas_attention import flash_attention
 
 Pytree = Any
@@ -155,14 +156,15 @@ def loop_loss(params: Pytree, ids, cfg: LoopModelConfig
     """The objective of one batch of ids [B, S] (int32). Returns (loss,
     {"loss", "loss_ut": [T] each exit's mean cross-entropy, "exit_mass":
     [T] sum over the scored positions of p_t, "exit_entropy",
-    "exit_mean_step": mean of sum_t t p_t})."""
+    "exit_mean_step": mean of sum_t t p_t, "attn_kept": the attention
+    outputs the step keeps across its recomputation})."""
     b, s = ids.shape
     steps = cfg.total_ut_steps
     scored = b * (s - 1)
     rope = rotary_tables(s, cfg.head_dim, cfg.rope_theta)
-    # every block is recomputed in the backward pass: its input is all that
-    # is kept of it, once per pass
-    blocks = {f"block{i}": jax.checkpoint(functools.partial(
+    # every block is recomputed in the backward pass: its input and its
+    # attention kernel's outputs are kept of it, once per pass
+    blocks = {f"block{i}": recomputed(functools.partial(
         block_apply, cfg=cfg, rope=rope, name=f"block{i}"))
         for i in range(cfg.num_hidden_layers)}
     gate = params["exit_gate"]
@@ -203,13 +205,16 @@ def loop_loss(params: Pytree, ids, cfg: LoopModelConfig
         "loss": loss, "loss_ut": plain / scored, "exit_mass": mass,
         "exit_entropy": entropy,
         "exit_mean_step": jnp.sum(
-            jnp.arange(1, steps + 1, dtype=jnp.float32) * mass) / scored}
+            jnp.arange(1, steps + 1, dtype=jnp.float32) * mass) / scored,
+        "attn_kept": jnp.float32(
+            steps * len(blocks) if cfg.use_pallas else 0)}
 
 
 # --- what the likelihood step asks of a token arch (train/steps.py) ------------
 
 #: state entries the loss reads beside the parameters (none); aux entries
-#: averaged / summed over the data shards
+#: averaged / summed over the data shards (`attn_kept`, a constant of the
+#: program and the same on every shard, is neither)
 LM_READS = ()
 LM_MEAN = ("loss", "loss_ut", "exit_entropy", "exit_mean_step")
 LM_SUM = ("exit_mass",)
@@ -232,7 +237,9 @@ def lm_metrics(aux: Dict[str, Any]) -> Dict[str, jax.Array]:
             **{f"loss_ut{t + 1}": aux["loss_ut"][t]
                for t in range(aux["loss_ut"].shape[0])},
             "exit_entropy": aux["exit_entropy"],
-            "exit_mean_step": aux["exit_mean_step"]}
+            "exit_mean_step": aux["exit_mean_step"],
+            # attention outputs kept across the recomputation, a chip
+            "attn_outputs_kept": aux["attn_kept"]}
 
 
 def lm_accumulate(state: Pytree, aux: Dict[str, Any]) -> Pytree:

@@ -60,8 +60,8 @@ import jax.numpy as jnp
 from dcgan_tpu.config import TokenModelConfig
 from dcgan_tpu.models.token_ops import (apply_rotary, dense_causal_attention,
                                         dtypes, head_loss, mm, normal,
-                                        rms_norm, rotary_tables, swiglu_apply,
-                                        swiglu_init)
+                                        recomputed, rms_norm, rotary_tables,
+                                        swiglu_apply, swiglu_init)
 from dcgan_tpu.ops.pallas_attention import flash_attention
 
 Pytree = Any
@@ -441,12 +441,14 @@ def token_loss(params: Pytree, bias: Pytree, ids, cfg: TokenModelConfig
     `mtp_loss_weight` times that of the multi-token module over 0..S-3.
     Returns (loss, {"loss", "loss_mtp", "counts": {layer: [held]},
     "rows": rows the grouped kernels computed, "compact": the share of the
-    expert layers that ran over the sized buffer})."""
+    expert layers that ran over the sized buffer, "attn_kept": the attention
+    outputs the step keeps across its recomputation})."""
     b, s = ids.shape
     rope = rotary_tables(s, cfg.qk_rope_head_dim, cfg.rope_theta)
-    # every block is recomputed in the backward pass: its input is all that
-    # is kept of it (10.9 GB of state leave 5 GB for activations)
-    block = jax.checkpoint(functools.partial(block_apply, cfg=cfg, rope=rope))
+    # every block is recomputed in the backward pass: its input and its
+    # attention kernel's outputs are kept of it (10.9 GB of state leave
+    # 5 GB for activations)
+    block = recomputed(functools.partial(block_apply, cfg=cfg, rope=rope))
     table = params["embed"]["table"]
     with jax.named_scope("embed"):
         x = table[ids].astype(jnp.float32)
@@ -489,13 +491,18 @@ def token_loss(params: Pytree, bias: Pytree, ids, cfg: TokenModelConfig
         "counts": {n: c["counts"] for n, c in counters.items()},
         "rows": sum(c["rows"] for c in counters.values()),
         "compact": sum(c["compact"] for c in counters.values())
-        / max(len(counters), 1)}
+        / max(len(counters), 1),
+        # one a block: the trunk's layers and the multi-token module
+        "attn_kept": jnp.float32(
+            cfg.num_hidden_layers + bool(cfg.num_nextn_predict_layers)
+            if cfg.use_pallas else 0)}
 
 
 # --- what the likelihood step asks of a token arch (train/steps.py) ------------
 
 #: state entries the loss reads beside the parameters; aux entries
-#: averaged / summed over the data shards
+#: averaged / summed over the data shards (`attn_kept`, a constant of the
+#: program and the same on every shard, is neither)
 LM_READS = ("moe_bias",)
 LM_MEAN = ("loss", "loss_mtp", "compact")
 LM_SUM = ("counts", "rows")
@@ -532,6 +539,8 @@ def lm_metrics(aux: Dict[str, Any]) -> Dict[str, jax.Array]:
         "moe_rows_computed": aux["rows"].astype(jnp.float32),
         # share of the expert layers whose pairs fit the sized buffer
         "moe_compact_share": aux["compact"],
+        # attention outputs kept across the recomputation, a chip
+        "attn_outputs_kept": aux["attn_kept"],
     }
 
 

@@ -1,6 +1,7 @@
 """What the token archs share (models/mla_moe.py, models/loop_lm.py): the
 matmul of the precision policy, RMSNorm, rotary, SwiGLU, dense masked
-attention for short sequences, and the chunked head + loss.
+attention for short sequences, the chunked head + loss, and what a block
+keeps across its recomputation.
 
 Precision policy (ops/layers.py's): float32 parameters, matmul operands in
 `compute_dtype` with float32 accumulation; softmax, the norms' statistics
@@ -16,10 +17,30 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from dcgan_tpu.ops.pallas_attention import FLASH_LSE_NAME, FLASH_OUT_NAME
+
 Pytree = Any
 
 #: tokens of one chunk of the head + loss (logits of one chunk live at a time)
 LOSS_CHUNK = 2048
+
+
+#: what a recomputed block keeps beside its input: the flash forward's two
+#: outputs as the kernel wrote them (float32 o^T, one more activation of the
+#: block's width, and the log-sum-exp; the backward's `delta` reads float32
+#: o^T, so a rounded copy would be another result)
+KEPT_NAMES = (FLASH_OUT_NAME, FLASH_LSE_NAME)
+_KEEP = jax.checkpoint_policies.save_only_these_names(*KEPT_NAMES)
+
+
+def recomputed(block):
+    """`block` as a function whose backward pass recomputes it from its
+    inputs, the attention kernel's outputs excepted: projections, rotary,
+    norms and the feed-forward run again, the flash forward does not (its
+    outputs are the residuals its backward takes, ops/pallas_attention.py).
+    With the dense fallback nothing carries the names and the input is all
+    that is kept."""
+    return jax.checkpoint(block, policy=_KEEP)
 
 
 def dtypes(cfg):

@@ -98,6 +98,7 @@ import os
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -460,11 +461,24 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return out
 
 
+#: `checkpoint_name`s of the forward kernel's two outputs. A caller that
+#: recomputes a block under `jax.checkpoint` keeps them by a policy on
+#: these names (models/token_ops.py::recomputed), and the recomputation
+#: then holds no forward kernel; under no `jax.checkpoint` a name lowers
+#: to nothing.
+FLASH_OUT_NAME = "flash_outT"
+FLASH_LSE_NAME = "flash_lse"
+
+
 def _flash_vjp_fwd(q, k, v, scale, causal):
     # the [B, S, d] <-> [B, d, S] swaps at the edges are XLA's; the
     # residuals stay in the kernels' layout
     qT = jnp.swapaxes(q, 1, 2)
     outT, lse = _fwd_core(qT, k, jnp.swapaxes(v, 1, 2), scale, causal)
+    # named on the kernel's own outputs, ahead of the swap: named any later
+    # the kernel would still be live in a recomputation that kept them
+    outT = checkpoint_name(outT, FLASH_OUT_NAME)
+    lse = checkpoint_name(lse, FLASH_LSE_NAME)
     return jnp.swapaxes(outT, 1, 2), (qT, k, v, outT, lse)
 
 

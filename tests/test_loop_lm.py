@@ -205,8 +205,10 @@ def test_the_step_is_the_same_on_one_device_and_over_a_data_mesh():
         assert sorted(pt.programs) == ["init", "train_step"]
         out.append((jax.device_get(m), jax.device_get(state)))
     (m1, s1), (m2, s2) = out
-    assert sorted(m1) == ["exit_entropy", "exit_mean_step", "loss",
-                          "loss_ut1", "loss_ut2", "loss_ut3", "loss_ut4"]
+    assert sorted(m1) == ["attn_outputs_kept", "exit_entropy",
+                          "exit_mean_step", "loss", "loss_ut1", "loss_ut2",
+                          "loss_ut3", "loss_ut4"]
+    assert m1["attn_outputs_kept"] == m2["attn_outputs_kept"] == 2 * 4
     assert sorted(s1) == ["exit_mass", "opt", "params", "step"]
     for k in m1:
         np.testing.assert_allclose(m1[k], m2[k], rtol=1e-5)
@@ -221,7 +223,16 @@ def test_the_step_is_the_same_on_one_device_and_over_a_data_mesh():
 def test_the_step_names_its_scopes():
     """Every scope PERF.md section 3 lists is in the lowered step, and no
     path holds `loop` twice (the readers sum the paths that END in a name,
-    so a repeated scope would count its operations twice)."""
+    so a repeated scope would count its operations twice). The recomputed
+    block runs its projections again and no forward kernel.
+
+    Only scope paths are read: a named location with a `/` in it
+    (`loop/block0/ffn/dot_general`). The text also holds file names and
+    Python frames, and those are not the step's alone: jax keeps the
+    jaxprs of its jitted library functions (`jax.nn.silu`, `logsumexp`)
+    by shape, each with the frames of its FIRST trace, so after the routed
+    arch's tests in one process this step's text names
+    `models/mla_moe.py` and `moe_apply` as callers of `token_ops`."""
     import re
 
     from dcgan_tpu.train.steps import make_lm_train_step
@@ -232,7 +243,7 @@ def test_the_step_names_its_scopes():
     ids = jax.ShapeDtypeStruct((8, 32), jnp.int32)
     text = jax.jit(fns.train_step).lower(
         state, ids, jax.random.key(0)).as_text(debug_info=True)
-    paths = set(re.findall(r'loc\("([^"]+)"', text))
+    paths = {p for p in re.findall(r'loc\("([^"]+)"\(', text) if "/" in p}
     parts = [[re.sub(r"^\w+\((.*)\)$", r"\1", q) for q in p.split("/")]
              for p in paths]
     named = {q for p in parts for q in p}
@@ -241,7 +252,12 @@ def test_the_step_names_its_scopes():
                   "loss", "adam"):
         assert scope in named, scope
     assert any(p.count("qkv_proj") and "checkpoint" in p for p in parts)
+    assert any("flash_fwd" in p for p in parts)
     for p in parts:
+        # the backward pass (`checkpoint/..`) holds the backward kernel
+        # and, in its recomputation, no forward kernel
+        if "flash_fwd" in p or "flash_dq_dkv" in p:
+            assert ("checkpoint" in p) == ("flash_dq_dkv" in p), p
         assert p.count("loop") <= 1, p
         if "attn_block" in p or "ffn" in p:
             assert p.count("loop") == 1 and "head" not in p, p
