@@ -700,16 +700,18 @@ def enumerate_audits() -> Tuple[List[ProgramAudit], List[CoverageRow]]:
 
         if backend == "gspmd":
             # The one-network token archs (models/mla_moe.py, named @lm;
-            # models/loop_lm.py, named @loop_lm): the likelihood step at
-            # each tiny preset over the same 2-way data mesh (loss and
-            # gradient per shard inside a shard_map, the looped arch's
-            # kernels in interpret mode). A token arch's surface is "init"
+            # models/loop_lm.py, named @loop_lm; models/sambay.py, named
+            # @sambay): the likelihood step at each tiny preset over the
+            # same 2-way data mesh (loss and gradient per shard inside a
+            # shard_map, the looped and the hybrid arch's kernels in
+            # interpret mode). A token arch's surface is "init"
             # and "train_step" alone, and the warmup plan covers it from
             # the same `_program_args` the trainer warms.
             from dcgan_tpu.presets import get_preset
 
             for preset, tag in (("mla_moe_tiny", "lm"),
-                                ("loop_lm_tiny", "loop_lm")):
+                                ("loop_lm_tiny", "loop_lm"),
+                                ("sambay_tiny", "sambay")):
                 cfg_lm = get_preset(preset, mesh=cfg.mesh)
                 pt_lm = make_parallel_train(cfg_lm, mesh)
                 plan_lm, _bk_lm = warmup.build_warmup_plan(

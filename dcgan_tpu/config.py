@@ -170,6 +170,10 @@ TOKEN_ARCH = "mla_moe"
 #: layers run `total_ut_steps` times on shared weights, an exit gate and
 #: an expected loss over the exits; the same likelihood step
 LOOP_ARCH = "loop_lm"
+#: the decoder-hybrid-decoder family's `arch` (models/sambay.py): Mamba
+#: scans, window, full and cross differential attention and gated memory
+#: units in one stack, a tied head; the same likelihood step
+SAMBAY_ARCH = "sambay"
 LM_LOSS = "lm"
 
 
@@ -312,12 +316,151 @@ class LoopModelConfig:
             raise ValueError("seq_len must be >= 2 (a next token to score)")
 
 
+#: the kinds of layer of the decoder-hybrid-decoder family, by the name of
+#: the scope each mixer runs under
+SAMBAY_KINDS = ("mamba", "attn_win", "attn_full", "gmu", "attn_cross")
+
+
+def sambay_layout(n: int) -> tuple:
+    """The published layout of `n` layers (Phi-4-mini-flash-reasoning, 32):
+    the self-decoder alternates Mamba and window attention over the first
+    half, layer n/2 is a Mamba layer (its scan output is the memory), layer
+    n/2 + 1 the one full-attention layer (its keys and values are the
+    shared set), and the cross-decoder after it alternates gated memory
+    units (even layers) and cross-attention (odd)."""
+    if n < 4 or n % 2:
+        raise ValueError(
+            f"the published layout needs an even number of layers >= 4 "
+            f"(two decoders around the bridge pair), got {n}")
+    half = n // 2
+    return tuple(
+        ("mamba" if i % 2 == 0 else "attn_win") if i < half else
+        "mamba" if i == half else "attn_full" if i == half + 1 else
+        ("gmu" if i % 2 == 0 else "attn_cross") for i in range(n))
+
+
+@dataclasses.dataclass(frozen=True)
+class SambaYModelConfig:
+    """A decoder-hybrid-decoder causal language model (SambaY with
+    differential attention: "Decoder-Hybrid-Decoder Architecture for
+    Efficient Reasoning with Long Generation", arXiv:2507.06607;
+    Phi-4-mini-flash-reasoning): every layer is `x += Mixer(LN(x)); x +=
+    SwiGLU(LN(x))` with one of five mixers: a Mamba-1 selective scan, window
+    / full / cross differential attention (two softmax maps over one value
+    set, subtracted), a gated memory unit. The last Mamba layer before the
+    cross-decoder hands its scan output to every gated memory unit, the one
+    full-attention layer its keys and values to every cross-attention
+    layer. LayerNorm with bias, no positional encoding, the head is the
+    embedding transposed. Fields carry the names of the model's public
+    `config.json`; what it does not give (the Mamba sizes, the layout) is
+    the family's convention. The defaults are a small model, the presets
+    hold the published one.
+    """
+
+    arch: str = SAMBAY_ARCH
+    vocab_size: int = 256          # rows of the tied embedding held here
+    hidden_size: int = 64
+    num_hidden_layers: int = 6
+    #: the kind of every layer, of SAMBAY_KINDS; () is the published layout
+    #: of `num_hidden_layers` layers (`sambay_layout`)
+    layer_types: tuple = ("mamba", "attn_win", "mamba", "attn_full", "gmu",
+                          "attn_cross")
+    intermediate_size: int = 256   # SwiGLU width
+    num_attention_heads: int = 4   # 64-wide at the published size; a
+                                   # differential pair is two of them
+    num_key_value_heads: int = 2
+    sliding_window: int = 8        # keys a window layer's query sees,
+                                   # itself counted
+    mb_per_layer: int = 2          # every second layer of the self-decoder
+                                   # is Mamba: all `sambay_layout` lays out
+    layer_norm_eps: float = 1e-5
+    tie_word_embeddings: bool = True
+    mlp_bias: bool = False
+    lm_head_bias: bool = False
+    mamba_d_state: int = 16
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_dt_rank: int = 0         # 0: ceil(hidden_size / 16)
+    seq_len: int = 32              # tokens of one row of the batch
+    compute_dtype: str = "bfloat16"
+    param_dtype: str = "float32"
+    # no `use_pallas`: one scan and one attention path, the kernels
+    # (interpreted off the TPU)
+
+    num_classes = property(lambda self: 0)
+
+    def __post_init__(self):
+        if self.arch != SAMBAY_ARCH:
+            raise ValueError(
+                f"SambaYModelConfig.arch must be {SAMBAY_ARCH!r}, got "
+                f"{self.arch!r}")
+        kinds = tuple(self.layer_types) or sambay_layout(
+            self.num_hidden_layers)
+        object.__setattr__(self, "layer_types", kinds)   # JSON gives a list
+        if len(kinds) != self.num_hidden_layers \
+                or any(k not in SAMBAY_KINDS for k in kinds):
+            raise ValueError(
+                f"layer_types must name num_hidden_layers="
+                f"{self.num_hidden_layers} kinds of {SAMBAY_KINDS}, got "
+                f"{kinds}")
+        if kinds.count("attn_full") != 1:
+            raise ValueError(
+                "arch 'sambay' has ONE full-attention layer (the shared "
+                f"keys and values); layer_types holds "
+                f"{kinds.count('attn_full')}")
+        full = kinds.index("attn_full")
+        late = [i for i, k in enumerate(kinds) if k in ("gmu", "attn_cross")]
+        if any(i < full for i in late) or "mamba" not in kinds[:full]:
+            raise ValueError(
+                "gated memory units and cross-attention read the last "
+                "Mamba layer and the full-attention layer before them: "
+                f"layer_types {kinds} puts a reader first")
+        if self.num_attention_heads % 2 or self.num_key_value_heads % 2 \
+                or self.num_attention_heads % self.num_key_value_heads \
+                or self.hidden_size % self.num_attention_heads:
+            raise ValueError(
+                "differential attention pairs the heads: "
+                "num_attention_heads and num_key_value_heads must be even, "
+                "the first a multiple of the second and a divisor of "
+                "hidden_size")
+        if not (self.tie_word_embeddings and not self.mlp_bias
+                and not self.lm_head_bias and self.mb_per_layer == 2):
+            raise ValueError(
+                "arch 'sambay' ties embedding and head, has no bias in the "
+                "SwiGLU or on the head, and alternates Mamba and attention "
+                "(mb_per_layer 2)")
+        if self.sliding_window < 1 or self.mamba_d_conv < 1:
+            raise ValueError("sliding_window and mamba_d_conv must be >= 1")
+        if self.seq_len < 2:
+            raise ValueError("seq_len must be >= 2 (a next token to score)")
+
+    @property
+    def head_dim(self) -> int:
+        return self.hidden_size // self.num_attention_heads
+
+    @property
+    def d_inner(self) -> int:
+        return self.mamba_expand * self.hidden_size
+
+    @property
+    def dt_rank(self) -> int:
+        return self.mamba_dt_rank or -(-self.hidden_size // 16)
+
+    @property
+    def memory_layer(self) -> int:
+        """The Mamba layer whose scan output the gated memory units read:
+        the last one before the full-attention layer."""
+        full = self.layer_types.index("attn_full")
+        return max(i for i in range(full) if self.layer_types[i] == "mamba")
+
+
 #: the archs that train one network on id batches by `LM_LOSS`, each with
 #: the dataclass that holds its model config; an arch is the name of its
 #: module under models/ (train/steps.py takes init, loss and counters from
 #: it)
 TOKEN_MODEL_CONFIGS = {TOKEN_ARCH: TokenModelConfig,
-                       LOOP_ARCH: LoopModelConfig}
+                       LOOP_ARCH: LoopModelConfig,
+                       SAMBAY_ARCH: SambaYModelConfig}
 TOKEN_ARCHS = tuple(TOKEN_MODEL_CONFIGS)
 
 

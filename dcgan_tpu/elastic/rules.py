@@ -108,6 +108,16 @@ PARTITION_RULES: Tuple[Tuple[str, LogicalSpec], ...] = (
     (r"(^|/)(q_proj|k_proj|v_proj|exit_gate)/w$", REPLICATED),
     (r"(^|/)(attn_out_norm|ffn_out_norm)/scale$", REPLICATED),
     (r"^exit_mass$", REPLICATED),
+    # -- the decoder-hybrid-decoder token family (models/sambay.py): the
+    # leaves the rows above do not name (every `b` is the bias row's):
+    # LayerNorm's leaves, the scan's, the lambda vectors; the memory's
+    # per-channel mean
+    (r"(^|/)(in_proj|x_proj|dt_proj|out_proj|qkv_proj|conv)/w$", REPLICATED),
+    (r"(^|/)(norm1|norm2)/(scale|bias)$", REPLICATED),
+    (r"(^|/)final_norm/bias$", REPLICATED),
+    (r"(^|/)mixer/(A_log|D|lambda_[qk][12])$", REPLICATED),
+    (r"(^|/)subln/scale$", REPLICATED),
+    (r"^mem_abs$", REPLICATED),
     # Adam step counts (optax ScaleByAdamState / schedule counts)
     (r"(^|/)count$", REPLICATED),
     # the trainer's global step

@@ -1,4 +1,5 @@
-"""What the token archs share (models/mla_moe.py, models/loop_lm.py): the
+"""What the token archs share (models/mla_moe.py, models/loop_lm.py,
+models/sambay.py): the
 matmul of the precision policy, RMSNorm, rotary, SwiGLU, dense masked
 attention for short sequences, the chunked head + loss, and what a block
 keeps across its recomputation.
@@ -33,14 +34,17 @@ KEPT_NAMES = (FLASH_OUT_NAME, FLASH_LSE_NAME)
 _KEEP = jax.checkpoint_policies.save_only_these_names(*KEPT_NAMES)
 
 
-def recomputed(block):
+def recomputed(block, keep=KEPT_NAMES):
     """`block` as a function whose backward pass recomputes it from its
     inputs, the attention kernel's outputs excepted: projections, rotary,
     norms and the feed-forward run again, the flash forward does not (its
     outputs are the residuals its backward takes, ops/pallas_attention.py).
     With the dense fallback nothing carries the names and the input is all
-    that is kept."""
-    return jax.checkpoint(block, policy=_KEEP)
+    that is kept. `keep`: the `checkpoint_name`s kept, for an arch whose
+    blocks hold another kernel beside attention (models/sambay.py)."""
+    policy = _KEEP if keep == KEPT_NAMES else \
+        jax.checkpoint_policies.save_only_these_names(*keep)
+    return jax.checkpoint(block, policy=policy)
 
 
 def dtypes(cfg):
@@ -108,12 +112,14 @@ def swiglu_apply(p: Pytree, x, cd):
 
 
 def head_loss(h, norm_scale, head_w, targets, weights, cfg,
-              chunk: int = LOSS_CHUNK):
+              chunk: int = LOSS_CHUNK, norm=None):
     """Sum over positions of weight x cross-entropy of
     `RMSNorm(h) @ head_w` against `targets`, over [B, S, H] / [B, S], in
     chunks of `chunk` tokens so that one chunk's logits live at a time
     (each chunk recomputed in the backward pass). `norm_scale` None: `h`
-    is normed already. `weights` is [B, S], or [K, B, S] for K weighted
+    is normed already, or `norm` (a function of one chunk [chunk, H]) is
+    the final norm in RMSNorm's place (models/sambay.py: LayerNorm with
+    bias). `weights` is [B, S], or [K, B, S] for K weighted
     sums of the same cross-entropies (the result is [K]); a weight may
     carry gradient."""
     cd, _ = dtypes(cfg)
@@ -128,7 +134,9 @@ def head_loss(h, norm_scale, head_w, targets, weights, cfg,
 
     @jax.checkpoint
     def one(hc, tc, wc):
-        if norm_scale is not None:
+        if norm is not None:
+            hc = norm(hc)
+        elif norm_scale is not None:
             hc = rms_norm(hc, norm_scale, cfg.rms_norm_eps)
         logits = mm(hc, head_w, cd)
         with jax.named_scope("loss"):

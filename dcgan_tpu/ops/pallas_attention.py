@@ -66,13 +66,36 @@ Layout notes (TPU):
   trunk of models/mla_moe.py; PERF.md section 6 has the times), and since
   PR 31 q/k/v 128/128/128 at 16 heads, S = 4096, causal (the looped trunk of
   models/loop_lm.py): 0.946 ms the forward alone, 2.389 ms forward +
-  backward (tools/bench_attention.py, one TPU v5 lite; PERF.md section 6).
+  backward (tools/bench_attention.py, one TPU v5 lite; PERF.md section 6),
+  and since PR 33 q/k 64 with v 128 at 40 folded rows, S = 8192 (the
+  differential attention of models/sambay.py: a pair's two maps share one
+  value set two heads wide): causal 6.80 ms the forward alone, 17.24 ms
+  forward + backward; with `window=512` 2.91 / 6.31 ms.
   Other widths compile from the same code and have not been timed.
 - `causal=True` (a static argument) computes the lower triangle only: the
   forward's k-loop ends at the q-tile's diagonal, the backward's q-loop
   starts at the k-tile's, and only the tiles the diagonal crosses build a
   mask. Tiles above the diagonal are skipped, not masked after the fact.
   The non-causal call traces kernel bodies with no mask in them.
+- `window=w` (a static argument, with `causal=True`): query i sees keys
+  i - w + 1..i, the position itself counted. The forward's k-loop starts at
+  the tile of the q-tile's first row less w - 1 and ends at its diagonal;
+  the backward's q-loop runs from the k-tile's diagonal to the tile of its
+  last column plus w - 1. Tiles outside the band are skipped, not masked;
+  tiles that the band's two edges cross build a mask, tiles wholly inside
+  it none. `window=None` traces the kernel bodies it traced before the
+  argument existed (tests/test_flash_window.py pins the lowered text), and
+  `window >= S` IS the causal call, bit for bit. The windowed call runs
+  under its own kernel names (`flash_fwd_win`, `flash_dq_dkv_win`) at its
+  own tiles, WIN_BLOCK_Q x WIN_BLOCK_K = 512 x 512 for both kernels: with
+  the causal forward's 2,048-wide q-tile a 512-key band would compute
+  2,560 keys a tile for 512 useful. Swept at 40 rows x 8,192, q/k 64, v
+  128, w 512 (forward / forward + backward, ms; my chip run, PR 33): TQ 128
+  with TK 128 / 256 / 512: 5.49 / 13.72, 4.67 / 10.47, 4.42 / 8.86; TQ 256:
+  4.36 / 11.34, 3.68 / 7.72, 3.83 / 7.46; TQ 512: 3.63 / 10.79, 3.14 /
+  7.43, **2.91 / 6.31**. At 512 x 512 a q-tile folds two k-tiles, both
+  masked (half the scores computed are inside the band); smaller tiles
+  waste fewer scores and lose more to the per-tile overheads.
 - Off-TPU the kernels run under `interpret=True`, so the CPU test mesh
   exercises the identical code path (tests/test_flash_forward.py,
   tests/test_flash_backward.py and tests/test_pallas_attention.py assert
@@ -135,6 +158,10 @@ FWD_UNROLL = 4
 BLOCK_Q = 256
 BLOCK_K = 1024
 BWD_BLOCK_Q = 1024
+# The windowed call's tiles (`window`), forward and backward alike: the
+# module's docstring has the sweep and why the causal tiles do not serve.
+WIN_BLOCK_Q = 512
+WIN_BLOCK_K = 512
 
 # Measurement generation: bump on ANY change that alters attention-kernel
 # performance characteristics (tile defaults, precision policy, block
@@ -200,7 +227,7 @@ def _blocks(s: int, block_q: int = BLOCK_Q, block_k: int = BLOCK_K) -> tuple:
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(qT_ref, k_ref, vT_ref, oT_ref, lse_ref, *, scale, tk, unroll,
-                causal=False):
+                causal=False, window=None):
     # Precision policy (shared with ops/attention.py::full_attention):
     # matmul operands stay in the INPUT dtype — bf16 rides the MXU fast
     # path — while scores/stats/accumulator are f32 via
@@ -224,7 +251,10 @@ def _fwd_kernel(qT_ref, k_ref, vT_ref, oT_ref, lse_ref, *, scale, tk, unroll,
         if masked:
             key = j * tk + lax.broadcasted_iota(jnp.int32, (tk, tq), 0)
             query = q0 + lax.broadcasted_iota(jnp.int32, (tk, tq), 1)
-            sT = jnp.where(key <= query, sT, _NEG_INF)
+            keep = key <= query
+            if window is not None:
+                keep = keep & (key > query - window)
+            sT = jnp.where(keep, sT, _NEG_INF)
         m_new = jnp.maximum(m, jnp.max(sT, axis=0, keepdims=True))
         pT = jnp.exp(sT - m_new)                        # [TK, TQ]
         corr = jnp.exp(m - m_new)
@@ -245,7 +275,23 @@ def _fwd_kernel(qT_ref, k_ref, vT_ref, oT_ref, lse_ref, *, scale, tk, unroll,
     carry = (jnp.full((1, tq), _NEG_INF, jnp.float32),
              jnp.zeros((1, tq), jnp.float32),
              jnp.zeros((dv, tq), jnp.float32))
-    if causal:
+    if window is not None:
+        # the band: k-tiles from the one that holds the first query's first
+        # key to the diagonal's. Tiles that the band's lower edge crosses
+        # (masked), tiles wholly inside the band (plain), tiles the
+        # diagonal crosses (masked); where the window is shorter than a
+        # q-tile and a k-tile together no tile is plain. A column whose
+        # keys of the first tile are all masked carries _NEG_INF and sums
+        # of ones until its first real score, whose `corr` is exp(-1e30) =
+        # 0: what was gathered before it is wiped.
+        n_here = (q0 + tq + tk - 1) // tk
+        lo = jnp.maximum(q0 - (window - 1), 0) // tk
+        plain_lo = jnp.clip((q0 + tq - window + tk - 1) // tk, lo, n_here)
+        plain_hi = jnp.clip((q0 + 1) // tk, plain_lo, n_here)
+        carry = loop(lo, plain_lo, carry, masked=True)
+        carry = loop(plain_lo, plain_hi, carry)
+        m, l, acc = loop(plain_hi, n_here, carry, masked=True)
+    elif causal:
         # k-tiles wholly on or below the diagonal of this q-tile, then the
         # ones the diagonal crosses; the rest are never touched. Key 0 is in
         # the first tile and no query precedes it, so every column's running
@@ -270,20 +316,27 @@ def _fwd_unroll(tq: int, tk: int) -> int:
     return math.gcd(FWD_UNROLL, tq // tk) if tq % tk == 0 else 1
 
 
-def _fwd_core(qT, k, vT, scale, causal=False):
+def _fwd_core(qT, k, vT, scale, causal=False, window=None):
     """The forward pallas_call: q^T [B, dk, S], k [B, S, dk], v^T [B, dv, S]
     -> (o^T [B, dv, S] f32, lse [B, 1, S] f32); q^T, o^T and lse are the
     layouts `_bwd_core` takes its residents in."""
     B, dk, S = qT.shape
     dv = vT.shape[1]
-    tq, tk = _blocks(S, FWD_BLOCK_Q, FWD_BLOCK_K)
-    kernel = functools.partial(_fwd_kernel, scale=scale, tk=tk,
-                               unroll=_fwd_unroll(tq, tk))
-    if causal:
-        kernel = functools.partial(kernel, causal=True)
+    if window is not None:
+        # its own tiles, one fold an iteration: the band's loop bounds are
+        # no multiples of a group of folds
+        tq, tk = _blocks(S, WIN_BLOCK_Q, WIN_BLOCK_K)
+        kernel = functools.partial(_fwd_kernel, scale=scale, tk=tk, unroll=1,
+                                   causal=True, window=window)
+    else:
+        tq, tk = _blocks(S, FWD_BLOCK_Q, FWD_BLOCK_K)
+        kernel = functools.partial(_fwd_kernel, scale=scale, tk=tk,
+                                   unroll=_fwd_unroll(tq, tk))
+        if causal:
+            kernel = functools.partial(kernel, causal=True)
     return pl.pallas_call(
         kernel,
-        name="flash_fwd",
+        name="flash_fwd" if window is None else "flash_fwd_win",
         grid=(B, S // tq),
         in_specs=[pl.BlockSpec((1, dk, tq), lambda b, i: (b, 0, i)),
                   pl.BlockSpec((1, S, dk), lambda b, i: (b, 0, 0)),
@@ -297,11 +350,11 @@ def _fwd_core(qT, k, vT, scale, causal=False):
     )(qT, k, vT)
 
 
-def _fwd_impl(q, k, v, scale, causal=False):
+def _fwd_impl(q, k, v, scale, causal=False, window=None):
     """The forward over row-major [B, S, d] blocks, XLA's swaps at its
     edges: (out [B, S, dv] f32, lse [B, 1, S])."""
     outT, lse = _fwd_core(jnp.swapaxes(q, 1, 2), k, jnp.swapaxes(v, 1, 2),
-                          scale, causal)
+                          scale, causal, window)
     return jnp.swapaxes(outT, 1, 2), lse
 
 
@@ -309,17 +362,20 @@ def _fwd_impl(q, k, v, scale, causal=False):
 # backward
 # ---------------------------------------------------------------------------
 
-def _causal_keep(row0, col0, tq: int, tk: int):
+def _causal_keep(row0, col0, tq: int, tk: int, window=None):
     """[TQ, TK] mask of a score tile whose first row is query `row0` and
-    first column is key `col0`: True where the key is not after the query."""
+    first column is key `col0`: True where the key is not after the query
+    (and, with a window, among the query's last `window` keys)."""
     rows = row0 + lax.broadcasted_iota(jnp.int32, (tq, tk), 0)
     cols = col0 + lax.broadcasted_iota(jnp.int32, (tq, tk), 1)
-    return cols <= rows
+    if window is None:
+        return cols <= rows
+    return (cols <= rows) & (cols > rows - window)
 
 
 def _dq_dkv_kernel(k_ref, v_ref, qT_ref, doT_ref, lse_ref, delta_ref,
                    dq_ref, dkT_ref, dvT_ref, dq_acc, *, scale, tq,
-                   causal=False):
+                   causal=False, window=None):
     # same operand-dtype / f32-accumulation policy as the forward. One
     # k-tile per program; q^T/do^T/lse/delta enter as full-sequence
     # residents with the sequence on the LANE axis (a [S, d] or [S, 1]
@@ -346,7 +402,8 @@ def _dq_dkv_kernel(k_ref, v_ref, qT_ref, doT_ref, lse_ref, delta_ref,
                                 preferred_element_type=jnp.float32) * scale
         if masked:
             tk = kb.shape[0]
-            s = jnp.where(_causal_keep(i * tq, j * tk, tq, tk), s, _NEG_INF)
+            s = jnp.where(_causal_keep(i * tq, j * tk, tq, tk, window), s,
+                          _NEG_INF)
         p = jnp.exp(s - lse)                             # [TQ, TK]
         dp = jax.lax.dot_general(doT.T, vb, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
@@ -360,7 +417,21 @@ def _dq_dkv_kernel(k_ref, v_ref, qT_ref, doT_ref, lse_ref, delta_ref,
 
     zeros = (jnp.zeros(dkT_ref.shape[1:], jnp.float32),
              jnp.zeros(dvT_ref.shape[1:], jnp.float32))
-    if causal:
+    if window is not None:
+        # the band seen from this k-tile: q-tiles from its diagonal's to the
+        # one that holds the last query its last key reaches: the diagonal
+        # crosses the first (masked), then tiles wholly inside the band
+        # (plain), then those the band's lower edge crosses (masked)
+        col0, tk = j * kb.shape[0], kb.shape[0]
+        masked = functools.partial(body, masked=True)
+        i_first = col0 // tq
+        i_last = jnp.minimum((col0 + tk + window - 2) // tq + 1, n_q)
+        plain_lo = jnp.clip((col0 + tk + tq - 2) // tq, i_first, i_last)
+        plain_hi = jnp.clip((col0 + window - tq) // tq + 1, plain_lo, i_last)
+        carry = lax.fori_loop(i_first, plain_lo, masked, zeros)
+        carry = lax.fori_loop(plain_lo, plain_hi, body, carry)
+        dkT, dvT = lax.fori_loop(plain_hi, i_last, masked, carry)
+    elif causal:
         # q-tiles the diagonal crosses within this k-tile, then the ones
         # wholly on or below it; q-tiles above the k-tile are skipped (their
         # rows of the dQ accumulator take nothing from it)
@@ -407,31 +478,36 @@ def _bwd_stats(q, out, lse, g):
     return _bwd_inputs(jnp.swapaxes(q, 1, 2), jnp.swapaxes(out, 1, 2), lse, g)
 
 
-def _bwd_impl(scale, causal, res, g):
+def _bwd_impl(scale, causal, window, res, g):
     qT, k, v, outT, lse = res
     return _bwd_core(scale, k, v, *_bwd_inputs(qT, outT, lse, g),
-                     causal=causal)
+                     causal=causal, window=_band(window, causal, k.shape[1]))
 
 
 def _bwd_core(scale, k, v, qT, doT, lse, delta, grad_dtype=None,
-              causal=False):
+              causal=False, window=None):
     """The backward pallas_call: dQ, dK and dV from one pass over the score
     tiles. grad_dtype overrides the gradient output dtype (the ring
     backward asks for f32 so per-hop contributions are not rounded to bf16
     before the cross-hop accumulation)."""
     B, S, dk = k.shape
     dv = v.shape[-1]
-    tq, tk = _blocks(S, BWD_BLOCK_Q)
+    if window is not None:
+        tq, tk = _blocks(S, WIN_BLOCK_Q, WIN_BLOCK_K)
+    else:
+        tq, tk = _blocks(S, BWD_BLOCK_Q)
 
     def resident(d):
         return pl.BlockSpec((1, d, S), lambda b, j: (b, 0, 0))
 
     kernel = functools.partial(_dq_dkv_kernel, scale=scale, tq=tq)
-    if causal:
+    if window is not None:
+        kernel = functools.partial(kernel, causal=True, window=window)
+    elif causal:
         kernel = functools.partial(kernel, causal=True)
     dq, dkT, dvT = pl.pallas_call(
         kernel,
-        name="flash_dq_dkv",
+        name="flash_dq_dkv" if window is None else "flash_dq_dkv_win",
         grid=(B, S // tk),
         in_specs=[pl.BlockSpec((1, tk, dk), lambda b, j: (b, j, 0)),
                   pl.BlockSpec((1, tk, dv), lambda b, j: (b, j, 0)),
@@ -449,15 +525,33 @@ def _bwd_core(scale, k, v, qT, doT, lse, delta, grad_dtype=None,
     return dq, jnp.swapaxes(dkT, 1, 2), jnp.swapaxes(dvT, 1, 2)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _band(window, causal: bool, seq: int):
+    """The window the kernels are given: None where it hides nothing (no
+    window, or one as long as the sequence: the plain causal kernels then,
+    bit for bit)."""
+    if window is None:
+        return None
+    if not causal:
+        raise ValueError("a window is the causal mask's: pass causal=True")
+    if window < 1:
+        raise ValueError(f"window must be >= 1, got {window}")
+    return None if window >= seq else int(window)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                    scale: float, causal: bool = False) -> jax.Array:
+                    scale: float, causal: bool = False,
+                    window=None) -> jax.Array:
     """softmax(q k^T * scale) v over [B, S, d] blocks without ever
     materializing the [S, S] score matrix in HBM. Returns float32 (matching
     ops/attention.py::full_attention's accumulation contract). `causal`
     (static): query i sees keys 0..i only, and the tiles above the diagonal
-    are not computed."""
-    out, _ = _fwd_impl(q, k, v, scale, causal)
+    are not computed. `window` (static, with `causal`): query i sees keys
+    i - window + 1..i, the position itself counted; tiles outside the band
+    are skipped, and the call runs kernels of its own name and tiles
+    (`flash_fwd_win`, `flash_dq_dkv_win`)."""
+    out, _ = _fwd_impl(q, k, v, scale, causal,
+                       _band(window, causal, q.shape[1]))
     return out
 
 
@@ -470,11 +564,12 @@ FLASH_OUT_NAME = "flash_outT"
 FLASH_LSE_NAME = "flash_lse"
 
 
-def _flash_vjp_fwd(q, k, v, scale, causal):
+def _flash_vjp_fwd(q, k, v, scale, causal, window):
     # the [B, S, d] <-> [B, d, S] swaps at the edges are XLA's; the
     # residuals stay in the kernels' layout
     qT = jnp.swapaxes(q, 1, 2)
-    outT, lse = _fwd_core(qT, k, jnp.swapaxes(v, 1, 2), scale, causal)
+    outT, lse = _fwd_core(qT, k, jnp.swapaxes(v, 1, 2), scale, causal,
+                          _band(window, causal, q.shape[1]))
     # named on the kernel's own outputs, ahead of the swap: named any later
     # the kernel would still be live in a recomputation that kept them
     outT = checkpoint_name(outT, FLASH_OUT_NAME)

@@ -38,6 +38,10 @@ families (fourteen registered configs total — keep this count in sync with
   layers run four times on shared weights, an exit gate, an expected loss
   over the exits; the same likelihood step): a published 2.6B model as
   published, and the size the tests train.
+- ``phi_4_mini_flash`` / ``sambay_tiny`` — the decoder-hybrid-decoder token
+  family (Mamba scans, window / full / cross differential attention, gated
+  memory units, a tied head; the same likelihood step): a published 3.8B
+  model as published, and the size the tests train.
 
 Every preset factory takes overrides as keyword arguments forwarded to
 `dataclasses.replace`-style reconstruction, so the CLI's explicit flags win
@@ -54,6 +58,7 @@ from dcgan_tpu.config import (
     LoopModelConfig,
     MeshConfig,
     ModelConfig,
+    SambaYModelConfig,
     TokenModelConfig,
     TrainConfig,
 )
@@ -278,6 +283,41 @@ def loop_lm_tiny(**overrides) -> TrainConfig:
         _lm(model, batch_size=8, learning_rate=3e-4), **overrides)
 
 
+def phi_4_mini_flash(**overrides) -> TrainConfig:
+    """Phi-4-mini-flash-reasoning AS PUBLISHED (microsoft, config.json on
+    huggingface.co/microsoft/Phi-4-mini-flash-reasoning; SambaY with
+    differential attention, arXiv:2507.06607): 32 layers of hidden 2560 in
+    the published layout (9 Mamba-1 layers of d_inner 5120 and 16 states, 8
+    layers of 512-token window attention, ONE full-attention layer whose
+    keys and values 7 cross-attention layers share, 7 gated memory units
+    reading layer 16's scan output), 40 heads of 64 over 20 key/value heads
+    in differential pairs, SwiGLU 10240 wide, LayerNorm, no positional
+    encoding, a tied vocabulary of 200,064; 8,192-token rows.
+    3,852,562,944 parameters are 61.6 GB of training state, so no chip
+    holds it: a configuration that runs cuts depth and vocabulary to one
+    chip's share (benchmark/configs/phi-4-mini-flash.json)."""
+    model = SambaYModelConfig(
+        vocab_size=200064, hidden_size=2560, num_hidden_layers=32,
+        layer_types=(), intermediate_size=10240, num_attention_heads=40,
+        num_key_value_heads=20, sliding_window=512, mb_per_layer=2,
+        layer_norm_eps=1e-5, mamba_d_state=16, mamba_d_conv=4,
+        mamba_expand=2, mamba_dt_rank=160, seq_len=8192)
+    return dataclasses.replace(
+        _lm(model, batch_size=1, learning_rate=3e-4), **overrides)
+
+
+def sambay_tiny(**overrides) -> TrainConfig:
+    """The decoder-hybrid-decoder family at a size the CPU tests and a
+    smoke run train: hidden 64, one layer of each kind in the published
+    order (Mamba, window attention, Mamba as memory, full attention, gated
+    memory unit, cross-attention), 4 heads of 16 in two pairs over one
+    key/value pair, d_inner 128, a window of 8, vocabulary 256, rows of 32
+    tokens, float32, every kernel in interpret mode."""
+    model = SambaYModelConfig(compute_dtype="float32")
+    return dataclasses.replace(
+        _lm(model, batch_size=8, learning_rate=3e-4), **overrides)
+
+
 PRESETS: Dict[str, Callable[..., TrainConfig]] = {
     "celeba64": celeba64,
     "lsun64-dp8": lsun64_dp8,
@@ -293,6 +333,8 @@ PRESETS: Dict[str, Callable[..., TrainConfig]] = {
     "mla_moe_tiny": mla_moe_tiny,
     "ouro_2_6b": ouro_2_6b,
     "loop_lm_tiny": loop_lm_tiny,
+    "phi_4_mini_flash": phi_4_mini_flash,
+    "sambay_tiny": sambay_tiny,
 }
 
 # Preset revisions: bump when a preset's PERF-RELEVANT config changes

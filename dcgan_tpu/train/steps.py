@@ -1070,7 +1070,8 @@ def _lm_arch(cfg: TrainConfig):
 def init_lm_state(key, cfg: TrainConfig) -> Pytree:
     """State of a token arch: what the arch keeps (parameters, and for the
     routed arch the selection biases and per-expert pair counts, for the
-    looped arch the per-exit mass), the optimizer state and the step."""
+    looped arch the per-exit mass, for the hybrid arch the memory's
+    per-channel mean), the optimizer state and the step."""
     state = _lm_arch(cfg).lm_init(key, cfg.model)
     return {
         **state,

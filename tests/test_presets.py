@@ -13,12 +13,13 @@ class TestPresets:
         # sngan-cifar10 are the beyond-BASELINE attention / resnet families
         # (presets.py docstrings); joyai_llm_flash and mla_moe_tiny are the
         # one-network token family (a published model as published, and the
-        # size the tests train); ouro_2_6b and loop_lm_tiny the looped one.
+        # size the tests train); ouro_2_6b and loop_lm_tiny the looped one;
+        # phi_4_mini_flash and sambay_tiny the decoder-hybrid-decoder.
         assert set(PRESETS) == {
             "celeba64", "lsun64-dp8", "dcgan128", "cifar10-cond", "wgan-gp",
             "sagan64", "sagan128", "sagan256-lc", "sngan-cifar10",
             "stylegan64", "joyai_llm_flash", "mla_moe_tiny", "ouro_2_6b",
-            "loop_lm_tiny"}
+            "loop_lm_tiny", "phi_4_mini_flash", "sambay_tiny"}
 
     def test_celeba64_is_reference_headline(self):
         cfg = get_preset("celeba64")

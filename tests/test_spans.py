@@ -22,7 +22,10 @@ PACKAGE = os.path.join(ROOT, "dcgan_tpu")
 TRAIN_SPANS = ("train/next", "train/dispatch", "train/consume",
                "train/services")
 PALLAS_NAMES = {
+    # the windowed call's names (`flash_fwd_win`, `flash_dq_dkv_win`) are
+    # these two call sites' under `window`: tests/test_flash_window.py
     "ops/pallas_attention.py": ["flash_fwd", "flash_dq_dkv"],
+    "ops/pallas_scan.py": ["ssm_scan_fwd", "ssm_scan_bwd"],
 }
 
 

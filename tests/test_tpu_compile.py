@@ -25,7 +25,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from dcgan_tpu.ops import pallas_attention
+from dcgan_tpu.ops import pallas_attention, pallas_scan
 from dcgan_tpu.presets import get_preset
 
 BATCH = 64
@@ -55,6 +55,7 @@ def v5e():
 @pytest.fixture
 def compiled_kernels(monkeypatch):
     monkeypatch.setattr(pallas_attention, "_interpret", lambda: False)
+    monkeypatch.setattr(pallas_scan, "_interpret", lambda: False)
 
 
 def _sds(shape, dtype, dev):
@@ -152,6 +153,42 @@ def test_causal_flash_attention_at_the_looped_model_widths(v5e):
     text = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), qkv, qkv,
                     qkv)
     assert "flash_fwd" in text and "flash_dq_dkv" in text
+
+
+@pytest.mark.usefixtures("compiled_kernels")
+@pytest.mark.parametrize("window", [None, 512])
+def test_differential_attention_at_the_hybrid_model_widths(v5e, window):
+    """The decoder-hybrid-decoder's attention (models/sambay.py): 40 folded
+    rows of 8,192 tokens, q/k 64 wide with v 128, causal; the full layer's
+    call and the window layer's, which runs the windowed kernels at their
+    own tiles."""
+    def loss(q, k, v):
+        return jnp.sum(pallas_attention.flash_attention(q, k, v, 0.125, True,
+                                                        window))
+
+    qk = _sds((40, 8192, 64), jnp.bfloat16, v5e)
+    text = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), qk, qk,
+                    _sds((40, 8192, 128), jnp.bfloat16, v5e))
+    names = ("flash_fwd_win", "flash_dq_dkv_win") if window else \
+        ("flash_fwd", "flash_dq_dkv")
+    assert all(n in text for n in names)
+    assert ("flash_fwd_win" in text) == bool(window)
+
+
+@pytest.mark.usefixtures("compiled_kernels")
+def test_selective_scan_at_the_hybrid_model_widths(v5e):
+    """The Mamba layers' scan (ops/pallas_scan.py) at the shipped size: one
+    row of 8,192 steps, 5,120 channels, 16 states, float32, forward and
+    all five gradients (the backward's rebuilt states are 4 MB of VMEM
+    scratch at a chunk of 128 steps and 512 channels)."""
+    def loss(u, dt, a, b, c):
+        return jnp.sum(pallas_scan.selective_scan(u, dt, a, b, c))
+
+    seq = _sds((1, 8192, 5120), jnp.float32, v5e)
+    bc = _sds((1, 8192, 16), jnp.float32, v5e)
+    text = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)), seq,
+                    seq, _sds((5120, 16), jnp.float32, v5e), bc, bc)
+    assert "ssm_scan_fwd" in text and "ssm_scan_bwd" in text
 
 
 @pytest.mark.parametrize("rows", [65536, 16384])

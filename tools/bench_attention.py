@@ -12,6 +12,9 @@ sequence-parallel strategies. CPU runs are for smoke only.
     python tools/bench_attention.py --mesh 4 --heads 4   # + ring/ulysses
     JAX_PLATFORMS=cpu python tools/bench_attention.py --seq 256 --steps 2
     python tools/bench_attention.py --causal --heads 32 --d 192 --dv 128 --seq 8192
+    # a 512-key window over the same triangle, the windowed kernels' tiles:
+    python tools/bench_attention.py --causal --window 512 --forms flash \
+        --heads 40 --d 64 --dv 128 --seq 8192 --tq 256 512 --tk 128 256
     # the flash kernels alone, forward by itself and with the backward, over
     # a grid of the forward's tiles and folds per loop iteration (the sweep
     # behind FWD_BLOCK_Q / FWD_BLOCK_K / FWD_UNROLL of
@@ -77,6 +80,12 @@ def main() -> None:
                         "triangle path against dense masked attention "
                         "(e.g. --causal --heads 32 --d 192 --dv 128 --seq "
                         "8192: the token trunk's shape)")
+    p.add_argument("--window", type=int, default=None,
+                   help="with --causal: query i sees its last WINDOW keys "
+                        "(itself counted); the flash form runs the windowed "
+                        "kernels, whose tiles --tq / --tk then sweep "
+                        "(WIN_BLOCK_Q / WIN_BLOCK_K), forward and backward "
+                        "alike")
     p.add_argument("--platform", default=None,
                    help="force a JAX platform (e.g. cpu)")
     args = p.parse_args()
@@ -119,14 +128,19 @@ def main() -> None:
             s = jnp.einsum("bqd,bkd->bqk", q, k,
                            preferred_element_type=jnp.float32) * scale
             keep = jnp.tril(jnp.ones((q.shape[1], k.shape[1]), bool))
+            if args.window:
+                keep &= ~jnp.tril(keep, -args.window)
             p = jax.nn.softmax(jnp.where(keep, s, -1e30), axis=-1)
             return jnp.einsum("bqk,bkd->bqd", p.astype(v.dtype), v,
                               preferred_element_type=jnp.float32)
 
         forms = {
             "dense": dense_causal,
-            "flash": lambda q, k, v: flash_attention(q, k, v, scale, True),
+            "flash": lambda q, k, v: flash_attention(q, k, v, scale, True,
+                                                     args.window),
         }
+    elif args.window:
+        sys.exit("--window is the causal mask's: pass --causal")
     if args.mesh == 1:
         sys.exit("--mesh must be > 1 (a 1-device ring/ulysses is the dense "
                  "path)")
@@ -217,6 +231,8 @@ def main() -> None:
         row = {"form": name, "seq": S, "heads": h, "batch": args.batch,
                "d": args.d, "dv": args.dv, "backward": which == "fwd_bwd",
                "causal": args.causal, "gen": ATTN_GEN}
+        if args.window:
+            row["window"] = args.window
         for key, val in (("DCGAN_FLASH_TQ", tq), ("DCGAN_FLASH_TK", tk)):
             if val is not None:
                 os.environ[key] = str(val)
