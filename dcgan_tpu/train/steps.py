@@ -33,11 +33,12 @@ refill program), `d_update` (the critic update(s) CONSUMING a provided fake
 stack instead of regenerating it), and `g_update` (the generator update,
 which RETURNS the fake stack it generated so the next step's `d_update` can
 consume it at staleness 1). Per-step FLOPs are conservation-equal to the
-fused program — every consumed fake is produced exactly once, and XLA
-already CSEs the fused step's shared-z generator forward (cost-analysis-
-verified; DESIGN.md §6f) — the split's wins are the largest program's
-peak temp memory and the stage separation itself (cross-stage placement/
-overlap substrate). The stage bodies reuse the exact loss/penalty/
+fused program — every consumed fake is produced exactly once: the fused
+step (n_critic = 1, no grad_accum) runs G's forward once, linearised, its
+D step taking the fake batch from the forward its G step pulls the loss
+gradient back through (DESIGN.md §6f). The split's wins are the largest
+program's peak temp memory and the stage separation itself (cross-stage
+placement/overlap substrate). The stage bodies reuse the exact loss/penalty/
 accumulation code paths of the fused step (n_critic critic scan, grad_accum
 microbatch scan), so the two surfaces cannot drift; only the fake batch's
 PROVENANCE differs — fused regenerates per step, pipelined consumes the
@@ -404,14 +405,20 @@ def make_train_step(cfg: TrainConfig, *, axis_name: Optional[str] = None,
         metrics["g_loss"] = _pmean(g_loss)
         return metrics
 
+    def _generate(g_params: Pytree, g_bn: Pytree, z: jax.Array, labels
+                  ) -> Tuple[jax.Array, Pytree]:
+        """G's train-mode forward: (fake fed to D, new G BN/SN state)."""
+        fake, g_bn = generator_apply(g_params, g_bn, z, cfg=mcfg,
+                                     train=True, labels=labels,
+                                     axis_name=axis_name, attn_mesh=attn_mesh,
+                                     pallas_mesh=pallas_mesh)
+        return _cf(fake), g_bn
+
     def d_loss_fn(d_params: Pytree, g_params: Pytree, bn: Pytree,
                   images: jax.Array, z: jax.Array, gp_key,
                   labels, step=0, r1_every_step=False,
                   aug_key=None) -> Tuple[jax.Array, Tuple]:
-        fake, _ = generator_apply(g_params, bn["gen"], z, cfg=mcfg, train=True,
-                                  labels=labels, axis_name=axis_name,
-                                  attn_mesh=attn_mesh, pallas_mesh=pallas_mesh)
-        fake = _cf(fake)
+        fake, _ = _generate(g_params, bn["gen"], z, labels)
         return _d_loss_on_fake(d_params, bn, images, fake, gp_key, labels,
                                step, r1_every_step, aug_key)
 
@@ -420,10 +427,10 @@ def make_train_step(cfg: TrainConfig, *, axis_name: Optional[str] = None,
                         r1_every_step=False,
                         aug_key=None) -> Tuple[jax.Array, Tuple]:
         """The D loss on an ALREADY-MATERIALIZED fake batch — the shared
-        body of the fused step (which generates `fake` just above) and the
-        pipelined d_update stage (which consumes the previous step's
-        device-resident stack), so the two can never diverge on loss,
-        penalty, or BN-chaining semantics."""
+        body of d_loss_fn, the fused step (which takes `fake` from G's one
+        linearised forward) and the pipelined d_update stage (which
+        consumes the previous step's device-resident stack), so they can
+        never diverge on loss, penalty, or BN-chaining semantics."""
         # D sees real then fake, chaining BN state through both applications —
         # the functional analogue of the reference's two discriminator() calls
         # with reuse=True (image_train.py:82,85). Each D input is
@@ -475,17 +482,15 @@ def make_train_step(cfg: TrainConfig, *, axis_name: Optional[str] = None,
                 d_loss = d_loss + 0.5 * cfg.r1_gamma * cfg.r1_interval * gp
         return d_loss, (d_bn2, d_real, d_fake, gp)
 
-    def g_loss_fn(g_params: Pytree, d_params: Pytree, bn: Pytree,
-                  z: jax.Array, labels, aug_key=None,
-                  return_fake: bool = False) -> Tuple[jax.Array, Tuple]:
-        fake, g_bn = generator_apply(g_params, bn["gen"], z, cfg=mcfg,
-                                     train=True, labels=labels,
-                                     axis_name=axis_name, attn_mesh=attn_mesh, pallas_mesh=pallas_mesh)
-        fake = _cf(fake)
+    def _g_loss_on_fake(fake: jax.Array, d_params: Pytree, d_bn: Pytree,
+                        labels, aug_key=None) -> jax.Array:
+        """G's loss on an ALREADY-GENERATED fake batch — the shared body of
+        g_loss_fn and the fused step (which differentiates it by `fake`
+        alone and pulls that back through G's one linearised forward)."""
         # generator gradients flow THROUGH the augmentation — the property
         # DiffAugment needs (arXiv:2006.10738)
         _, fake_logits, _ = discriminator_apply(
-            d_params, bn["disc"], _aug(fake, aug_key, 2), cfg=mcfg,
+            d_params, d_bn, _aug(fake, aug_key, 2), cfg=mcfg,
             train=True, labels=labels, axis_name=axis_name,
             attn_mesh=attn_mesh, pallas_mesh=pallas_mesh)
         # the family's own generator loss (4th return) — single-sourced with
@@ -493,7 +498,13 @@ def make_train_step(cfg: TrainConfig, *, axis_name: Optional[str] = None,
         # fake logits, so the real-logits slot gets a dummy (its unused
         # d-side outputs are DCE'd by XLA). BCE: non-saturating generator
         # loss (image_train.py:96).
-        g_loss = gan_losses(fake_logits, fake_logits)[3]
+        return gan_losses(fake_logits, fake_logits)[3]
+
+    def g_loss_fn(g_params: Pytree, d_params: Pytree, bn: Pytree,
+                  z: jax.Array, labels, aug_key=None,
+                  return_fake: bool = False) -> Tuple[jax.Array, Tuple]:
+        fake, g_bn = _generate(g_params, bn["gen"], z, labels)
+        g_loss = _g_loss_on_fake(fake, d_params, bn["disc"], labels, aug_key)
         # return_fake (pipelined g_update only): ride the already-computed
         # fake out through the aux so the stage can hand it to the NEXT
         # step's d_update — a Python-level branch, so the fused path's
@@ -518,6 +529,9 @@ def make_train_step(cfg: TrainConfig, *, axis_name: Optional[str] = None,
     d_grad = _loss_grad(d_loss_fn)
     d_on_fake_grad = _loss_grad(_d_loss_on_fake)
     g_grad = _loss_grad(g_loss_fn)
+    # by the fake batch alone; the caller sets the scope (G's pull-back
+    # shares it)
+    g_on_fake_grad = jax.value_and_grad(_g_loss_on_fake)
 
     def _adam(opt, grads, opt_state, params: Pytree, net: str):
         """One optimizer update from already reduced / averaged gradients:
@@ -684,13 +698,24 @@ def make_train_step(cfg: TrainConfig, *, axis_name: Optional[str] = None,
         # targets; forwards and grads run on the gathered full view
         gen_full = _gather_params(params["gen"], "gen")
 
+        if cfg.n_critic == 1:
+            # G's ONE forward, linearised: D's step takes its fake batch
+            # from it and G's step pulls its loss gradient back through it.
+            # G reads nothing of D, so the fake is the same whichever D the
+            # G loss is taken against (either update_mode). It runs under
+            # the G step's scopes, which own G's forward and backward.
+            with jax.named_scope("g_step"), jax.named_scope("loss"):
+                fake, g_vjp, g_bn = jax.vjp(
+                    lambda gp: _generate(gp, bn["gen"], z, labels),
+                    gen_full, has_aux=True)
+
         # --- D step(s) ------------------------------------------------------
         with jax.named_scope("d_step"):
             if cfg.n_critic == 1:
-                (d_loss, (d_bn, d_real, d_fake, gp)), d_grads = d_grad(
-                    _gather_params(params["disc"], "disc"), gen_full, bn,
-                    images, z, gp_key,
-                    labels, state["step"], False, aug_key)
+                (d_loss, (d_bn, d_real, d_fake, gp)), d_grads = \
+                    d_on_fake_grad(
+                        _gather_params(params["disc"], "disc"), bn, images,
+                        fake, gp_key, labels, state["step"], False, aug_key)
                 new_disc, d_opt = _adam(
                     opt_d, _reduce_grads(d_grads, "disc"),
                     state["opt"]["disc"], params["disc"], "disc")
@@ -729,16 +754,24 @@ def make_train_step(cfg: TrainConfig, *, axis_name: Optional[str] = None,
                     iter_keys)
 
         if cfg.update_mode == "sequential":
-            g_target_disc = _gather_params(new_disc, "disc")
-            g_bn_in = {"gen": bn["gen"], "disc": d_bn}
+            g_target_disc, disc_bn_for_g = \
+                _gather_params(new_disc, "disc"), d_bn
         else:  # "fused": reference parity — G grads at pre-update D params
-            g_target_disc = _gather_params(params["disc"], "disc")
-            g_bn_in = bn
+            g_target_disc, disc_bn_for_g = \
+                _gather_params(params["disc"], "disc"), bn["disc"]
 
         # --- G step ---------------------------------------------------------
         with jax.named_scope("g_step"):
-            (g_loss, (g_bn,)), g_grads = g_grad(
-                gen_full, g_target_disc, g_bn_in, z, labels, aug_key)
+            if cfg.n_critic == 1:
+                with jax.named_scope("loss"):
+                    g_loss, fake_ct = g_on_fake_grad(
+                        fake, g_target_disc, disc_bn_for_g, labels, aug_key)
+                    g_grads, = g_vjp(fake_ct)
+            else:
+                (g_loss, (g_bn,)), g_grads = g_grad(
+                    gen_full, g_target_disc,
+                    {"gen": bn["gen"], "disc": disc_bn_for_g}, z, labels,
+                    aug_key)
             new_gen, g_opt = _adam(
                 opt_g, _reduce_grads(g_grads, "gen"), state["opt"]["gen"],
                 params["gen"], "gen")
@@ -775,12 +808,7 @@ def make_train_step(cfg: TrainConfig, *, axis_name: Optional[str] = None,
         applied. lax.scan so the body compiles once whatever n is."""
         def one(carry, iter_key):
             z_i, _, _ = _critic_streams(iter_key, stage_batch)
-            fake, _ = generator_apply(g_params, g_bn, z_i, cfg=mcfg,
-                                      train=True, labels=None,
-                                      axis_name=axis_name,
-                                      attn_mesh=attn_mesh,
-                                      pallas_mesh=pallas_mesh)
-            return carry, _cf(fake)
+            return carry, _generate(g_params, g_bn, z_i, None)[0]
         keys = jax.random.split(key, n)
         if n == 1:
             # no 1-trip scan (see d_update: a single-iteration while loop
