@@ -89,23 +89,21 @@ def _device() -> dict:
 
 
 class Probe:
-    """Compile time and persistent-cache counters, from jax.monitoring."""
+    """Compile time and persistent-cache counters, from the `compile/backend`
+    records of the program's span store (utils/profiling.py)."""
 
     def __init__(self) -> None:
-        import jax
         from dcgan_tpu.train.warmup import CompileCacheMonitor
 
         self.cache = CompileCacheMonitor()
-        self.compile_s = 0.0
-
-        def on_duration(event: str, secs: float, **kw) -> None:
-            if event == "/jax/core/compile/backend_compile_duration":
-                self.compile_s += secs
-
-        jax.monitoring.register_event_duration_secs_listener(on_duration)
+        self.since = time.perf_counter()
 
     def snapshot(self) -> dict:
-        return {**self.cache.counters(), "compile_s": self.compile_s}
+        from dcgan_tpu.utils import profiling
+
+        compile_s = sum(r.duration for r in profiling.spans("compile/backend")
+                        if r.start >= self.since)
+        return {**self.cache.counters(), "compile_s": compile_s}
 
 
 @contextlib.contextmanager

@@ -24,6 +24,9 @@ from dcgan_tpu.parallel.sharding import (
     state_shardings,
 )
 from dcgan_tpu.train.steps import make_train_step
+# imported for its compile listeners: they must be in place before the
+# first trace of `init` below (the `compile/*` records)
+from dcgan_tpu.utils import profiling  # noqa: F401
 
 Pytree = Any
 

@@ -1156,5 +1156,7 @@ def make_lm_train_step(cfg: TrainConfig, *, mesh=None) -> TrainStepFns:
                  "step": state["step"] + 1}
         return state, metrics
 
-    return TrainStepFns(train_step=train_step,
-                        init=lambda key: init_lm_state(key, cfg))
+    def init(key):
+        return init_lm_state(key, cfg)
+
+    return TrainStepFns(train_step=train_step, init=init)

@@ -437,10 +437,10 @@ def _train(cfg: TrainConfig, *, synthetic_data: bool,
         raise
     finally:
         if cache_mon is not None:
-            # unregister the monitoring listeners on EVERY exit — config
-            # validation errors and failed warmups included; a process
-            # that calls train() again (tests, drills) must not accumulate
-            # double-counting listeners
+            # freeze the run's counters on EVERY exit — config validation
+            # errors and failed warmups included; a process that calls
+            # train() again (tests, drills) must not see this run's
+            # counters move with the next run's compiles
             cache_mon.close()
 
 

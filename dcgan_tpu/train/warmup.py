@@ -23,10 +23,11 @@ is that discipline for tpu-dcgan's time-to-first-step:
   restart, so "too cheap to cache" (JAX's default 1 s floor, tuned for
   jit-churn workloads) is the wrong default here.
 
-- `CompileCacheMonitor` subscribes to JAX's monitoring events and turns
-  them into the `perf/compile_cache_{requests,hits,misses}` counters the
-  trainer surfaces as JSONL events — cache effectiveness is a recorded
-  number per run, not a log grep.
+- `CompileCacheMonitor` turns the `compile/backend` records of
+  utils/profiling.py's span store into the
+  `perf/compile_cache_{requests,hits,misses}` counters the trainer
+  surfaces as JSONL events — cache effectiveness is a recorded number per
+  run, not a log grep.
 
 - `build_warmup_plan` + `aot_compile` are the explicit AOT warmup phase
   (`--aot_warmup`): every program the run can dispatch — the k=1 n_critic
@@ -58,6 +59,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 
 from dcgan_tpu.config import is_token_arch
+from dcgan_tpu.utils import profiling
 
 #: jax's own variable: where it is set the cache is kept there, and the
 #: only thing that overrides it is an explicit --compile_cache_dir
@@ -69,15 +71,6 @@ CACHE_ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
 CHECKOUT_CACHE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__)))), ".jax_cache")
-
-#: monitoring event name -> counter key (the three adoption counters JAX's
-#: compile path records around the persistent cache)
-_EVENT_COUNTERS = {
-    "/jax/compilation_cache/compile_requests_use_cache": "requests",
-    "/jax/compilation_cache/cache_hits": "hits",
-    "/jax/compilation_cache/cache_misses": "misses",
-}
-_SAVED_EVENT = "/jax/compilation_cache/compile_time_saved_sec"
 
 
 def resolve_cache_dir(cfg_dir: str = "", *, entry_point: bool = False,
@@ -156,39 +149,29 @@ def cache_serves_all_processes(per_process: bool) -> bool:
 
 
 class CompileCacheMonitor:
-    """Counts persistent-cache adoption through jax.monitoring.
+    """Counts persistent-cache adoption from the `compile/backend` records
+    (utils/profiling.py) that start after its construction: a record whose
+    `count` is set asked the cache (`requests`); its `count` is 1 on a miss
+    and 0 on a hit. It registers nothing with JAX.
 
     The counters are process-local and monotonic from construction;
     `counters()` snapshots them, `delta(since)` diffs two snapshots (the
-    trainer brackets phases with it). `close()` unregisters the listeners —
-    required in multi-`train()` processes (tests, drills) or each monitor
-    would keep double-counting forever.
+    trainer brackets phases with it). `close()` freezes them. The store
+    keeps the newest `profiling.SPAN_RING` compile records, so a monitor
+    counts at most that many compiles.
     """
 
     def __init__(self) -> None:
-        self._counts: Dict[str, int] = {k: 0 for k in
-                                        _EVENT_COUNTERS.values()}
-        self._saved_secs = 0.0
-        self._closed = False
-
-        def _on_event(event: str, **kw) -> None:
-            key = _EVENT_COUNTERS.get(event)
-            if key is not None:
-                self._counts[key] += 1
-
-        def _on_duration(event: str, duration_secs: float, **kw) -> None:
-            if event == _SAVED_EVENT:
-                self._saved_secs += duration_secs
-
-        self._on_event = _on_event
-        self._on_duration = _on_duration
-        jax.monitoring.register_event_listener(_on_event)
-        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        self._since = time.perf_counter()
+        self._closed: Optional[Dict[str, float]] = None
 
     def counters(self) -> Dict[str, float]:
-        out: Dict[str, float] = dict(self._counts)
-        out["saved_ms"] = self._saved_secs * 1e3
-        return out
+        if self._closed is not None:
+            return dict(self._closed)
+        asked = [r.count for r in profiling.spans("compile/backend")
+                 if r.start >= self._since and r.count is not None]
+        return {"requests": len(asked), "hits": asked.count(0),
+                "misses": sum(asked)}
 
     @staticmethod
     def delta(now: Dict[str, float],
@@ -196,11 +179,8 @@ class CompileCacheMonitor:
         return {k: now[k] - since.get(k, 0) for k in now}
 
     def close(self) -> None:
-        if self._closed:
-            return
-        self._closed = True
-        jax.monitoring.unregister_event_listener(self._on_event)
-        jax.monitoring.unregister_event_duration_listener(self._on_duration)
+        if self._closed is None:
+            self._closed = self.counters()
 
 
 def backoff_config(cfg, scale: float):

@@ -13,6 +13,17 @@ timing" — and this module is it:
   `SpanRecord` to a bounded in-memory ring per name; `spans()` hands the
   records out. The feed (`feed/*`, data/pipeline.py) and the trainer loop
   (`train/*`, train/trainer.py) record through it.
+- Compile records: the listeners this module registers with
+  `jax.monitoring` when it is imported turn each compile stage JAX reports
+  into a record of the same store: `compile/trace` (Python tracing to a
+  jaxpr), `compile/lower` (jaxpr to MLIR, the Pallas kernels' Mosaic
+  lowering with it), `compile/backend` (XLA's compile on a persistent-
+  cache miss, the fetch and load of the executable on a hit), each
+  labelled with the program's name; a trace that runs inside another
+  stage of its thread is folded into that stage. Nothing else in the
+  package listens to JAX's compile events: `StartupProfile`,
+  train/warmup.py's `CompileCacheMonitor` and chip_smoke.py read these
+  records.
 - `StepTimer`: rolling per-step wall-time statistics (mean/p50/p90/max,
   steps/sec, images/sec) over a sliding window, emitted through the
   MetricWriter alongside the loss scalars.
@@ -49,9 +60,11 @@ from __future__ import annotations
 import collections
 import contextlib
 import os
+import threading
 import time
 from typing import Callable, Dict, List, NamedTuple, Optional
 
+from jax import monitoring
 from jax.profiler import TraceAnnotation
 
 SPAN_RING = 4096  # records kept per span name; older ones fall off
@@ -62,7 +75,9 @@ class SpanRecord(NamedTuple):
     start: float             # time.perf_counter() at entry
     duration: float          # seconds
     count: Optional[int]     # what was counted at this boundary (the
-    #                          feed's queue depth), where there is one
+    #                          feed's queue depth; a compile's persistent-
+    #                          cache misses), where there is one
+    label: Optional[str] = None  # the program a compile record is of
 
 
 # One ring per name. No lock: a name's ring is made by dict.setdefault and
@@ -96,12 +111,16 @@ class span:
         self.duration = time.perf_counter() - self.start
         self._annotation.__exit__(exc_type, exc, tb)
         if exc_type is None:
-            ring = _rings.get(self.name)
-            if ring is None:
-                ring = _rings.setdefault(
-                    self.name, collections.deque(maxlen=SPAN_RING))
-            ring.append(SpanRecord(self.name, self.start, self.duration,
-                                   self.count))
+            _append(SpanRecord(self.name, self.start, self.duration,
+                               self.count))
+
+
+def _append(record: SpanRecord) -> None:
+    ring = _rings.get(record.name)
+    if ring is None:
+        ring = _rings.setdefault(record.name,
+                                 collections.deque(maxlen=SPAN_RING))
+    ring.append(record)
 
 
 def spans(name: Optional[str] = None) -> List[SpanRecord]:
@@ -111,6 +130,84 @@ def spans(name: Optional[str] = None) -> List[SpanRecord]:
         return list(_rings.get(name, ()))
     out = [r for ring in list(_rings.values()) for r in list(ring)]
     return sorted(out, key=lambda r: r.start)
+
+
+# --- JAX's compile stages as records -----------------------------------------
+
+COMPILE_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "compile/trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile/lower",
+    "/jax/core/compile/backend_compile_duration": "compile/backend",
+}
+_CACHE_REQUEST = "/jax/compilation_cache/compile_requests_use_cache"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+# JAX stamps its stages with time.time(); every record is on perf_counter
+_WALL_TO_PERF = time.perf_counter() - time.time()
+# per thread: `depth`, the compile stages open now; `cache`, [cache requests,
+# cache hits] since the thread's last `compile/backend` record (both events
+# fire inside that stage, before it ends)
+_thread = threading.local()
+
+
+def _program(fun_name) -> Optional[str]:
+    """`jit(train_step)` -> `train_step`: the trace stage names the
+    function, the later stages the program made of it."""
+    if not isinstance(fun_name, str):
+        return None
+    if fun_name.endswith(")") and "(" in fun_name:
+        return fun_name[fun_name.index("(") + 1:-1]
+    return fun_name
+
+
+def _on_stage_start(event: str, value, **_) -> None:
+    if event in COMPILE_STAGES:
+        _thread.depth = getattr(_thread, "depth", 0) + 1
+
+
+def _on_cache_event(event: str, **_) -> None:
+    if event == _CACHE_REQUEST or event == _CACHE_HIT:
+        seen = getattr(_thread, "cache", None)
+        if seen is None:
+            seen = _thread.cache = [0, 0]
+        seen[event == _CACHE_HIT] += 1
+
+
+def _on_compile_stage(event: str, start_time: float, end_time: float,
+                      **kw) -> None:
+    name = COMPILE_STAGES.get(event)
+    if name is None:
+        return
+    _thread.depth = max(getattr(_thread, "depth", 0) - 1, 0)
+    if _thread.depth and name == "compile/trace":
+        # a trace inside another stage of this thread (the jits `jnp` calls
+        # while a function is traced or lowered): its seconds are that
+        # stage's, so summing `compile/trace` counts each second once
+        return
+    count = None
+    if name == "compile/backend":
+        seen = getattr(_thread, "cache", None)
+        if seen and seen[0]:
+            count = seen[0] - seen[1]    # 1 on a miss, 0 on a hit
+        _thread.cache = [0, 0]
+    _append(SpanRecord(name, start_time + _WALL_TO_PERF,
+                       end_time - start_time, count,
+                       _program(kw.get("fun_name"))))
+
+
+# once, at import: a compile then records itself (JAX reports a stage's
+# start as a scalar, its interval when it ends); a call that compiles
+# nothing emits no event and costs nothing here
+monitoring.register_scalar_listener(_on_stage_start)
+monitoring.register_event_time_span_listener(_on_compile_stage)
+monitoring.register_event_listener(_on_cache_event)
+
+
+def compile_records(since: float = float("-inf")) -> List[SpanRecord]:
+    """The `compile/*` records that started at or after `since`
+    (perf_counter), by start time."""
+    return sorted((r for name in COMPILE_STAGES.values()
+                   for r in spans(name) if r.start >= since),
+                  key=lambda r: r.start)
 
 
 class StepTimer:
@@ -190,7 +287,9 @@ class StartupProfile:
     bench (tools/bench_startup.py) A/Bs cold-vs-warm. Phases are additive
     and disjoint; `total_ms` runs from construction to the first-step
     stamp, so untracked gaps (imports inside phases, loader thread spin-up)
-    are visible as total minus the named parts rather than hidden.
+    are visible as total minus the named parts rather than hidden. Each
+    phase is a `span("startup/<name>")`, so it sits in `spans()` beside
+    the compile records it holds, and in any capture.
     """
 
     def __init__(self) -> None:
@@ -202,12 +301,13 @@ class StartupProfile:
         """Context manager accumulating wall time under `name`."""
         @contextlib.contextmanager
         def _cm():
-            t0 = time.perf_counter()
+            timed = span(f"startup/{name}")
             try:
-                yield self
+                with timed:
+                    yield self
             finally:
                 self._phases[name] = self._phases.get(name, 0.0) \
-                    + (time.perf_counter() - t0) * 1e3
+                    + timed.duration * 1e3
         return _cm()
 
     def first_step(self) -> None:
