@@ -40,8 +40,20 @@ statistics and the loss in float32; the residual stream in float32. The
 pieces every token arch uses (the matmul, RMSNorm, rotary, SwiGLU, the
 chunked head + loss) are models/token_ops.py's.
 
+The rotary dimensions are STORED interleaved, as published: pairs (2i,
+2i+1) in `q_b`'s rope columns of every head and in `kv_a`'s last
+`qk_rope_head_dim` columns. Checkpoints and the weight draw see that layout;
+the forward reads the columns through a pair transpose (`mla_qkv`), so q, k
+and v are built head-major in the compute dtype straight from the
+up-projections, and only the rope slices pass through float32.
+
 Scopes (`jax.named_scope`, PERF.md section 3): `embed`, `block<i>`, `mla`
-(`q_proj`, `kv_proj`, `rope`, `attn`, `o_proj`), `dense_ffn`, `moe`
+(`q_proj`: `q_a`, its norm, and `q_b` as two head-major products, the nope
+part in the compute dtype and the pair-transposed rope part in float32;
+`kv_proj`: `kv_a`, its norm, and `kv_b` as k's nope part and v, head-major
+in the compute dtype; `rope`: the rotation of the two rope slices, their
+cast, and q and k joined from their parts, the shared k rope broadcast over
+the heads; `attn`; `o_proj`), `dense_ffn`, `moe`
 (`route`, `dispatch`, `experts`, `shared`, `combine`), `mtp`, `head` (with
 `loss` inside it). Kernels: `flash_fwd` / `flash_dq_dkv` (causal), `gmm` /
 `tgmm`.
@@ -60,8 +72,9 @@ import jax.numpy as jnp
 from dcgan_tpu.config import TokenModelConfig
 from dcgan_tpu.models.token_ops import (apply_rotary, dense_causal_attention,
                                         dtypes, head_loss, mm, normal,
-                                        recomputed, rms_norm, rotary_tables,
-                                        swiglu_apply, swiglu_init)
+                                        pairs_to_halves, recomputed, rms_norm,
+                                        rotary_tables, swiglu_apply,
+                                        swiglu_init)
 from dcgan_tpu.ops.pallas_attention import flash_attention
 
 Pytree = Any
@@ -160,6 +173,116 @@ def token_init(key, cfg: TokenModelConfig) -> Tuple[Pytree, Pytree]:
 
 # --- pieces ---------------------------------------------------------------------
 
+def _heads(x, w, out):
+    """x [B, S, r] times w [r, nh, d] (the compute dtype) as [B, nh, S, d]:
+    the head-major layout is asked of the matmul, operands in the compute
+    dtype with float32 accumulation, the result cast to `out`. A
+    `dot_general` and a swap, not `jnp.einsum`: an einsum puts its spec
+    ("bsr,rnd->bnsd") into the op_name of every operation under it, and a
+    path with that part names no scope (`benchmark/tracing.py::scope_of`),
+    so the products would fall out of `q_proj` / `kv_proj` and `mla`."""
+    y = jax.lax.dot_general(x.astype(w.dtype), w, (((2,), (0,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    return jnp.swapaxes(y, 1, 2).astype(out)
+
+
+def _parts(w, parts):
+    """The column groups of every head of w [r, nh, d]: `parts` is
+    ((width, dtype, pairs), ...) in the stored order; a group with `pairs`
+    is pair-transposed."""
+    out, lo = [], 0
+    for d, _, pairs in parts:
+        out.append(pairs_to_halves(w[..., lo:lo + d]) if pairs
+                   else w[..., lo:lo + d])
+        lo += d
+    return out
+
+
+def _up_split(c, w, nh: int, cd, parts):
+    """The up-projection c [B, S, r] (the compute dtype, so the VJP keeps
+    it as its residual in that dtype) @ w [r, nh * d] as head-major parts
+    [B, nh, S, width] in their dtypes, each from its own product over its
+    own columns (`_parts`), so no part is made wider or in another dtype
+    than it leaves in."""
+    # the weight's groups are cast and relaid out once c is here: left free,
+    # the compiler makes every layer's copies at the program's start, and
+    # they lie beside the peak of an earlier layer
+    c, w = jax.lax.optimization_barrier((c, w))
+    return tuple(_heads(c, wi.astype(cd), dt) for wi, (_, dt, _) in zip(
+        _parts(w.reshape(w.shape[0], nh, -1), parts), parts))
+
+
+def _up_whole(c, w, nh: int, cd, parts):
+    """The same parts, cut from ONE float32 product over every column in
+    the stored order: the values `_up_split` gives, and the VJP of the
+    unsplit matmul (one contraction for the gradient to c)."""
+    y = _heads(c, w.astype(cd).reshape(w.shape[0], nh, -1), jnp.float32)
+    return tuple(yi.astype(dt) for yi, (_, dt, _) in zip(_parts(y, parts),
+                                                         parts))
+
+
+def _up_bwd(nh: int, cd, parts, res, g):
+    c, w = res
+    # the weight's cast is tied to the cotangent, as the forward's is to c:
+    # left free, every layer's copy is made early and held
+    w, g = jax.lax.optimization_barrier((w, g))
+    return jax.vjp(functools.partial(_up_whole, nh=nh, cd=cd, parts=parts),
+                   c, w)[1](g)
+
+
+_up_heads = jax.custom_vjp(_up_split, nondiff_argnums=(2, 3, 4))
+_up_heads.defvjp(
+    lambda c, w, nh, cd, parts: (_up_split(c, w, nh, cd, parts), (c, w)),
+    _up_bwd)
+
+
+def mla_qkv(cq, ckv, k_rope, q_b, kv_b, cfg: TokenModelConfig, rope):
+    """The attention's q, k [B, nh, S, nope + rope] and v [B, nh, S, v], in
+    the compute dtype, from the normed latents cq [B, S, q rank] and ckv
+    [B, S, kv rank], the shared rotary input k_rope [B, S, rope] (float32)
+    and the up-projections' stored weights q_b, kv_b.
+
+    The values are those of a float32 projection swapped to head-major,
+    rotated and cast, and so is the VJP, but no float32 array of the
+    attention's width is made in the forward: the nope parts and v leave
+    their matmuls in the compute dtype and head-major, and only the rope
+    slices are rotated in float32 and cast once. The backward contracts
+    each projection's cotangent in one product, as the unsplit matmul's
+    transpose does. The stored rotary columns stay interleaved (pairs
+    (2i, 2i+1), as published); the forward reads q_b's rope columns
+    through a pair transpose (`pairs_to_halves`), so the product comes out
+    in halves, and k_rope likewise. The scopes: `q_proj` and `kv_proj`
+    hold the up-projections' head-major products (forward and backward)
+    and the weights' relayout; `rope` the rotation of the rope slices, the
+    joins of nope and rope and the broadcast of k's shared part."""
+    cd, _ = dtypes(cfg)
+    nh, dn, dr, dv = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
+                      cfg.qk_rope_head_dim, cfg.v_head_dim)
+    pairs = cfg.rope_interleave
+    # the latents are cast before the VJP's boundary, as the unsplit
+    # matmul's operands were: the same gradient, a residual in the compute
+    # dtype
+    with jax.named_scope("q_proj"):
+        q_nope, q_rope = _up_heads(cq.astype(cd), q_b, nh, cd,
+                                   ((dn, cd, False), (dr, jnp.float32, pairs)))
+    with jax.named_scope("kv_proj"):
+        k_nope, v = _up_heads(ckv.astype(cd), kv_b, nh, cd,
+                              ((dn, cd, False), (dv, cd, False)))
+    with jax.named_scope("rope"):
+        cos, sin = rope
+        q = jnp.concatenate(
+            [q_nope, apply_rotary(q_rope, cos, sin, False).astype(cd)],
+            axis=-1)
+        k_rope = apply_rotary(k_rope, cos, sin, pairs)
+        # broadcast in float32 and cast: the backward sums the heads'
+        # cotangents of the shared part in float32
+        k = jnp.concatenate(
+            [k_nope,
+             jnp.broadcast_to(k_rope[:, None], k_nope.shape[:3] + (dr,))
+             .astype(cd)], axis=-1)
+    return q, k, v
+
+
 def mla_apply(p: Pytree, x, cfg: TokenModelConfig, rope):
     """Latent attention over x [B, S, H] (already normed, float32)."""
     cd, _ = dtypes(cfg)
@@ -169,25 +292,13 @@ def mla_apply(p: Pytree, x, cfg: TokenModelConfig, rope):
     with jax.named_scope("q_proj"):
         cq = rms_norm(mm(x, p["q_a"]["w"], cd), p["q_norm"]["scale"],
                       cfg.rms_norm_eps)
-        q = mm(cq, p["q_b"]["w"], cd).reshape(b, s, nh, dn + dr)
     with jax.named_scope("kv_proj"):
         ckv = mm(x, p["kv_a"]["w"], cd)
         k_rope = ckv[..., cfg.kv_lora_rank:]                    # [B, S, dr]
         ckv = rms_norm(ckv[..., :cfg.kv_lora_rank], p["kv_norm"]["scale"],
                        cfg.rms_norm_eps)
-        kv = mm(ckv, p["kv_b"]["w"], cd).reshape(b, s, nh, dn + dv)
-    with jax.named_scope("rope"):
-        cos, sin = rope
-        q = jnp.swapaxes(q, 1, 2)                               # [B, nh, S, .]
-        kv = jnp.swapaxes(kv, 1, 2)
-        q_rope = apply_rotary(q[..., dn:], cos, sin, cfg.rope_interleave)
-        k_rope = apply_rotary(k_rope, cos, sin, cfg.rope_interleave)
-        q = jnp.concatenate([q[..., :dn], q_rope], axis=-1).astype(cd)
-        k = jnp.concatenate(
-            [kv[..., :dn],
-             jnp.broadcast_to(k_rope[:, None], (b, nh, s, dr))],
-            axis=-1).astype(cd)
-        v = kv[..., dn:].astype(cd)
+    q, k, v = mla_qkv(cq, ckv, k_rope, p["q_b"]["w"], p["kv_b"]["w"], cfg,
+                      rope)
     with jax.named_scope("attn"):
         fold = lambda a: a.reshape(b * nh, s, a.shape[-1])
         scale = float(dn + dr) ** -0.5

@@ -1,6 +1,7 @@
 """What the token archs share (models/mla_moe.py, models/loop_lm.py,
 models/sambay.py): the
-matmul of the precision policy, RMSNorm, rotary, SwiGLU, dense masked
+matmul of the precision policy, RMSNorm, rotary (and the pair-to-halves
+relayout of interleaved rotary dimensions), SwiGLU, dense masked
 attention for short sequences, the chunked head + loss, and what a block
 keeps across its recomputation.
 
@@ -82,6 +83,16 @@ def rotary_tables(seq_len: int, dim: int, theta: float):
     return jnp.cos(ang), jnp.sin(ang)
 
 
+def pairs_to_halves(x):
+    """The last axis read as pairs (2i, 2i+1) and laid out as halves (the
+    first members, then the second): a static reshape-transpose, so its
+    transpose is one too (no strided slices, no scatter-add). Applied to a
+    weight's columns it de-interleaves what the product gives."""
+    d = x.shape[-1]
+    return jnp.swapaxes(x.reshape(*x.shape[:-1], d // 2, 2), -1, -2
+                        ).reshape(x.shape)
+
+
 def apply_rotary(x, cos, sin, interleave: bool):
     """Rotary embedding over the last axis of x [..., S, d] (float32). With
     `interleave` the stored dimensions are pairs (2i, 2i+1), de-interleaved
@@ -89,7 +100,7 @@ def apply_rotary(x, cos, sin, interleave: bool):
     lie (the `rotate_half` form)."""
     x = x.astype(jnp.float32)
     if interleave:
-        x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+        x = pairs_to_halves(x)
     half = x.shape[-1] // 2
     rot = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
     return x * cos + rot * sin

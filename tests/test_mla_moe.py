@@ -2,10 +2,13 @@
 layer's share arithmetic, no token dropped at any imbalance, the trainer on
 the tiny preset, and the image-only services refusing by name."""
 
+import collections
 import dataclasses
 import importlib.util
 import math
 import os
+import re
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -293,6 +296,122 @@ def test_rotary_de_interleaves_pairs(reference):
         got, mla_moe.apply_rotary(halves, cos, sin, False), rtol=1e-6)
     # position 0 is not rotated
     np.testing.assert_allclose(got[:, 0], halves[:, 0], rtol=1e-6)
+
+
+def qkv_swapped_in_float32(cq, ckv, k_rope, q_b, kv_b, cfg, rope):
+    """The formulation `mla_qkv` replaces: both up-projections in float32,
+    swapped to head-major in float32, the interleaved rope dimensions
+    de-interleaved by strided slices, nope and rope joined, then cast.
+    tests/test_tpu_compile.py compiles it at the published widths."""
+    cd = jnp.dtype(cfg.compute_dtype)
+    b, s, _ = cq.shape
+    nh, dn, dr, dv = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
+                      cfg.qk_rope_head_dim, cfg.v_head_dim)
+    q = mla_moe.mm(cq, q_b, cd).reshape(b, s, nh, dn + dr)
+    kv = mla_moe.mm(ckv, kv_b, cd).reshape(b, s, nh, dn + dv)
+    q, kv = jnp.swapaxes(q, 1, 2), jnp.swapaxes(kv, 1, 2)
+    cos, sin = rope
+
+    def rotary(x):
+        if cfg.rope_interleave:
+            x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+        return mla_moe.apply_rotary(x, cos, sin, False)
+    q_rope, k_rope = rotary(q[..., dn:]), rotary(k_rope)
+    q = jnp.concatenate([q[..., :dn], q_rope], axis=-1).astype(cd)
+    k = jnp.concatenate(
+        [kv[..., :dn], jnp.broadcast_to(k_rope[:, None], (b, nh, s, dr))],
+        axis=-1).astype(cd)
+    return q, k, kv[..., dn:].astype(cd)
+
+
+@pytest.mark.parametrize("compute_dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("interleave", [True, False])
+def test_qkv_are_the_float32_formulations_values(compute_dtype, interleave):
+    """`mla_qkv` gives what the float32 formulation gives: q, k and v, and
+    the VJP to the two latents, to k_rope and to both stored weights, with
+    the rotary dimensions stored as pairs or as halves. Bit for bit under
+    bfloat16 compute (the shipped configurations'); under float32 compute
+    to float32 rounding, since a float32 product over a column group of
+    another width may sum in another order on this backend."""
+    cfg = TokenModelConfig(compute_dtype=compute_dtype,
+                           num_attention_heads=4, q_lora_rank=48,
+                           kv_lora_rank=32, qk_nope_head_dim=16,
+                           qk_rope_head_dim=8, v_head_dim=16,
+                           rope_interleave=interleave)
+    b, s, nh = 2, 16, cfg.num_attention_heads
+    ks = jax.random.split(jax.random.key(6), 8)
+    args = (jax.random.normal(ks[0], (b, s, cfg.q_lora_rank)),
+            jax.random.normal(ks[1], (b, s, cfg.kv_lora_rank)),
+            jax.random.normal(ks[2], (b, s, cfg.qk_rope_head_dim)),
+            0.2 * jax.random.normal(ks[3], (cfg.q_lora_rank, nh * 24)),
+            0.2 * jax.random.normal(ks[4], (cfg.kv_lora_rank, nh * 32)))
+    rope = mla_moe.rotary_tables(s, cfg.qk_rope_head_dim, 10000.0)
+    cd = jnp.dtype(compute_dtype)
+    cots = (jax.random.normal(ks[5], (b, nh, s, 24)).astype(cd),
+            jax.random.normal(ks[6], (b, nh, s, 24)).astype(cd),
+            jax.random.normal(ks[7], (b, nh, s, 16)).astype(cd))
+
+    def value_and_vjp(fn):
+        out, vjp = jax.vjp(lambda *a: fn(*a, cfg, rope), *args)
+        return out, vjp(cots)
+
+    def same(a, w, name):
+        if cd == jnp.bfloat16:
+            np.testing.assert_array_equal(a, w, err_msg=name)
+        else:
+            scale = float(jnp.max(jnp.abs(w)))
+            np.testing.assert_allclose(
+                a / scale, w / scale, rtol=0,
+                atol=16 * float(jnp.finfo(jnp.float32).eps), err_msg=name)
+    (got, got_g), (want, want_g) = (
+        jax.jit(value_and_vjp, static_argnums=0)(f)
+        for f in (mla_moe.mla_qkv, qkv_swapped_in_float32))
+    for name, a, w in zip("qkv", got, want, strict=True):
+        assert a.dtype == cd and a.shape == w.shape
+        same(a, w, name)
+    for name, a, w in zip(("cq", "ckv", "k_rope", "q_b", "kv_b"), got_g,
+                          want_g, strict=True):
+        assert a.dtype == w.dtype == jnp.float32, name
+        same(a, w, name)
+
+
+def test_the_latent_projections_are_booked_to_their_scopes(monkeypatch):
+    """Every product of the step that takes or gives a latent (q rank, kv
+    rank: `q_a`, `q_b`, `kv_b` in the forward, the recomputation and the
+    backward) carries an `op_name` that the benchmark's trace reduction
+    books under `mla/q_proj` or `mla/kv_proj`. A name part that is no scope
+    (an einsum's spec, say) would take the product out of `mla_ms` and
+    `mtp_ms` and into no named scope."""
+    path = os.path.join(REPO, "benchmark", "tracing.py")
+    spec = importlib.util.spec_from_file_location("tracing_t", path)
+    tracing = importlib.util.module_from_spec(spec)
+    monkeypatch.setitem(sys.modules, spec.name, tracing)  # its dataclasses
+    spec.loader.exec_module(tracing)
+    # ranks of a size no other axis of the tiny model has
+    cfg = dataclasses.replace(get_preset("mla_moe_tiny").model,
+                              compute_dtype="bfloat16", q_lora_rank=56,
+                              kv_lora_rank=40)
+    state = mla_moe.lm_init(jax.random.key(0), cfg)
+    rest = {n: state[n] for n in mla_moe.LM_READS}
+    fn = jax.value_and_grad(lambda p, ids: mla_moe.lm_loss(p, rest, ids, cfg),
+                            has_aux=True)
+    text = jax.jit(fn).lower(
+        state["params"], jax.ShapeDtypeStruct((2, cfg.seq_len), jnp.int32)
+    ).as_text(dialect="hlo", debug_info=True)
+    dims = {m[1]: {int(d) for d in m[2].split(",") if d} for m in re.finditer(
+        r"^\s*(?:ROOT )?([\w.\-]+) = \w+\[([\d,]*)\]", text, re.M)}
+    booked = collections.Counter()
+    for m in re.finditer(r"^\s*(?:ROOT )?([\w.\-]+) = \S+ dot\(([\w.\-]+), "
+                         r"([\w.\-]+)\).*op_name=\"([^\"]*)\"", text, re.M):
+        if not {cfg.q_lora_rank, cfg.kv_lora_rank} & (
+                dims[m[1]] | dims[m[2]] | dims[m[3]]):
+            continue
+        parts = tracing.scope_of(m[4]).split("/")
+        assert "mla" in parts and {"q_proj", "kv_proj"} & set(parts), m[4]
+        booked["q_proj" if "q_proj" in parts else "kv_proj"] += 1
+    # three trunk blocks and the multi-token module, each in the forward,
+    # its recomputation and the backward
+    assert booked["q_proj"] >= 4 * 3 and booked["kv_proj"] >= 4 * 3, booked
 
 
 def test_the_step_is_the_same_on_one_device_and_over_a_data_mesh():
