@@ -1,7 +1,8 @@
 """CLI: `python -m dcgan_tpu.analysis [--semantic|--protocol|--all] ...`.
 
 Three tiers behind one entry point and one exit contract (exit 1 on any
-non-baselined finding — tests/test_tools.py pins the umbrella clean):
+non-baselined finding — tests/test_tools_analysis_all*.py pin the
+umbrella clean):
 
 - default: the import-free AST tier (DCG001-006 + the DCG013 divergence
   lint) over the package or the given paths, milliseconds per run; a
@@ -39,7 +40,7 @@ import sys
 import time
 from typing import List, Optional
 
-from dcgan_tpu.analysis import core
+from dcgan_tpu.analysis import core, semantic  # jax-free until they run
 
 
 def main(argv: Optional[List[str]] = None) -> int:
@@ -97,6 +98,11 @@ def main(argv: Optional[List[str]] = None) -> int:
                         "analysis/protocol.lock.jsonl) — drift findings "
                         "are moot while regenerating, termination/"
                         "lockstep findings still gate the exit code")
+    p.add_argument("--backend", default=None, choices=semantic.BACKENDS,
+                   help="with --write-manifest: lower and write only this "
+                        "backend's share of the program rows (gspmd's "
+                        "share carries the serving rows, both carry the "
+                        "transports)")
     p.add_argument("--stream-table", action="store_true",
                    help="with --semantic: print DESIGN §6c.1's generated "
                         "dispatch-stream table from the live census and "
@@ -108,9 +114,10 @@ def main(argv: Optional[List[str]] = None) -> int:
         p.error("--semantic / --protocol / --all are mutually exclusive "
                 "(--all already includes the other two)")
     if (args.write_manifest is not None or args.stream_table
-            or args.manifest) and not (args.semantic or args.run_all):
-        p.error("--write-manifest/--manifest require --semantic or "
-                "--all; --stream-table requires --semantic")
+            or args.manifest or args.backend) \
+            and not (args.semantic or args.run_all):
+        p.error("--write-manifest/--manifest/--backend require --semantic "
+                "or --all; --stream-table requires --semantic")
     if args.stream_table and args.run_all:
         p.error("--stream-table is a pure printer — run it under "
                 "--semantic, separately from the --all gate")
@@ -166,14 +173,14 @@ def _ast_tier(p, args, full_registry: bool):
 
 def _semantic_tier(p, args):
     from dcgan_tpu.analysis import manifest as manifest_lib
-    from dcgan_tpu.analysis import semantic
 
     writing = args.write_manifest is not None
     findings, records = semantic.run_semantic(
         checks=None if args.run_all else args.checks,
         manifest_path=args.manifest,
         # drift against the old manifest is moot while regenerating it
-        compare_manifest=not writing)
+        compare_manifest=not writing,
+        backends=(args.backend,) if args.backend else semantic.BACKENDS)
     if writing:
         path = args.write_manifest or manifest_lib.default_manifest_path()
         with open(path, "w", encoding="utf-8") as f:
@@ -189,6 +196,10 @@ def _semantic_tier(p, args):
         # DCG008 drift exemption must not be called stale by the very
         # run that rewrites the manifest
         ran = tuple(c for c in ran if c != "DCG008")
+    if args.backend:
+        # one share cannot prove a baseline row dead: its finding may
+        # belong to the other share's programs
+        ran = ()
     return findings, records, {"programs": len(records), "ran_checks": ran}
 
 
@@ -248,8 +259,6 @@ def _run_semantic(p: argparse.ArgumentParser, args) -> int:
     # topology first, BEFORE anything can initialize jax: the census needs
     # >= 2 CPU devices (collectives over a size-1 axis trace away) and the
     # committed fingerprints assume partitionable threefry
-    from dcgan_tpu.analysis import semantic
-
     semantic.ensure_semantic_platform()
     from dcgan_tpu.analysis import manifest as manifest_lib
 
@@ -296,8 +305,6 @@ def _run_all(p: argparse.ArgumentParser, args) -> int:
     # FIRST jax import in this process — the AST tier never imports jax
     # and the protocol tier only patches process identity, so one
     # arrangement up front serves all three
-    from dcgan_tpu.analysis import semantic
-
     semantic.ensure_semantic_platform()
 
     tier_meta = {}

@@ -10,10 +10,10 @@ difference as findings (DCG008), so the §6c.1 dispatch-stream table, the
 donation-aliasing story, and the program inventory can no longer drift
 from the code without failing tier-1.
 
-Byte-identity is part of the contract (tests/test_tools.py pins it): rows
-are sorted by name, keys are sorted, floats never appear, and the header
-carries no timestamps — regenerating an unchanged repo reproduces the
-file exactly.
+Byte-identity is part of the contract (tests/test_tools_analysis_all*.py
+pin it): rows are sorted by name, keys are sorted, floats never appear,
+and the header carries no timestamps — regenerating an unchanged repo
+reproduces the file exactly.
 """
 
 from __future__ import annotations

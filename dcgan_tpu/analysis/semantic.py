@@ -92,6 +92,11 @@ def _sanitized(jaxpr_text: str) -> str:
         lambda m: "frozenset({" + ", ".join(sorted(
             e.strip() for e in m.group(1).split(","))) + "})", text)
 
+#: the ParallelTrain backends `enumerate_audits` lowers, each a share of
+#: the manifest's program rows; the serving plane's rows (`serve::`) lower
+#: gspmd's sampler and ride with its share
+BACKENDS = ("gspmd", "shard_map")
+
 #: where findings for each enumeration group anchor
 GROUP_PATHS = {
     "gspmd": "dcgan_tpu/parallel/api.py",
@@ -389,8 +394,10 @@ def _base(name: str) -> str:
     return name.split("@", 1)[0]
 
 
-def enumerate_audits() -> Tuple[List[ProgramAudit], List[CoverageRow]]:
-    """Lower the full dispatch surface at the small preset. Order is
+def enumerate_audits(backends: Sequence[str] = BACKENDS
+                     ) -> Tuple[List[ProgramAudit], List[CoverageRow]]:
+    """Lower the full dispatch surface at the small preset, or the rows
+    of `backends` alone (a share of the manifest). Order is
     deterministic; the returned audits are the manifest's program rows."""
     _require_platform()
     import jax
@@ -406,7 +413,7 @@ def enumerate_audits() -> Tuple[List[ProgramAudit], List[CoverageRow]]:
     coverage: List[CoverageRow] = []
     serve_rows: List[Tuple[str, object, tuple]] = []
 
-    for backend in ("gspmd", "shard_map"):
+    for backend in backends:
         path = GROUP_PATHS[backend]
         cfg = small_config(backend)
         mesh = make_mesh(cfg.mesh, devices)
@@ -1063,11 +1070,21 @@ def check_manifest(records: Sequence[manifest_lib.ProgramRecord],
 def run_semantic(checks: Optional[Sequence[str]] = None,
                  manifest_path: Optional[str] = None,
                  compare_manifest: bool = True,
+                 backends: Sequence[str] = BACKENDS,
                  ) -> Tuple[List[Finding],
                             List[manifest_lib.ProgramRecord]]:
     """The full semantic tier: enumerate + audit + every requested checker
     (default: all five). Returns (findings, manifest records); the CLI
-    applies the shared baseline on top, exactly like the AST tier."""
+    applies the shared baseline on top, exactly like the AST tier.
+    `backends` narrows the enumeration to a share of the manifest, which
+    only a regeneration (`compare_manifest=False`) may ask for: drift
+    against the whole committed manifest would call the other share
+    missing."""
+    if tuple(backends) != BACKENDS and compare_manifest:
+        raise ValueError("a share of the manifest (backends "
+                         f"{list(backends)}) can only be regenerated "
+                         "(--write-manifest), not checked against the "
+                         "whole committed manifest")
     if checks:
         checks = [c.upper() for c in checks]
         unknown = sorted(set(checks) - set(SEMANTIC_CHECKS))
@@ -1081,7 +1098,7 @@ def run_semantic(checks: Optional[Sequence[str]] = None,
     # (compare_manifest=False is the CLI's --write-manifest mode) always
     # enumerates: the records ARE its output.
     if active - {"DCG011"} or not compare_manifest:
-        audits, coverage = enumerate_audits()
+        audits, coverage = enumerate_audits(backends)
         records = records_from(audits)
     else:
         audits, coverage, records = [], [], []

@@ -729,7 +729,8 @@ class TestTripwire:
 # Fixtures are synthetic jitted programs audited through
 # semantic.audit_callable — the spec for what the lowered-program checkers
 # resolve, each with a clean twin. The real enumeration runs as the
-# tier-1 subprocess pin (tests/test_tools.py), not in-process.
+# tier-1 subprocess pin (tests/test_tools_analysis_all*.py), not
+# in-process.
 
 import dataclasses as _dc
 import os as _os

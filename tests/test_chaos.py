@@ -1,7 +1,7 @@
 """Fail-operational layer (ISSUE 3): fault plans, quarantine, retry,
 checkpoint integrity fallback, NaN rollback — the fast in-process half of
 the proof (tools/chaos_drill.py is the subprocess end-to-end half; its
---smoke subset is pinned in tests/test_tools.py).
+--smoke subset is pinned in tests/test_tools_chaos.py).
 
 Everything here is `chaos`-marked and stays in the tier-1 (not-slow) suite
 except the full-trainer rollback/parity runs at the bottom."""

@@ -18,7 +18,7 @@ headline row with per-arm collective-op censuses.
 The census-shrink half of the acceptance (one collective per bucket
 instead of one per leaf) is pinned by the committed manifest's
 `@overlap` rows, checked in tests/test_zero.py and the analyzer lock
-byte-compare in tests/test_tools.py.
+byte-compare in tests/test_tools_analysis_all*.py.
 """
 
 import json

@@ -2,7 +2,7 @@
 stop, hung-collective watchdog, device-resident rollback snapshots — all
 with fake process-count/allgather shims, no subprocesses. The end-to-end
 2-process proofs live in tools/chaos_drill.py --multihost (smoke-pinned in
-test_tools.py) and the parity A/B in test_multihost.py."""
+test_tools_chaos.py) and the parity A/B in test_multihost.py."""
 
 import signal
 import threading

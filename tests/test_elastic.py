@@ -8,7 +8,7 @@ save; `restore_latest` must reshard across mesh/process changes while the
 same-topology path stays byte-identical in behavior (sidecar present,
 reshard not taken, no elastic/* keys). The full cross-process drill lives
 in tools/chaos_drill.py (elastic-shrink / elastic-grow, the shrink smoke
-pinned by tests/test_tools.py)."""
+pinned by tests/test_tools_elastic.py)."""
 
 import json
 import os

@@ -3,7 +3,7 @@ virtual-trainer scenarios, the committed protocol lock, the DCG013
 divergence lint, and the DCG014/015 stale-exemption audits — all
 in-process (the simulator needs no subprocesses by design). The live
 2-process replay proof is tools/chaos_drill.py mh-sigterm-stop (pinned
-via test_tools.py), which compares a real trainer's logged collective
+via test_tools_chaos.py), which compares a real trainer's logged collective
 sequence against the committed simulator schedule."""
 
 import json

@@ -9,12 +9,12 @@ prints well-formed JSON", the numbers only mean anything on a chip).
 
 import json
 import os
-import subprocess
 import sys
 
 import pytest
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from tool_smoke import REPO, json_rows, run_tool
+
 sys.path.insert(0, REPO)
 
 from tools.capture_all import (  # noqa: E402
@@ -227,9 +227,7 @@ class TestTraceSummary:
              "args": {"name": "tf_XLATfrtCpuClient/1"}},
             {"ph": "X", "pid": 7, "tid": 2, "name": "dot.1",
              "ts": 0, "dur": 500}])
-        res = subprocess.run(
-            [sys.executable, "tools/trace_summary.py", path], cwd=REPO,
-            capture_output=True, text=True, timeout=120)
+        res = run_tool(["tools/trace_summary.py", path], timeout=120)
         assert res.returncode == 0, res.stderr
         rows = [json.loads(l) for l in res.stdout.splitlines()]
         assert rows and rows[0]["program"] == "dot.1"
@@ -239,9 +237,7 @@ class TestTraceSummary:
         path = self._write_trace(tmp_path / "e.trace.json.gz", [
             {"ph": "M", "pid": 7, "name": "process_name",
              "args": {"name": "/host:CPU"}}])
-        res = subprocess.run(
-            [sys.executable, "tools/trace_summary.py", path], cwd=REPO,
-            capture_output=True, text=True, timeout=120)
+        res = run_tool(["tools/trace_summary.py", path], timeout=120)
         assert res.returncode == 1
         assert res.stdout.strip() == ""
         assert "no duration events" in res.stderr
@@ -277,392 +273,6 @@ class TestTrainerLoopParsing:
         assert pts == [(500, 30.0), (1000, 33.2), (5000, 46.0)]
 
 
-class TestAnalysisAllSmoke:
-    """THE consolidated analyzer pin (ISSUE 14, replacing the separate
-    AST + semantic subprocess pins): ONE `python -m dcgan_tpu.analysis
-    --all` subprocess must run every tier CLEAN — zero non-baselined
-    findings across DCG001-015 — AND regenerate BOTH committed contracts
-    (analysis/programs.lock.jsonl, analysis/protocol.lock.jsonl)
-    byte-identically. `--write-manifest/--write-lock <tmp>` recompute
-    every row (exit code still gated on the non-drift findings), and the
-    byte compares against the committed files ARE the drift checks at
-    full strength. The CLI arranges its own canonical topology (CPU, 2
-    virtual devices) before jax initializes, so the pin is
-    environment-stable. Per-tier flags keep working and are covered
-    in-process (tests/test_analysis.py, tests/test_protocol.py) plus the
-    dedicated --protocol subprocess pin below."""
-
-    def test_all_tiers_clean_and_locks_reproducible_within_budget(
-            self, tmp_path):
-        import time
-
-        committed_manifest = os.path.join(
-            REPO, "dcgan_tpu", "analysis", "programs.lock.jsonl")
-        committed_lock = os.path.join(
-            REPO, "dcgan_tpu", "analysis", "protocol.lock.jsonl")
-        out_manifest = str(tmp_path / "programs.lock.jsonl")
-        out_lock = str(tmp_path / "protocol.lock.jsonl")
-        t0 = time.monotonic()
-        res = subprocess.run(
-            [sys.executable, "-m", "dcgan_tpu.analysis", "--all",
-             "--json", "--write-manifest", out_manifest,
-             "--write-lock", out_lock],
-            cwd=REPO, env=dict(os.environ, JAX_PLATFORMS="cpu"),
-            capture_output=True, text=True, timeout=510)
-        elapsed = time.monotonic() - t0
-        assert res.returncode == 0, (res.stdout[-1500:], res.stderr[-800:])
-        summary = json.loads(
-            [l for l in res.stdout.splitlines()
-             if l.startswith("{")][-1])
-        assert summary["label"] == "dcgan-analysis-all"
-        assert summary["new_findings"] == 0
-        tiers = summary["tiers"]
-        # per-tier timing is part of the contract: a tier that silently
-        # stopped running would report no timing row
-        assert set(tiers) == {"ast", "semantic", "protocol"}
-        assert all(t["ms"] > 0 for t in tiers.values())
-        assert tiers["ast"]["files"] > 50
-        assert tiers["semantic"]["programs"] > 60
-        # the protocol lattice really explored (ISSUE 14 acceptance:
-        # >= 4 configs x >= 6 interleavings); the stderr line makes
-        # silent shrinkage visible in CI logs
-        assert tiers["protocol"]["configs"] >= 4
-        assert tiers["protocol"]["interleavings"] >= 24
-        assert "explored" in res.stderr and "interleaving" in res.stderr
-        for out, committed, what in (
-                (out_manifest, committed_manifest, "programs.lock.jsonl"),
-                (out_lock, committed_lock, "protocol.lock.jsonl")):
-            with open(out, "rb") as f_new, open(committed, "rb") as f_old:
-                assert f_new.read() == f_old.read(), (
-                    f"regenerated {what} differs from the committed file "
-                    "— either the contract drifted (regenerate "
-                    "deliberately and review the diff) or determinism "
-                    "broke")
-        # The budget keeps the tier-1 pin from quietly eating the tier.
-        # Recalibrated as the tiers grew (the semantic tier compiles
-        # every dispatchable program: 70 -> 97 manifest rows across the
-        # pallas/precision, progressive, and live-elastic PRs, then
-        # 97 -> 124 with the collective-overlap variants; the protocol
-        # lattice is 129 interleavings with the serving-fleet
-        # promotion-drain configs): measured ~380 s quiet / 444 s under
-        # contention on a 1-core host at 124 rows, where ~370 s quiet
-        # was the 97-row measurement and the original 300 s bound — set
-        # when the tier took ~65 s on 2 cores — already failed BEFORE
-        # the live-elastic rows landed (339 s at that commit on the
-        # same host).
-        assert elapsed < 530, f"--all took {elapsed:.0f}s"
-
-
-class TestProtocolAnalysisSmoke:
-    """ISSUE 14's dedicated tier pin: `--protocol --json` alone must run
-    clean inside a tight budget (the simulator is pure host code — if it
-    slows down, its lattice grew in a way someone should look at), and
-    must PRINT the explored-interleaving counts so lattice shrinkage can
-    never be silent in logs."""
-
-    def test_protocol_clean_within_budget(self):
-        import time
-
-        t0 = time.monotonic()
-        res = subprocess.run(
-            [sys.executable, "-m", "dcgan_tpu.analysis", "--protocol",
-             "--json"],
-            cwd=REPO, env=dict(os.environ, JAX_PLATFORMS="cpu"),
-            capture_output=True, text=True, timeout=180)
-        elapsed = time.monotonic() - t0
-        assert res.returncode == 0, (res.stdout[-1500:], res.stderr[-800:])
-        summary = json.loads(
-            [l for l in res.stdout.splitlines()
-             if l.startswith("{")][-1])
-        assert summary["label"] == "dcgan-analysis-protocol"
-        assert summary["new_findings"] == 0
-        assert summary["configs"] >= 4
-        assert summary["interleavings"] >= 24
-        import re as _re
-
-        m = _re.search(r"explored (\d+) interleaving\(s\) across (\d+) "
-                       r"knob config\(s\)", res.stderr)
-        assert m, f"no interleaving-count line in stderr: {res.stderr}"
-        assert int(m.group(1)) == summary["interleavings"]
-        assert elapsed < 120, f"protocol tier took {elapsed:.0f}s"
-
-
-@pytest.mark.chaos
-class TestChaosDrillSmoke:
-    """tools/chaos_drill.py --smoke pinned into tier-1 (not slow, per the
-    chaos-marker contract in pytest.ini): the cheap scenario subset —
-    corrupt-record quarantine, transient-IO retry, services-crash
-    surfacing — must keep passing end to end through real trainer
-    subprocesses. The full 9-scenario matrix (rollback + checkpoint
-    fallback + the ISSUE 6 observability trio included) runs standalone:
-    `python tools/chaos_drill.py`."""
-
-    def test_smoke_matrix_passes(self):
-        res = subprocess.run(
-            [sys.executable, "tools/chaos_drill.py", "--smoke"], cwd=REPO,
-            env=dict(os.environ, JAX_PLATFORMS="cpu"),
-            capture_output=True, text=True, timeout=600)
-        lines = [json.loads(l) for l in res.stdout.splitlines()
-                 if l.startswith("{")]
-        summary = lines[-1]
-        assert res.returncode == 0, (res.stdout[-1500:], res.stderr[-500:])
-        assert summary["label"] == "chaos-drill"
-        assert summary["scenarios"] == 3 and summary["failed"] == 0
-        scenarios = {p["scenario"]: p for p in lines if "scenario" in p}
-        assert set(scenarios) == {"corrupt-record", "io-error-once",
-                                  "services-crash"}
-        assert scenarios["corrupt-record"]["corrupt_records"] >= 1
-
-    def test_multihost_smoke_passes_within_budget(self):
-        """tools/chaos_drill.py --multihost --smoke pinned into tier-1
-        (ISSUE 4): the cheapest coordinated-recovery scenario — SIGTERM on
-        one host of a real 2-process localhost-gRPC job becomes a
-        collective stop + bit-exact resume — with an explicit runtime
-        budget so the pin can never quietly eat the tier. The full
-        3-scenario matrix (coordinated rollback + watchdog trip included)
-        runs standalone: `python tools/chaos_drill.py --multihost`."""
-        import time
-
-        t0 = time.monotonic()
-        res = subprocess.run(
-            [sys.executable, "tools/chaos_drill.py", "--multihost",
-             "--smoke"], cwd=REPO,
-            env=dict(os.environ, JAX_PLATFORMS="cpu"),
-            capture_output=True, text=True, timeout=420)
-        elapsed = time.monotonic() - t0
-        lines = [json.loads(l) for l in res.stdout.splitlines()
-                 if l.startswith("{")]
-        summary = lines[-1]
-        assert res.returncode == 0, (res.stdout[-1500:], res.stderr[-500:])
-        assert summary["label"] == "chaos-drill-multihost"
-        assert summary["scenarios"] == 1 and summary["failed"] == 0
-        scenarios = {p["scenario"]: p for p in lines if "scenario" in p}
-        assert set(scenarios) == {"mh-sigterm-stop"}
-        assert scenarios["mh-sigterm-stop"]["resumed"] is True
-        # runtime budget: two tiny 2-process launches; 300 s is ~4x the
-        # measured cost on a quiet host, headroom for CI contention
-        assert elapsed < 300, f"multihost smoke took {elapsed:.0f}s"
-
-
-@pytest.mark.chaos
-class TestObservabilitySmoke:
-    """ISSUE 6's tier-1 pin (chaos-marker pattern from PRs 3-5): the
-    trigger-file capture -> in-process digest loop and the flight-recorder
-    dump triggers must keep working end to end through real trainer
-    subprocesses, inside an explicit runtime budget. The full matrix runs
-    standalone: `JAX_PLATFORMS=cpu python tools/chaos_drill.py`."""
-
-    def test_trace_trigger_and_flight_recorder_within_budget(self):
-        import time
-
-        t0 = time.monotonic()
-        res = subprocess.run(
-            [sys.executable, "tools/chaos_drill.py", "--only",
-             "flight-recorder", "watchdog-dump", "trace-trigger"],
-            cwd=REPO, env=dict(os.environ, JAX_PLATFORMS="cpu"),
-            capture_output=True, text=True, timeout=420)
-        elapsed = time.monotonic() - t0
-        lines = [json.loads(l) for l in res.stdout.splitlines()
-                 if l.startswith("{")]
-        summary = lines[-1]
-        assert res.returncode == 0, (res.stdout[-1500:], res.stderr[-500:])
-        assert summary["scenarios"] == 3 and summary["failed"] == 0
-        scenarios = {p["scenario"]: p for p in lines if "scenario" in p}
-        assert set(scenarios) == {"flight-recorder", "watchdog-dump",
-                                  "trace-trigger"}
-        assert scenarios["flight-recorder"]["failing_step"] == 3
-        assert scenarios["watchdog-dump"]["phase"] == "step-dispatch"
-        assert scenarios["trace-trigger"]["device_compute_ms"] > 0
-        # three tiny trainer subprocesses (~15 s each on a quiet host,
-        # compile-dominated); ~4x headroom for CI contention
-        assert elapsed < 300, f"observability smoke took {elapsed:.0f}s"
-
-
-@pytest.mark.chaos
-class TestPipelineRollbackSmoke:
-    """ISSUE 7's tier-1 pin (chaos-marker pattern): a NaN fault under
-    --pipeline_gd must drain the in-flight fake stack at the rollback,
-    refill from the restored state, complete, and replay bit-exactly —
-    through real trainer subprocesses, inside an explicit runtime budget.
-    The full matrix runs standalone:
-    `JAX_PLATFORMS=cpu python tools/chaos_drill.py`."""
-
-    def test_pipeline_rollback_within_budget(self):
-        import time
-
-        t0 = time.monotonic()
-        res = subprocess.run(
-            [sys.executable, "tools/chaos_drill.py", "--only",
-             "pipeline-rollback"],
-            cwd=REPO, env=dict(os.environ, JAX_PLATFORMS="cpu"),
-            capture_output=True, text=True, timeout=420)
-        elapsed = time.monotonic() - t0
-        lines = [json.loads(l) for l in res.stdout.splitlines()
-                 if l.startswith("{")]
-        summary = lines[-1]
-        assert res.returncode == 0, (res.stdout[-1500:], res.stderr[-500:])
-        assert summary["scenarios"] == 1 and summary["failed"] == 0
-        scenarios = {p["scenario"]: p for p in lines if "scenario" in p}
-        assert set(scenarios) == {"pipeline-rollback"}
-        assert scenarios["pipeline-rollback"]["rollbacks"] >= 1
-        assert scenarios["pipeline-rollback"]["replay_bit_exact"] is True
-        # two tiny trainer subprocesses (the replay pair, ~20 s each on a
-        # quiet host, compile-dominated); ~4x headroom for CI contention
-        assert elapsed < 300, f"pipeline-rollback smoke took {elapsed:.0f}s"
-
-
-@pytest.mark.chaos
-class TestProgressiveSwitchSmoke:
-    """ISSUE 15's tier-1 pin (chaos-marker pattern): a NaN at the first
-    step after a progressive phase switch must roll back to the
-    POST-switch snapshot (the new phase's tree), complete, replay
-    STATE_SUM bit-exactly, and keep the pre-switch phase's losses
-    bit-exact against an unfaulted control — through real trainer
-    subprocesses, inside an explicit runtime budget. The full matrix
-    runs standalone: `JAX_PLATFORMS=cpu python tools/chaos_drill.py`."""
-
-    def test_progressive_switch_within_budget(self):
-        import time
-
-        t0 = time.monotonic()
-        res = subprocess.run(
-            [sys.executable, "tools/chaos_drill.py", "--only",
-             "progressive-switch"],
-            cwd=REPO, env=dict(os.environ, JAX_PLATFORMS="cpu"),
-            capture_output=True, text=True, timeout=420)
-        elapsed = time.monotonic() - t0
-        lines = [json.loads(l) for l in res.stdout.splitlines()
-                 if l.startswith("{")]
-        summary = lines[-1]
-        assert res.returncode == 0, (res.stdout[-1500:], res.stderr[-500:])
-        assert summary["scenarios"] == 1 and summary["failed"] == 0
-        scenarios = {p["scenario"]: p for p in lines if "scenario" in p}
-        assert set(scenarios) == {"progressive-switch"}
-        row = scenarios["progressive-switch"]
-        assert row["rollbacks"] >= 1
-        assert row["replay_bit_exact"] is True
-        assert row["preswitch_losses_bit_exact"] is True
-        # three tiny trainer subprocesses (faulted pair + control, each
-        # compiling two phase surfaces); ~4x headroom for CI contention
-        assert elapsed < 300, f"progressive-switch smoke took {elapsed:.0f}s"
-
-
-@pytest.mark.chaos
-class TestZeroRollbackSmoke:
-    """ISSUE 13's tier-1 pin (chaos-marker pattern): a NaN fault under
-    --zero_stage 3 must restore the data-SHARDED state from the rollback
-    snapshot, complete, and replay losses + STATE_SUM bit-exactly against
-    a --zero_stage 1 control — through real trainer subprocesses, inside
-    an explicit runtime budget. The full matrix runs standalone:
-    `JAX_PLATFORMS=cpu python tools/chaos_drill.py`."""
-
-    def test_zero_rollback_within_budget(self):
-        import time
-
-        t0 = time.monotonic()
-        res = subprocess.run(
-            [sys.executable, "tools/chaos_drill.py", "--only",
-             "zero-rollback"],
-            cwd=REPO, env=dict(os.environ, JAX_PLATFORMS="cpu"),
-            capture_output=True, text=True, timeout=420)
-        elapsed = time.monotonic() - t0
-        lines = [json.loads(l) for l in res.stdout.splitlines()
-                 if l.startswith("{")]
-        summary = lines[-1]
-        assert res.returncode == 0, (res.stdout[-1500:], res.stderr[-500:])
-        assert summary["scenarios"] == 1 and summary["failed"] == 0
-        scenarios = {p["scenario"]: p for p in lines if "scenario" in p}
-        assert set(scenarios) == {"zero-rollback"}
-        assert scenarios["zero-rollback"]["rollbacks"] >= 1
-        # to 1e-5, not to the bit: see the scenario's docstring for what
-        # the installed XLA:CPU does to the shard-local update
-        assert scenarios["zero-rollback"]["replay_rtol"] == 1e-5
-        # two tiny 2-device trainer subprocesses (~25 s each on a quiet
-        # host, compile-dominated); ~4x headroom for CI contention
-        assert elapsed < 300, f"zero-rollback smoke took {elapsed:.0f}s"
-
-
-@pytest.mark.chaos
-class TestElasticShrinkSmoke:
-    """ISSUE 12's tier-1 pin (chaos-marker pattern): a checkpoint saved
-    by 2 processes must resume on 1 process (2 virtual devices — same
-    2-way data mesh, different process census) through the sharding
-    sidecar's host-staged reshard, with post-resume losses and final
-    STATE_SUM replaying against a same-topology control resume to within
-    ulp-scale reduction-order tolerances (the cross-process collective
-    may sum partials in a different order than the intra-process one —
-    the drill documents the bound; see chaos_drill._elastic_scenario) —
-    through real trainer subprocesses, inside an explicit runtime budget.
-    The grow direction (and the rest of the matrix) runs standalone:
-    `JAX_PLATFORMS=cpu python tools/chaos_drill.py`."""
-
-    def test_elastic_shrink_within_budget(self):
-        import time
-
-        t0 = time.monotonic()
-        res = subprocess.run(
-            [sys.executable, "tools/chaos_drill.py", "--only",
-             "elastic-shrink"],
-            cwd=REPO, env=dict(os.environ, JAX_PLATFORMS="cpu"),
-            capture_output=True, text=True, timeout=420)
-        elapsed = time.monotonic() - t0
-        lines = [json.loads(l) for l in res.stdout.splitlines()
-                 if l.startswith("{")]
-        summary = lines[-1]
-        assert res.returncode == 0, (res.stdout[-1500:], res.stderr[-500:])
-        assert summary["scenarios"] == 1 and summary["failed"] == 0
-        scenarios = {p["scenario"]: p for p in lines if "scenario" in p}
-        assert set(scenarios) == {"elastic-shrink"}
-        row = scenarios["elastic-shrink"]
-        assert row["direction"] == "2proc->1proc"
-        assert row["replay_within_tolerance"] is True
-        assert row["state_sum_rel"] <= 5e-4
-        assert row["final_step"] == 6
-        assert row["reshard_ms"] > 0
-        # five tiny trainer launches (one 2-proc save pair, a 1-proc
-        # cross resume, a 2-proc control pair; ~20 s measured total on a
-        # quiet host) — generous headroom for CI contention
-        assert elapsed < 300, f"elastic-shrink smoke took {elapsed:.0f}s"
-
-
-@pytest.mark.chaos
-class TestLiveNoticeShrinkSmoke:
-    """ISSUE 18's tier-1 pin: a chaos preemption notice at step 3 drives
-    a LIVE t2x1 -> t1x1 mesh switch in one uninterrupted trainer process
-    (no restart), the run completes to step 6, the switch line reports
-    compile_requests_delta=0 (both topologies AOT-warmed+primed up
-    front), pre-notice losses replay bit-exactly against an
-    armed-but-unnotified control, and elastic/live_* event keys appear
-    only in the notified run. The grow-back direction runs standalone:
-    `JAX_PLATFORMS=cpu python tools/chaos_drill.py --only grow-back`."""
-
-    def test_live_notice_shrink_within_budget(self):
-        import time
-
-        t0 = time.monotonic()
-        res = subprocess.run(
-            [sys.executable, "tools/chaos_drill.py", "--only",
-             "notice-shrink"],
-            cwd=REPO, env=dict(os.environ, JAX_PLATFORMS="cpu"),
-            capture_output=True, text=True, timeout=420)
-        elapsed = time.monotonic() - t0
-        lines = [json.loads(l) for l in res.stdout.splitlines()
-                 if l.startswith("{")]
-        summary = lines[-1]
-        assert res.returncode == 0, (res.stdout[-1500:], res.stderr[-500:])
-        assert summary["scenarios"] == 1 and summary["failed"] == 0
-        scenarios = {p["scenario"]: p for p in lines if "scenario" in p}
-        row = scenarios["notice-shrink"]
-        assert row["compile_requests_delta"] == 0
-        assert row["final_step"] == 6
-        assert row["switch_ms"] > 0
-        assert row["state_sum_rel"] <= 5e-4
-        # two tiny 2-device trainer launches (control + notified, ~25 s
-        # measured total on a quiet host, warmup-dominated) — generous
-        # headroom for CI contention
-        assert elapsed < 300, f"notice-shrink smoke took {elapsed:.0f}s"
-
-
 @pytest.mark.slow
 class TestBenchProgressiveAB:
     """ISSUE 15's bench contract: `PROGRESSIVE=1 python bench.py` prints
@@ -672,17 +282,13 @@ class TestBenchProgressiveAB:
     a 256px compile in a subprocess."""
 
     def test_progressive_rows_before_headline(self):
-        env = dict(os.environ, JAX_PLATFORMS="cpu", BENCH_PLATFORM="cpu",
-                   BENCH_BATCH="4", BENCH_STEPS="2", BENCH_WINDOWS="1",
-                   BENCH_DEVSTEP="0", BENCH_SIZE="16", PROGRESSIVE="1",
-                   BENCH_PROGRESSIVE_STEPS="2", BENCH_256_BATCH="2",
-                   BENCH_256_STEPS="1")
-        res = subprocess.run([sys.executable, "bench.py"], cwd=REPO,
-                             env=env, capture_output=True, text=True,
-                             timeout=900)
+        res = run_tool(["bench.py"], timeout=900, BENCH_PLATFORM="cpu",
+                       BENCH_BATCH="4", BENCH_STEPS="2", BENCH_WINDOWS="1",
+                       BENCH_DEVSTEP="0", BENCH_SIZE="16", PROGRESSIVE="1",
+                       BENCH_PROGRESSIVE_STEPS="2", BENCH_256_BATCH="2",
+                       BENCH_256_STEPS="1")
         assert res.returncode == 0, (res.stdout[-800:], res.stderr[-800:])
-        rows = [json.loads(l) for l in res.stdout.splitlines()
-                if l.startswith("{")]
+        rows = json_rows(res.stdout)
         # both extra rows precede the headline row (last-line parse)
         ab = next(r for r in rows if "progressive" in r["metric"])
         r256 = next(r for r in rows if r["metric"].startswith("DCGAN-256"))
@@ -704,17 +310,13 @@ class TestBenchZeroAB:
     step compiles in a subprocess."""
 
     def test_zero_ab_row_state_strictly_decreasing(self):
-        env = dict(os.environ, JAX_PLATFORMS="cpu", BENCH_PLATFORM="cpu",
-                   BENCH_BATCH="8", BENCH_STEPS="4", BENCH_WINDOWS="1",
-                   BENCH_ZERO_STEPS="3", BENCH_DEVSTEP="0",
-                   BENCH_SIZE="16", ZERO_STAGE="3",
-                   XLA_FLAGS="--xla_force_host_platform_device_count=2")
-        res = subprocess.run([sys.executable, "bench.py"], cwd=REPO,
-                             env=env, capture_output=True, text=True,
-                             timeout=600)
+        res = run_tool(["bench.py"], timeout=600, BENCH_PLATFORM="cpu",
+                       BENCH_BATCH="8", BENCH_STEPS="4", BENCH_WINDOWS="1",
+                       BENCH_ZERO_STEPS="3", BENCH_DEVSTEP="0",
+                       BENCH_SIZE="16", ZERO_STAGE="3",
+                       XLA_FLAGS="--xla_force_host_platform_device_count=2")
         assert res.returncode == 0, (res.stdout[-800:], res.stderr[-800:])
-        rows = [json.loads(l) for l in res.stdout.splitlines()
-                if l.startswith("{")]
+        rows = json_rows(res.stdout)
         # the A/B row precedes the headline row (last-line parse contract)
         ab = next(r for r in rows if "ZeRO" in r["metric"])
         assert rows[-1]["metric"].endswith("(batch 8/chip, bf16)")
@@ -722,145 +324,6 @@ class TestBenchZeroAB:
         assert mibs[0] > mibs[1] > mibs[2], mibs
         # headline row carries the per-chip resident state too
         assert rows[-1]["peak_state_mib"] == pytest.approx(mibs[0])
-
-
-@pytest.mark.chaos
-class TestBenchStartupSmoke:
-    """tools/bench_startup.py --smoke pinned into tier-1 (ISSUE 5,
-    mirroring the chaos_drill pattern): the cold-vs-warm trainer A/B must
-    keep proving the warm-start invariants end to end through real trainer
-    subprocesses — warm compile strictly lower with a primed cache, zero
-    warm cache misses, and the fused verified restore reading each
-    manifest byte exactly once — inside an explicit runtime budget so the
-    pin can never quietly eat the tier. The full-size run is standalone:
-    `JAX_PLATFORMS=cpu python tools/bench_startup.py`."""
-
-    def test_cold_warm_ab_passes_within_budget(self):
-        import time
-
-        t0 = time.monotonic()
-        res = subprocess.run(
-            [sys.executable, "tools/bench_startup.py", "--smoke"], cwd=REPO,
-            env=dict(os.environ, JAX_PLATFORMS="cpu"),
-            capture_output=True, text=True, timeout=420)
-        elapsed = time.monotonic() - t0
-        assert res.returncode == 0, (res.stdout[-1500:], res.stderr[-500:])
-        row = json.loads(res.stdout.strip().splitlines()[-1])
-        assert row["label"] == "bench-startup" and row["ok"] is True
-        assert row["checks"]["warm_compile_strictly_lower"]
-        assert row["checks"]["warm_zero_misses"]
-        assert row["checks"]["restore_bytes_read_once"]
-        assert row["warm"]["cache"]["hits"] > 0
-        # the cross-topology arm (ISSUE 12): save@2-dev -> restore@1-dev
-        # must take the sidecar reshard path, and the same-topology warm
-        # arm must NOT
-        assert row["checks"]["cross_resharded"]
-        assert row["checks"]["warm_no_reshard"]
-        assert row["cross"]["reshard_ms"] > 0
-        # three tiny trainer subprocesses; ~4x measured cost (quiet host)
-        assert elapsed < 240, f"bench_startup smoke took {elapsed:.0f}s"
-
-
-@pytest.mark.chaos
-class TestBenchServeSmoke:
-    """tools/bench_serve.py --smoke pinned into tier-1 (ISSUE 9, the
-    chaos-marker pattern): the cold-vs-warm serving A/B over a bursty
-    Poisson trace must keep proving the serving-plane invariants end to
-    end through real subprocesses — zero sampler recompiles after the
-    AOT bucket warmup on BOTH arms (every served batch hits a
-    precompiled bucket), warm cache hits with zero misses, and the
-    finite-trace drain losing nothing — inside an explicit runtime
-    budget so the pin can never quietly eat the tier. The full-size run
-    is standalone: `JAX_PLATFORMS=cpu python tools/bench_serve.py`."""
-
-    def test_cold_warm_serve_ab_passes_within_budget(self):
-        import time
-
-        t0 = time.monotonic()
-        res = subprocess.run(
-            [sys.executable, "tools/bench_serve.py", "--smoke"], cwd=REPO,
-            env=dict(os.environ, JAX_PLATFORMS="cpu"),
-            capture_output=True, text=True, timeout=420)
-        elapsed = time.monotonic() - t0
-        assert res.returncode == 0, (res.stdout[-1500:], res.stderr[-500:])
-        row = json.loads(res.stdout.strip().splitlines()[-1])
-        assert row["label"] == "bench-serve" and row["ok"] is True
-        assert row["checks"]["cold_zero_recompiles_after_warmup"]
-        assert row["checks"]["warm_zero_recompiles_after_warmup"]
-        assert row["checks"]["warm_has_hits"]
-        assert row["checks"]["warm_zero_misses"]
-        assert row["cold"]["p99_ms"] >= row["cold"]["p50_ms"] > 0
-        assert row["warm"]["completed"] == row["trace"]["requests"]
-        # three tiny subprocesses (1 trainer + 2 serve arms, ~40 s on a
-        # quiet host, compile-dominated); ~4x headroom for CI contention
-        assert elapsed < 240, f"bench_serve smoke took {elapsed:.0f}s"
-
-    def test_serve_drain_scenario_within_budget(self):
-        """chaos_drill serve-drain pinned alongside: SIGTERM mid-load ->
-        in-flight requests complete, queue drains, clean exit (the
-        serving plane's first chaos consumer)."""
-        import time
-
-        t0 = time.monotonic()
-        res = subprocess.run(
-            [sys.executable, "tools/chaos_drill.py", "--only",
-             "serve-drain"], cwd=REPO,
-            env=dict(os.environ, JAX_PLATFORMS="cpu"),
-            capture_output=True, text=True, timeout=420)
-        elapsed = time.monotonic() - t0
-        lines = [json.loads(l) for l in res.stdout.splitlines()
-                 if l.startswith("{")]
-        summary = lines[-1]
-        assert res.returncode == 0, (res.stdout[-1500:], res.stderr[-500:])
-        assert summary["scenarios"] == 1 and summary["failed"] == 0
-        scenario = next(p for p in lines if p.get("scenario") == "serve-drain")
-        assert scenario["clean_exit"] is True
-        assert scenario["completed"] == scenario["submitted"] > 0
-        # two tiny subprocesses (1 trainer + 1 serve under SIGTERM);
-        # ~4x headroom for CI contention
-        assert elapsed < 240, f"serve-drain smoke took {elapsed:.0f}s"
-
-
-@pytest.mark.chaos
-class TestFleetReplicaKillSmoke:
-    """ISSUE 19's tier-1 pin (chaos-marker pattern): the serving fleet
-    under live fire through a real `python -m dcgan_tpu.serve --fleet 3`
-    subprocess — a chaos kill of replica 1 mid-trace must become a
-    failover (ZERO failed client requests, completed == submitted), the
-    dead replica must be drained from rotation and logged, and the
-    mid-trace checkpoint injection must be hot-swapped onto EXACTLY the
-    survivors with compile_requests_delta == 0 per replica (the
-    zero-recompile promotion literal, proven by the compile-cache
-    monitor, not assumed). Inside an explicit runtime budget so the pin
-    can never quietly eat the tier."""
-
-    def test_fleet_replica_kill_within_budget(self):
-        import time
-
-        t0 = time.monotonic()
-        res = subprocess.run(
-            [sys.executable, "tools/chaos_drill.py", "--only",
-             "fleet-replica-kill"], cwd=REPO,
-            env=dict(os.environ, JAX_PLATFORMS="cpu"),
-            capture_output=True, text=True, timeout=420)
-        elapsed = time.monotonic() - t0
-        lines = [json.loads(l) for l in res.stdout.splitlines()
-                 if l.startswith("{")]
-        summary = lines[-1]
-        assert res.returncode == 0, (res.stdout[-1500:], res.stderr[-500:])
-        assert summary["scenarios"] == 1 and summary["failed"] == 0
-        row = next(p for p in lines
-                   if p.get("scenario") == "fleet-replica-kill")
-        assert row["failed"] == 0
-        assert row["completed"] == row["submitted"] > 0
-        assert row["unhealthy"] == [1]
-        assert row["promoted_replicas"] == [0, 2]
-        assert row["promoted_step"] == 2
-        assert row["compile_requests_delta"] == 0
-        # three tiny subprocesses (2 trainer runs for the checkpoint
-        # lineage + 1 fleet serve; ~21 s measured total on a quiet
-        # 1-core host); ~4x headroom for CI contention
-        assert elapsed < 240, f"fleet-replica-kill took {elapsed:.0f}s"
 
 
 @pytest.mark.slow
@@ -871,16 +334,13 @@ class TestToolsRunOnCpu:
         one shared wall window, and the parent emits well-formed aggregate
         rows (the numbers only mean anything on a quiet multi-core host —
         the contract here is protocol + JSON shape)."""
-        res = subprocess.run(
-            [sys.executable, "tools/bench_loader_scale.py",
+        res = run_tool(
+            ["tools/bench_loader_scale.py",
              "--processes", "1", "2", "--seconds", "1.5", "--warmup_s", "5",
              "--num_examples", "512", "--num_shards", "8", "--threads",
-             "4"],
-            cwd=REPO, env=dict(os.environ), capture_output=True, text=True,
-            timeout=300)
+             "4"], timeout=300)
         assert res.returncode == 0, res.stderr[-800:]
-        lines = [json.loads(l) for l in res.stdout.splitlines()
-                 if l.startswith("{")]
+        lines = json_rows(res.stdout)
         assert [p["processes"] for p in lines] == [1, 2]
         for p in lines:
             assert p["label"] == "loader-scale"
@@ -896,11 +356,8 @@ class TestToolsRunOnCpu:
         tool runs at 50k on a chip, pinned here so the tool
         cannot rot (the score is arbitrary; the contract is that the
         canonical path executes and reports the requested sample count)."""
-        res = subprocess.run(
-            [sys.executable, "tools/canonical_50k.py"], cwd=REPO,
-            env=dict(os.environ, BENCH_PLATFORM="cpu", JAX_PLATFORMS="cpu",
-                     CANON_SAMPLES="64"),
-            capture_output=True, text=True, timeout=900)
+        res = run_tool(["tools/canonical_50k.py"], timeout=900,
+                       BENCH_PLATFORM="cpu", CANON_SAMPLES="64")
         assert res.returncode == 0, (res.stderr[-800:], res.stdout[-300:])
         row = json.loads(res.stdout.strip().splitlines()[-1])
         assert row["label"] == "canonical-npz-50k"
@@ -909,15 +366,11 @@ class TestToolsRunOnCpu:
         assert "torch" in row["embedder"]
 
     def test_matmul_rate_cpu(self):
-        env = dict(os.environ, BENCH_PLATFORM="cpu", JAX_PLATFORMS="cpu",
-                   MATMUL_SHAPES="64x64,64x128", MATMUL_ITERS="2",
-                   MATMUL_WINDOWS="1")
-        res = subprocess.run(
-            [sys.executable, "tools/matmul_rate.py"], cwd=REPO, env=env,
-            capture_output=True, text=True, timeout=300)
+        res = run_tool(["tools/matmul_rate.py"], timeout=300,
+                       BENCH_PLATFORM="cpu", MATMUL_SHAPES="64x64,64x128",
+                       MATMUL_ITERS="2", MATMUL_WINDOWS="1")
         assert res.returncode == 0, res.stderr[-500:]
-        lines = [json.loads(l) for l in res.stdout.splitlines()
-                 if l.startswith("{")]
+        lines = json_rows(res.stdout)
         shapes = [(p["m"], p["n"]) for p in lines if p.get("form")]
         assert shapes == [(64, 64), (64, 128)]
         summ = lines[-1]
@@ -927,14 +380,10 @@ class TestToolsRunOnCpu:
         """attention_memory compiles both forms and prints well-formed
         rows; where the backend reports temp sizes, dense must grow with
         S while flash stays bounded (the O(S^2)-vs-O(S) claim's shape)."""
-        env = dict(os.environ, JAX_PLATFORMS="cpu")
-        res = subprocess.run(
-            [sys.executable, "tools/attention_memory.py",
-             "--platform", "cpu", "--seq", "256", "512"],
-            cwd=REPO, env=env, capture_output=True, text=True, timeout=300)
+        res = run_tool(["tools/attention_memory.py", "--platform", "cpu",
+                        "--seq", "256", "512"], timeout=300)
         assert res.returncode == 0, res.stderr[-500:]
-        lines = [json.loads(l) for l in res.stdout.splitlines()
-                 if l.startswith("{")]
+        lines = json_rows(res.stdout)
         rows = {(p["form"], p["seq"]): p for p in lines if "form" in p}
         assert set(rows) == {("dense", 256), ("dense", 512),
                              ("flash", 256), ("flash", 512)}

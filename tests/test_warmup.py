@@ -8,7 +8,7 @@ variant) so a rollback drill triggers no recompile; default-flags event
 streams stay byte-identical to warm-start-enabled ones (the parity
 contract); and the fused restore reads each verified byte once, still
 quarantining same-size corruption. The cross-process half of the story is
-tools/bench_startup.py, pinned in test_tools.py."""
+tools/bench_startup.py, pinned in test_tools_serve.py."""
 
 import json
 import os

@@ -12,10 +12,10 @@ Stage-1 parity (the `--zero_stage 1` default must be byte-identical to
 pre-PR behavior) is pinned MECHANICALLY, not by an A/B of the binary
 against itself: every stage-1 program's jaxpr fingerprint in the
 committed `analysis/programs.lock.jsonl` is unchanged from the pre-ZeRO
-manifest (the semantic smoke pin in tests/test_tools.py recomputes and
-byte-compares it), and the rule engine's stage-1 resolution still matches
-the retired hand-built oracle spec-object-for-spec-object
-(tests/test_elastic.py). What THIS file pins:
+manifest (the semantic smoke pin, tests/test_tools_analysis_all*.py,
+recomputes and byte-compares it), and the rule engine's stage-1
+resolution still matches the retired hand-built oracle
+spec-object-for-spec-object (tests/test_elastic.py). What THIS file pins:
 
 - stage 1/2/3 loss parity on the canonical 2-device CPU mesh for all
   three model families, both backends, with per-chip resident state
@@ -29,7 +29,7 @@ the retired hand-built oracle spec-object-for-spec-object
 
 The end-to-end NaN-rollback drill (zero_stage=3 vs a stage-1 control,
 bit-exact loss replay) lives in tools/chaos_drill.py::zero-rollback,
-pinned by tests/test_tools.py; the cross-stage cross-mesh checkpoint
+pinned by tests/test_tools_elastic.py; the cross-stage cross-mesh checkpoint
 restore lives in tests/test_elastic.py.
 """
 

@@ -33,7 +33,7 @@ One BENCH line: per-arm p50/p99, dropped/failed, recompile counters,
 and the promotion swap time.
 
 `--smoke` shrinks the model, trace, and budgets to the tier-1 pin
-(tests/test_tools.py, the chaos-marker pattern); the full-size run is
+(tests/test_tools_serve.py, the chaos-marker pattern); the full-size run is
 the standalone capture. CPU-only by design — the serving economics
 story on chips comes from the module tracks; this tool certifies the
 MECHANISM.

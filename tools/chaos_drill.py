@@ -130,7 +130,7 @@ sys.path.insert(0, REPO)
 # ModelConfig — through the driver's repr-round-tripped `extra` dict
 from dcgan_tpu.config import MeshConfig, ModelConfig  # noqa: E402
 
-# CI subset (tests/test_tools.py pins --smoke into tier-1): the cheapest
+# CI subset (tests/test_tools_chaos.py pins --smoke into tier-1): the cheapest
 # scenarios that still cross every new layer — quarantine (data), retry
 # (checkpoint IO), worker-crash surfacing (services). The two-phase
 # checkpoint-fallback and rollback scenarios run in the full matrix (and
@@ -862,7 +862,7 @@ SCENARIOS = {
 # MH_PID), so every scenario proves a LOCAL fault becoming a GLOBAL,
 # deterministic decision.
 
-# cheapest multi-host scenario, pinned into tier-1 (tests/test_tools.py)
+# cheapest multi-host scenario, pinned into tier-1 (tests/test_tools_chaos.py)
 MH_SMOKE_SCENARIOS = ("mh-sigterm-stop",)
 
 _MH_DRIVER = """
